@@ -291,8 +291,7 @@ class MultiLayerNetwork:
         def step(params, states, upd_states, it, ep, x, y, mask, label_mask, rng, carries):
             # split on DEVICE and return the next key + iteration: the fit
             # loop then re-feeds them without any per-step host-side device
-            # ops (a host rng split + two scalar placements cost ~14 ms/step
-            # through a remote dispatch link — measured round 3)
+            # ops (a host rng split + two scalar placements)
             rng_use, rng_next = jax.random.split(rng)
 
             def lf(p):
@@ -411,10 +410,8 @@ class MultiLayerNetwork:
     def fit_batches_on_device(self, datasets) -> "MultiLayerNetwork":
         """Train on a window of equal-shape batches in ONE device dispatch
         (``lax.scan`` over the stacked window) — semantically identical to
-        ``fit`` per batch; built for dispatch-bound setups on directly-
-        attached hardware (tunneled backends that stream operands lazily
-        can be SLOWER this way). Requires uniform shapes, no masks,
-        standard backprop."""
+        ``fit`` per batch; built for dispatch-bound setups. Requires
+        uniform shapes, no masks, standard backprop."""
         from deeplearning4j_tpu.nn.conf.network import normalize_backprop_type
         if self.params is None:
             self.init()

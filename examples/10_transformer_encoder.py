@@ -6,7 +6,7 @@ trains a small encoder on a token-presence task, shows variable-length
 masking (padded batch == unpadded prefix batch), and prints the model card.
 
 Measured on one TPU v5e chip at BERT-base shape (B=32, T=128, bf16):
-31.3 ms/step — ~44% model FLOPs utilization (BASELINE.md).
+31.3 ms/step — ~44% model FLOPs utilization (rounds 1-5, not re-measured: PERF.md).
 
 Run: python examples/10_transformer_encoder.py   (CPU-friendly at this size)
 """

@@ -6,9 +6,9 @@ protocol as the LSTMs) trained on a next-token task, then sampled three ways:
 
 1. `generate`      — host loop over `rnn_time_step` (one jitted step/token);
 2. `generate_on_device` — the WHOLE decode compiled to one executable
-   (prefill + `lax.scan` + on-device sampling). Measured on one TPU v5e
-   through a remote link: 1.37 ms/token vs the host loop's 116 ms/token —
-   85x, because the per-token host round trip disappears (BASELINE.md);
+   (prefill + `lax.scan` + on-device sampling): the per-token host round
+   trip disappears. No timing of either loop has been taken on this
+   installation (PERF.md);
 3. truncated BPTT — the same model trained in chunks with carried caches
    (Transformer-XL-style), via the graph's `t_bptt_length`.
 

@@ -6,15 +6,16 @@ what each one buys, on a small causal LM so it runs anywhere:
 
 1. **Causal flash attention** at the helper seam — O(T) memory, skips the
    masked upper triangle. Measured on v5e: 1.45x LM training at T=2048,
-   2.64x at T=4096 (BASELINE.md). Registered once, serves every causal
-   attention layer whose shapes it supports; outputs unchanged.
+   2.64x at T=4096 (rounds 1-5, not re-measured: PERF.md). Registered
+   once, serves every causal attention layer whose shapes it supports;
+   outputs unchanged.
 2. **Sequence parallelism** — `SequenceParallelAttentionHelper(causal=True)`
    shards the SEQUENCE axis over a mesh (ring or Ulysses all-to-all), so a
    context that cannot fit one chip's HBM spreads across the slice. Same
    outputs, one registration line.
 3. **Gradient checkpointing** — rematerialize per-layer activations in the
    backward pass: measured 5.2x less temp HBM on a 6-block attention stack
-   at T=512 (BASELINE.md).
+   at T=512 (rounds 1-5, not re-measured: PERF.md).
 4. **Truncated BPTT over the graph** — Transformer-XL-style chunking: KV
    caches and positional offsets carry across chunks, so a sequence longer
    than the attention window still trains end to end.

@@ -102,7 +102,7 @@ class TestGradientCheckpointing:
         """Remat composes with the XLA memory analysis. (The buffer-assignment
         savings materialize on the TPU backend; the CPU scheduler may order
         the recompute clusters differently, so no inequality is asserted
-        here — see the TPU verification in BASELINE.md.)"""
+        here.)"""
         from deeplearning4j_tpu.nn.conf import compiled_memory_analysis
 
         def analyze(remat):
