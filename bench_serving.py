@@ -884,6 +884,9 @@ def main(argv=None):
     p.add_argument("--out", default=None,
                    help="also write the JSON record here")
     args = p.parse_args(argv)
+    from deeplearning4j_tpu.util.compile_cache import (
+        enable_persistent_compile_cache)
+    enable_persistent_compile_cache()
     if args.check:
         with open(args.check) as f:
             committed = json.load(f)

@@ -192,7 +192,10 @@ def parallel_wrapper_main(argv: Optional[List[str]] = None):
     from deeplearning4j_tpu.parallel import ParallelWrapper
     from deeplearning4j_tpu.parallel.mesh import make_mesh
     from deeplearning4j_tpu.util import model_serializer
+    from deeplearning4j_tpu.util.compile_cache import (
+        enable_persistent_compile_cache)
 
+    enable_persistent_compile_cache()
     net = model_serializer.restore_model(args.modelPath)
     z = np.load(args.dataPath)
     ds = DataSet(z["features"], z["labels"])
@@ -595,7 +598,10 @@ def serve_main(argv: Optional[List[str]] = None, block: bool = True):
                         "restores lazy first-request compilation")
     p.add_argument("--compile-cache-dir", default=None, metavar="DIR",
                    help="persistent XLA compilation cache: restarts and "
-                        "rollbacks re-warm from disk instead of compiling")
+                        "rollbacks re-warm from disk instead of compiling "
+                        "(default: $JAX_COMPILATION_CACHE_DIR, else "
+                        "<checkout>/.jax_cache on an accelerator and none "
+                        "on CPU)")
     p.add_argument("--dtype-policy", action="append", default=[],
                    metavar="NAME=POLICY",
                    help="serve NAME quantized: POLICY is int8, bf16 or "
@@ -776,11 +782,16 @@ def serve_main(argv: Optional[List[str]] = None, block: bool = True):
                        window_s=args.breaker_window,
                        cooldown_s=args.breaker_cooldown,
                        half_open_probes=args.breaker_probes)
+    from deeplearning4j_tpu.util.compile_cache import (
+        enable_persistent_compile_cache)
+    try:
+        enable_persistent_compile_cache(args.compile_cache_dir)
+    except ValueError as e:
+        p.error(f"--compile-cache-dir: {e}")
     registry = ModelRegistry(metrics=default_registry(),
                              max_batch_size=args.max_batch_size,
                              wait_ms=args.wait_ms, buckets=buckets,
                              warmup=args.warmup,
-                             compile_cache_dir=args.compile_cache_dir,
                              max_dispatcher_restarts=(
                                  args.max_dispatcher_restarts),
                              breaker=breaker)

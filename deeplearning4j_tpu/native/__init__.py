@@ -12,6 +12,7 @@ reports which path is live.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -37,10 +38,18 @@ def _compile() -> Optional[str]:
     os.makedirs(_BUILD_DIR, exist_ok=True)
     out = os.path.join(_BUILD_DIR, _LIB_BASENAME)
     stamp = os.path.join(_BUILD_DIR, ".stamp")
-    newest_src = max(os.path.getmtime(s) for s in sources)
-    if os.path.exists(out) and os.path.exists(stamp) \
-            and os.path.getmtime(stamp) >= newest_src:
-        return out
+    # the stamp holds a hash of the sources the library was built from:
+    # mtimes do not survive a copy of the tree, and a stale .so that
+    # travelled with one must not be trusted
+    digest = hashlib.sha256()
+    for src in sources:
+        with open(src, "rb") as fh:
+            digest.update(fh.read())
+    want = digest.hexdigest()
+    if os.path.exists(out) and os.path.exists(stamp):
+        with open(stamp, encoding="ascii") as fh:
+            if fh.read().strip() == want:
+                return out
     cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
            "-o", out] + sources
     try:
@@ -50,8 +59,8 @@ def _compile() -> Optional[str]:
         log.warning("native build failed, using Python fallbacks: %s",
                     detail.strip()[:500])
         return None
-    with open(stamp, "w"):
-        pass
+    with open(stamp, "w", encoding="ascii") as fh:
+        fh.write(want)
     return out
 
 

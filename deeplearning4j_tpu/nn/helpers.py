@@ -65,11 +65,30 @@ def clear_all_helpers() -> None:
     _HELPERS.clear()
 
 
+def partitioned_by_compiler(x) -> bool:
+    """True when ``x`` is being traced for a program the compiler will
+    partition: its type carries a mesh with more than one device along an
+    axis that GSPMD manages, i.e. not one a ``shard_map`` made manual (jit
+    puts the mesh of any sharded argument into the type of everything
+    computed from it). Mosaic refuses such a program — "Mosaic kernels
+    cannot be automatically partitioned" — and only a chip with several
+    devices ever shows it, so both auto gates ask here before they pick a
+    Pallas kernel. A helper registered by hand is not second-guessed: JAX's
+    own error says what to do."""
+    import jax
+    from jax.sharding import AxisType
+
+    mesh = jax.typeof(x).sharding.mesh
+    return any(size > 1 and kind != AxisType.Manual
+               for size, kind in zip(mesh.axis_sizes, mesh.axis_types))
+
+
 # -- flash-attention auto-registration ---------------------------------------
 # When NO attention helper is registered, causal attention at T >= 2048 on a
 # TPU backend automatically uses the causal PallasFlashAttentionHelper — the
 # measured win region (LM training 1.45x at T=2048, 2.64x at T=4096; the
-# kernel skips the masked upper triangle the einsum path still computes).
+# kernel skips the masked upper triangle the einsum path still computes) —
+# unless the program is partitioned by the compiler (see above).
 # Registering any helper, or set_auto_flash_attention(False), overrides.
 _AUTO_FLASH = True
 

@@ -57,7 +57,8 @@ def dot_product_attention(q, k, v, mask=None, dropout_rate=0.0, rng=None,
             and q.shape == k.shape == v.shape):
         return helper.attend(q, k, v)
     if (helper is None and causal and q.shape[-2] >= _AUTO_FLASH_MIN_T
-            and _helpers.auto_flash_attention_enabled()):
+            and _helpers.auto_flash_attention_enabled()
+            and not _helpers.partitioned_by_compiler(q)):
         # no helper registered: auto-use the causal flash kernel in its
         # measured win region (1.45x T=2048 / 2.64x T=4096 LM training) so
         # the speedup doesn't depend on knowing the seam exists; opt out

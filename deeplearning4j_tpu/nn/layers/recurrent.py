@@ -28,17 +28,21 @@ import jax.numpy as jnp
 from jax import lax
 
 from deeplearning4j_tpu.nn import activations as act_mod
+from deeplearning4j_tpu.nn import helpers as _helpers
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
 
 
 # -- fused-LSTM auto-registration (helpers.set_auto_fused_lstm to opt out) ----
-# Win region for auto-using PallasLSTMHelper with NO helper registered:
-# long sequences with lane-aligned, modest hidden sizes. Measured on v5e the
-# fused kernel TIES stock XLA at H=512/T=128 (pallas_kernels.py header) — XLA
-# already keeps that carry on-chip — so the auto gate only takes shapes where
-# the sequential scan's per-step launch overhead dominates: T >= 256 steps
-# and H in {128, 256} (VMEM-resident h/c, one (H,4H) tile per step).
+# Region for auto-using PallasLSTMHelper with NO helper registered: long
+# sequences with lane-aligned, modest hidden sizes, in the two dtypes the
+# kernel is proven to compile for. What is measured: a TIE with stock XLA at
+# H=512/T=128 f32 on a v5e (pallas_kernels.py header), and — by
+# chip_smoke.py on every run — that the kernel compiles under Mosaic and
+# agrees with the XLA scan at H in {128, 256}, T=256, f32 and bf16. What is
+# NOT measured: any timing inside this region. T >= 256 and H <= 256 were
+# inferred (per-step overhead of the scan should dominate there), so the
+# gate is a candidate for ROADMAP S3, not a recorded win.
 _AUTO_LSTM_MIN_T = 256
 _AUTO_LSTM_MAX_H = 256
 _auto_lstm_cache: dict = {}
@@ -59,7 +63,9 @@ def _auto_lstm_helper():
 def _auto_lstm_win_region(layer, x) -> bool:
     return (x.shape[1] >= _AUTO_LSTM_MIN_T
             and layer.n_out % 128 == 0
-            and layer.n_out <= _AUTO_LSTM_MAX_H)
+            and layer.n_out <= _AUTO_LSTM_MAX_H
+            and x.dtype in (jnp.float32, jnp.bfloat16)
+            and not _helpers.partitioned_by_compiler(x))
 
 
 def check_carry_capacity(named_layers, t_total: int, context: str) -> None:
@@ -209,7 +215,6 @@ class LSTMLayer(BaseRecurrentLayer, Layer):
         # helper seam (ConvolutionLayer.java:76-84 reflective-load pattern):
         # a registered LSTM helper (e.g. the Pallas fused kernel) takes the
         # sequence pass when it supports this configuration
-        from deeplearning4j_tpu.nn import helpers as _helpers
         helper = _helpers.get_helper("lstm")
         if helper is not None and helper.supports(self, mask):
             return helper.forward_seq(self, params, x, carry)

@@ -44,15 +44,21 @@ def _lstm_kernel(hidden: int, t_total: int,
         h_scr[:] = h0_ref[:]
         c_scr[:] = c0_ref[:]
 
-    z = xw_ref[0] + jnp.dot(h_scr[:], rw_ref[:],
-                            preferred_element_type=jnp.float32).astype(xw_ref.dtype)
+    # gate math in f32 whatever the storage dtype: Mosaic refuses the
+    # transcendentals' scalar constants against bf16 vectors, and the v5e
+    # VPU/EUP has no bf16 path to lose; h/c round to the storage dtype once
+    # per step, as the reference scan's carry does
+    f32 = jnp.float32
+    z = xw_ref[0].astype(f32) + jnp.dot(h_scr[:], rw_ref[:],
+                                        preferred_element_type=f32)
     H = hidden
     i = jax.nn.sigmoid(z[:, :H])
     f = jax.nn.sigmoid(z[:, H:2 * H])
     o = jax.nn.sigmoid(z[:, 2 * H:3 * H])
     g = jnp.tanh(z[:, 3 * H:])
-    c = f * c_scr[:] + i * g
-    h = o * jnp.tanh(c)
+    c = f * c_scr[:].astype(f32) + i * g
+    h = (o * jnp.tanh(c)).astype(h_scr.dtype)
+    c = c.astype(c_scr.dtype)
     h_scr[:] = h
     c_scr[:] = c
     ys_ref[0] = h
@@ -136,12 +142,11 @@ lstm_fused.defvjp(_fused_fwd, _fused_bwd)
 
 class PallasLSTMHelper(LSTMHelper):
     """Fused-LSTM helper: standard LSTM (sigmoid gates, tanh cell, no
-    peepholes, no mask). ``interpret=True`` runs the kernel in the Pallas
-    interpreter (CPU testing)."""
+    peepholes, no mask). The kernel is compiled by Mosaic for the TPU;
+    ``interpret=True`` runs it in the Pallas interpreter instead and is for
+    CPU tests only — it is never chosen from the backend string."""
 
-    def __init__(self, interpret: bool = None):
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+    def __init__(self, interpret: bool = False):
         self.interpret = interpret
 
     def supports(self, layer, mask) -> bool:
@@ -244,12 +249,11 @@ class PallasUpdaterHelper(UpdaterHelper):
     """Fused Adam/Nadam/AMSGrad update: new param + new moments in ONE
     kernel launch per parameter tensor, in place over donated buffers.
     Other updater classes (and non-f32 params) fall back to the stock XLA
-    chain via ``supports``. ``interpret=True`` runs the kernel in the
-    Pallas interpreter (CPU testing)."""
+    chain via ``supports``. The kernel is compiled by Mosaic for the TPU;
+    ``interpret=True`` runs it in the Pallas interpreter instead and is for
+    CPU tests only — it is never chosen from the backend string."""
 
-    def __init__(self, interpret: bool = None):
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+    def __init__(self, interpret: bool = False):
         self.interpret = interpret
 
     def supports(self, updater, param, grad) -> bool:

@@ -44,20 +44,15 @@ def _on_event_duration(name: str, duration_s: float, **kwargs) -> None:
         pass
 
 
-def install_jax_hook() -> bool:
-    """Register the monitoring listener (idempotent). Returns True when the
-    hook is installed, False when ``jax.monitoring`` is unavailable."""
+def install_jax_hook() -> None:
+    """Register the monitoring listener (idempotent)."""
     global _installed
     with _install_lock:
-        if _installed:
-            return True
-        try:
-            import jax.monitoring as monitoring
-        except Exception:  # pragma: no cover - jax always present in-repo
-            return False
-        monitoring.register_event_duration_secs_listener(_on_event_duration)
-        _installed = True
-        return True
+        if not _installed:
+            import jax.monitoring
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_event_duration)
+            _installed = True
 
 
 def hook_installed() -> bool:

@@ -74,6 +74,7 @@ logs, and the shipped restart-storm alert rule
 from __future__ import annotations
 
 import dataclasses
+import glob
 import hashlib
 import json
 import os
@@ -353,6 +354,28 @@ class BackoffPolicy:
         return d
 
 
+def refuse_shared_tpu(env: Dict[str, str], num_workers: int) -> None:
+    """Raise when ``num_workers`` local processes would share this host's
+    TPU. :class:`SubprocessLauncher` starts every worker here with one
+    environment, so on a TPU host each would bring up the TPU runtime and
+    ask for every local chip. The first gets them; the others die at
+    start-up (seen on a v5e, libtpu 0.0.34: ``Unable to initialize backend
+    'tpu': ABORTED: Internal error when accessing libtpu multi-process
+    lockfile``) and the supervisor would spend its retries on them. A chip
+    belongs to one process, and nothing here pins one chip per worker."""
+    platforms = [p for p in env.get("JAX_PLATFORMS", "").split(",") if p]
+    if platforms and "tpu" not in platforms:
+        return  # the workers are held off the TPU
+    if not (glob.glob("/dev/vfio/[0-9]*") or glob.glob("/dev/accel*")):
+        return  # no TPU device nodes on this host
+    raise ValueError(
+        f"{num_workers} elastic workers on one TPU host would each claim "
+        f"every local chip and all but the first would fail at start-up. "
+        f"Run one worker per host (--elastic 1 here; a mesh over the "
+        f"host's chips belongs inside that worker: --mesh model=N), or "
+        f"hold the workers to the CPU with JAX_PLATFORMS=cpu")
+
+
 class SubprocessLauncher:
     """Default process backend (injectable: unit tests drive the
     supervisor with fake handles and a manual clock)."""
@@ -505,8 +528,11 @@ class ElasticJobSupervisor:
         self.clock = clock
         import time as _time
         self.sleep_fn = sleep_fn if sleep_fn is not None else _time.sleep
-        self.launcher = launcher if launcher is not None \
-            else SubprocessLauncher()
+        if launcher is None:
+            if num_workers > 1:
+                refuse_shared_tpu(spec.environment(), num_workers)
+            launcher = SubprocessLauncher()
+        self.launcher = launcher
         if metrics is None:
             from deeplearning4j_tpu.observe import default_registry
             metrics = default_registry()

@@ -57,29 +57,12 @@ def init_distributed(coordinator_address: Optional[str] = None,
     the advertised address)."""
     if num_processes is None or num_processes <= 1:
         return
-    _enable_cpu_collectives()
     kwargs = {}
     if coordinator_bind_address is not None:
         kwargs["coordinator_bind_address"] = coordinator_bind_address
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id, **kwargs)
-
-
-def _enable_cpu_collectives() -> None:
-    """The CPU backend has no native cross-process collectives (XLA raises
-    "Multiprocess computations aren't implemented on the CPU backend") —
-    route them through Gloo TCP. Must run before the backend initializes;
-    a value the operator set explicitly (flag or env) is left alone, and
-    on TPU the CPU-client setting is inert."""
-    try:
-        from jax._src import xla_bridge  # registers the flag
-        current = xla_bridge.CPU_COLLECTIVES_IMPLEMENTATION.value
-        if current in (None, "none") \
-                and not xla_bridge.backends_are_initialized():
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # noqa: BLE001 - older/newer jax: best effort only
-        pass
 
 
 class TrainingStats:

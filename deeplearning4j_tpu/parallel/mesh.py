@@ -9,52 +9,20 @@ lays collectives onto ICI links following the mesh topology.
 
 from __future__ import annotations
 
-import inspect
-
 from typing import Dict, Optional, Sequence
 
 import jax
 import numpy as np
+from jax import shard_map as _jax_shard_map
 from jax.sharding import Mesh
-
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-
-def _shard_map_check_kwarg() -> Optional[str]:
-    """Which disable-replication-checking kwarg THIS jax's shard_map takes
-    (``check_vma`` on recent jax, ``check_rep`` before, None when neither
-    is inspectable). Resolved from the wrapper's signature, NOT by probing
-    with try/except TypeError: a bare retry-on-TypeError also swallowed
-    genuine TypeErrors raised while tracing the user ``fn`` (e.g. a body
-    with the wrong arity), silently re-running the broken trace and then
-    reporting a misleading missing-kwarg failure."""
-    try:
-        params = inspect.signature(_shard_map_impl).parameters
-    except (TypeError, ValueError):  # pragma: no cover - C accelerated impl
-        return None
-    for kw in ("check_vma", "check_rep"):
-        if kw in params:
-            return kw
-    if any(p.kind is inspect.Parameter.VAR_KEYWORD
-           for p in params.values()):  # pragma: no cover - jax version
-        return "check_vma"
-    return None  # pragma: no cover - neither kwarg exists on this jax
-
-
-_CHECK_KWARG = _shard_map_check_kwarg()
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs):
-    """Version-tolerant shard_map with replication checking disabled
-    (the kwarg is ``check_vma`` on recent jax, ``check_rep`` before).
-    The kwarg is resolved once from the implementation's signature, so a
-    TypeError raised from the user's ``fn`` propagates untouched."""
-    kwargs = {} if _CHECK_KWARG is None else {_CHECK_KWARG: False}
-    return _shard_map_impl(fn, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
+    """``jax.shard_map`` with replication checking off, as every per-shard
+    body in this package (psum/pmean/ppermute by hand) needs it."""
+    return _jax_shard_map(fn, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False)
+
 
 # Canonical axis names used across the framework.
 DATA_AXIS = "data"

@@ -17,10 +17,6 @@ flags = os.environ.get("XLA_FLAGS", "")
 if "host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count=8").strip()
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 
 from deeplearning4j_tpu.datasets.dataset import DataSet, ListDataSetIterator

@@ -358,6 +358,26 @@ class TestSupervisorStateMachine:
                                  ckpt_dir=str(tmp_path))
 
 
+    def test_refuses_local_workers_sharing_a_tpu(self, tmp_path, monkeypatch):
+        """N > 1 subprocess workers on a TPU host: all but the first die on
+        libtpu's lockfile. The supervisor says so instead of retrying."""
+        from deeplearning4j_tpu.parallel import elastic
+        monkeypatch.setattr(elastic.glob, "glob",
+                            lambda pat: ["/dev/vfio/1"] if "vfio" in pat
+                            else [])
+        on_tpu = WorkerSpec(argv=["w"], env={"JAX_PLATFORMS": "tpu,cpu"})
+        with pytest.raises(ValueError, match="one worker per host"):
+            ElasticJobSupervisor(on_tpu, 2, ckpt_dir=str(tmp_path))
+        # one worker, workers held to the CPU, or an injected launcher
+        # (which may start them anywhere) are all fine
+        ElasticJobSupervisor(on_tpu, 1, ckpt_dir=str(tmp_path))
+        ElasticJobSupervisor(WorkerSpec(argv=["w"],
+                                        env={"JAX_PLATFORMS": "cpu"}),
+                             2, ckpt_dir=str(tmp_path))
+        ElasticJobSupervisor(on_tpu, 2, ckpt_dir=str(tmp_path),
+                             launcher=object())
+
+
 class TestBackoffPolicy:
     def test_deterministic_and_bounded(self):
         p = BackoffPolicy(base_s=1.0, factor=2.0, max_s=8.0, jitter=0.1,

@@ -151,7 +151,7 @@ class TestFlashAttentionHelper:
 class TestCausalFlashAttentionHelper:
     """causal=True flash helper serves causal layers through the seam (the
     causal flag is part of the request since the decoder work); measured on
-    v5e: 1.45x LM train step at T=2048, 2.64x at T=4096 (BASELINE.md)."""
+    v5e: 1.45x LM train step at T=2048, 2.64x at T=4096 (rounds 1-5, not re-measured: PERF.md)."""
 
     def test_causal_gating(self):
         from deeplearning4j_tpu.nn.pallas_kernels import PallasFlashAttentionHelper
@@ -316,7 +316,7 @@ class TestPallasUpdaterHelper:
     def test_supports_gating(self):
         from deeplearning4j_tpu.nn.pallas_kernels import PallasUpdaterHelper
         from deeplearning4j_tpu.nn.updaters import Adam, Sgd
-        h = PallasUpdaterHelper()
+        h = PallasUpdaterHelper(interpret=True)
         p = jnp.zeros((24, 16), jnp.float32)
         assert h.supports(Adam(1e-3), p, p)
         assert not h.supports(Sgd(1e-2), p, p)  # no state to fuse
@@ -345,7 +345,7 @@ class TestPallasUpdaterHelper:
         fused = _mlp_net(upd)
         for _ in range(3):
             stock._fit_batch(ds)
-        helpers.set_helper("updater", PallasUpdaterHelper())
+        helpers.set_helper("updater", PallasUpdaterHelper(interpret=True))
         for _ in range(3):
             fused._fit_batch(ds)
         for lb, lf in zip(stock.params, fused.params):
@@ -369,7 +369,7 @@ class TestPallasUpdaterHelper:
                 calls.append(param.shape)
                 return super().apply(updater, param, grad, state, lr, t)
 
-        helpers.set_helper("updater", Spy())
+        helpers.set_helper("updater", Spy(interpret=True))
         net._fit_batch(ds)
         # consulted once per fusable tensor (w+b per layer), despite the
         # already-compiled stock step: registry version keys the jit cache
@@ -388,7 +388,7 @@ class TestPallasUpdaterHelper:
         net = _mlp_net(Adam(1e-3))
         ds = _mlp_data(rng)
         assert _count_pallas_eqns(_train_step_jaxpr(net, ds)) == 0
-        helpers.set_helper("updater", PallasUpdaterHelper())
+        helpers.set_helper("updater", PallasUpdaterHelper(interpret=True))
         assert _count_pallas_eqns(_train_step_jaxpr(net, ds)) == 4
 
     def test_nonsquare_and_vector_params_pad_correctly(self, rng):

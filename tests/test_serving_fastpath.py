@@ -606,6 +606,21 @@ class TestInt8Inference:
 
 # -------------------------------------------------------- persistent cache
 class TestPersistentCompileCache:
+    @pytest.fixture(autouse=True)
+    def _explicit_dir_only(self, monkeypatch):
+        """These tests place the cache by argument. A cache the environment
+        placed would (rightly) refuse that, so the variable goes, and with
+        it whatever cache jax already opened under it."""
+        import jax
+        from jax._src import compilation_cache as jax_cc
+
+        from deeplearning4j_tpu.util import compile_cache
+        if os.environ.get(compile_cache.ENV_VAR):
+            monkeypatch.delenv(compile_cache.ENV_VAR)
+            monkeypatch.setattr(compile_cache, "_enabled_dir", None)
+            jax.config.update("jax_compilation_cache_dir", None)
+            jax_cc.reset_cache()
+
     def test_registry_populates_cache_dir(self, tmp_path):
         cache = tmp_path / "xla-cache"
         registry = ModelRegistry(buckets=[2], warmup="sync",
@@ -620,8 +635,8 @@ class TestPersistentCompileCache:
     def test_retarget_rejected(self, tmp_path):
         from deeplearning4j_tpu.util.compile_cache import (
             enable_persistent_compile_cache, persistent_compile_cache_dir)
-        active = persistent_compile_cache_dir()
-        assert active is not None  # latched by the test above or this one
+        enable_persistent_compile_cache(
+            persistent_compile_cache_dir() or str(tmp_path / "first"))
         with pytest.raises(ValueError, match="already active"):
             enable_persistent_compile_cache(str(tmp_path / "elsewhere"))
 

@@ -202,7 +202,7 @@ class TestDonationAudit:
         from deeplearning4j_tpu.nn.pallas_kernels import PallasUpdaterHelper
         net = _net(seed=6)
         ds = _dataset(rng, b=16)
-        helpers.set_helper("updater", PallasUpdaterHelper())
+        helpers.set_helper("updater", PallasUpdaterHelper(interpret=True))
         fn = net._get_train_step(False)
         hlo = fn.lower(*_train_step_args(net, ds)).compile().as_text()
         assert "input_output_alias" in hlo
