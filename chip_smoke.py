@@ -22,9 +22,12 @@ It fails loudly. The platform must be ``tpu`` (JAX falls back to the CPU
 by itself when no chip answers; this script does not). No phase is wrapped
 in a handler that records an error and goes on: the first failed check
 raises, the exit code is non-zero and no result line is printed. On
-success the last line of stdout is one JSON object with the device facts
-and every phase's result; the same object is written, with the profile
-trace, under ``chiprun_out/chip_smoke/``.
+success the last two lines of stdout are ``report `` followed by one JSON
+object with the versions, every phase's result and the compile-cache
+counts (the same object is written, with the profile trace, under
+``chiprun_out/chip_smoke/``), and then the result line, which holds
+exactly ``{"ok": true, "device": {"platform", "kind", "count"}}`` with the
+device as JAX reports it.
 
 Depth is the zoo default and weights are random from a seed. Times printed
 here are set-up facts (how long a cold and a warm start take), not
@@ -83,7 +86,7 @@ def device_facts() -> dict:
                 for name in ("jax", "jaxlib", "libtpu")}
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": jax.device_count()}
+              "count": len(jax.devices())}
     say("  ".join(f"{k} {v}" for k, v in versions.items()))
     say(f"platform={device['platform']}  device_kind={device['kind']!r}  "
         f"device_count={device['count']}")
@@ -93,6 +96,14 @@ def device_facts() -> dict:
               file=sys.stderr, flush=True)
         raise SystemExit(3)
     return {"device": device, "versions": versions}
+
+
+def result_line(device: dict) -> str:
+    """The last line of stdout: these keys and no others."""
+    return json.dumps({"ok": True,
+                       "device": {"platform": str(device["platform"]),
+                                  "kind": str(device["kind"]),
+                                  "count": int(device["count"])}})
 
 
 class CompileMeter:
@@ -703,7 +714,7 @@ def main(argv=None) -> int:
             f"(compile {phases[name]['info_compile_seconds']} s, "
             f"{phases[name]['cache_hits']} cache hits)")
 
-    result = {
+    report = {
         "ok": True,
         **facts,
         "phases": phases,
@@ -715,11 +726,12 @@ def main(argv=None) -> int:
     }
     say(f"compile cache hits {meter.hits}, misses {meter.misses}, "
         f"compile seconds {meter.seconds:.1f}")
-    line = json.dumps(result)
+    line = json.dumps(report)
     with open(os.path.join(OUT_DIR, "result.json"), "w",
               encoding="utf-8") as fh:
         fh.write(line + "\n")
-    print(line, flush=True)
+    say("report " + line)
+    say(result_line(facts["device"]))
     return 0
 
 

@@ -3,6 +3,7 @@ refuses the CPU, the compile cache lands where the environment says, and
 every Pallas kernel the program can reach lowers for the TPU from here
 (the Mosaic module is built; only the chip can compile and run it)."""
 
+import json
 import os
 import subprocess
 import sys
@@ -25,6 +26,22 @@ def test_chip_smoke_refuses_cpu():
     assert "platform 'cpu'" in r.stderr
     assert "platform=cpu" in r.stdout  # the facts are printed first
     assert not r.stdout.rstrip().endswith("}")  # and no result line
+
+
+def test_chip_smoke_result_line_has_the_contract_keys_only():
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    dev = jax.devices()[0]
+    line = mod.result_line({"platform": dev.platform, "kind": dev.device_kind,
+                            "count": len(jax.devices())})
+    assert "\n" not in line
+    got = json.loads(line)
+    assert got == {"ok": True, "device": {"platform": dev.platform,
+                                          "kind": dev.device_kind,
+                                          "count": len(jax.devices())}}
 
 
 # --------------------------------------------------- cache dir resolution
