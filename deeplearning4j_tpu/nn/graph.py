@@ -25,6 +25,7 @@ from deeplearning4j_tpu.nn.constraints import apply_constraints
 from deeplearning4j_tpu.nn.layers.base import Layer
 from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrentLayer, check_carry_capacity
 from deeplearning4j_tpu.nn.updaters import Sgd, Updater, normalize_gradients
+from deeplearning4j_tpu.observe import scope as _scope, trace as _trace
 
 Array = jax.Array
 Params = Dict[str, Dict[str, Array]]
@@ -156,8 +157,9 @@ class ComputationGraph:
             cast = lambda a: (a.astype(cd)
                               if hasattr(a, "dtype")
                               and jnp.issubdtype(a.dtype, jnp.floating) else a)
-            params = jax.tree_util.tree_map(cast, params)
-            inputs = {k: cast(v) for k, v in inputs.items()}
+            with jax.named_scope(_scope.CAST_PARAMS):
+                params = jax.tree_util.tree_map(cast, params)
+                inputs = {k: cast(v) for k, v in inputs.items()}
         acts: Dict[str, Array] = dict(inputs)
         m: Dict[str, Optional[Array]] = dict(masks or {})
         for name in conf.inputs:
@@ -170,57 +172,58 @@ class ComputationGraph:
             vd = conf.vertices[name]
             in_acts = [acts[s] for s in vd.inputs]
             in_masks = [m.get(s) for s in vd.inputs]
-            if vd.is_layer:
-                layer: Layer = vd.obj  # type: ignore[assignment]
-                p_v, rng_v = params[name], rngs[vi]
-                if (getattr(layer, "weight_noise", None) is not None and train
-                        and rng_v is not None):
-                    # train-time weight noise (DropConnect.java:19, MLN
-                    # parity) — applied before BOTH the single- and
-                    # multi-input forward paths
-                    rng_wn, rng_v = jax.random.split(rng_v)
-                    p_v = layer.weight_noise.apply(layer, p_v, rng_wn, train)
-                if getattr(layer, "consumes_multiple_inputs", False):
-                    y, st = layer.forward_multi(
-                        p_v, in_acts, state=states[name], train=train,
-                        rng=rng_v, masks=in_masks)
-                    new_states[name] = st if st else states[name]
-                    acts[name] = y
-                    m[name] = in_masks[0]
-                    continue
-                h = in_acts[0] if len(in_acts) == 1 else jnp.concatenate(in_acts, -1)
-                if name in conf.preprocessors:
-                    h = conf.preprocessors[name](h)
-                cur_mask = in_masks[0]
-                if layer.has_loss():
-                    acts[name + ":in"] = h
-                    acts[name + ":mask"] = cur_mask
-                if carries is not None and isinstance(layer, BaseRecurrentLayer):
-                    y, c = layer.forward_seq(p_v, h, carry=carries.get(name),
-                                             mask=cur_mask, train=train, rng=rng_v)
-                    new_states[name] = states[name]
-                    new_carries[name] = c
-                    acts[name] = y
+            with _scope.layer_scope(name, vd.obj):
+                if vd.is_layer:
+                    layer: Layer = vd.obj  # type: ignore[assignment]
+                    p_v, rng_v = params[name], rngs[vi]
+                    if (getattr(layer, "weight_noise", None) is not None and train
+                            and rng_v is not None):
+                        # train-time weight noise (DropConnect.java:19, MLN
+                        # parity) — applied before BOTH the single- and
+                        # multi-input forward paths
+                        rng_wn, rng_v = jax.random.split(rng_v)
+                        p_v = layer.weight_noise.apply(layer, p_v, rng_wn, train)
+                    if getattr(layer, "consumes_multiple_inputs", False):
+                        y, st = layer.forward_multi(
+                            p_v, in_acts, state=states[name], train=train,
+                            rng=rng_v, masks=in_masks)
+                        new_states[name] = st if st else states[name]
+                        acts[name] = y
+                        m[name] = in_masks[0]
+                        continue
+                    h = in_acts[0] if len(in_acts) == 1 else jnp.concatenate(in_acts, -1)
+                    if name in conf.preprocessors:
+                        h = conf.preprocessors[name](h)
+                    cur_mask = in_masks[0]
+                    if layer.has_loss():
+                        acts[name + ":in"] = h
+                        acts[name + ":mask"] = cur_mask
+                    if carries is not None and isinstance(layer, BaseRecurrentLayer):
+                        y, c = layer.forward_seq(p_v, h, carry=carries.get(name),
+                                                 mask=cur_mask, train=train, rng=rng_v)
+                        new_states[name] = states[name]
+                        new_carries[name] = c
+                        acts[name] = y
+                    else:
+                        fwd = lambda p, hh, _l=layer, _n=name, _r=rng_v: _l.forward(
+                            p, hh, state=states[_n], train=train, rng=_r,
+                            mask=cur_mask)
+                        if train and conf.global_conf.gradient_checkpointing:
+                            # rematerialize activations in the backward pass
+                            fwd = jax.checkpoint(fwd)
+                        y, st = fwd(p_v, h)
+                        new_states[name] = st if st else states[name]
+                        acts[name] = y
+                    # per-timestep mask collapses when the time dim disappears;
+                    # per-example [N]/[N,1] masks survive (MLN parity)
+                    if (cur_mask is not None and acts[name].ndim == 2
+                            and cur_mask.ndim == 2 and cur_mask.shape[1] > 1):
+                        m[name] = None
+                    else:
+                        m[name] = cur_mask
                 else:
-                    fwd = lambda p, hh, _l=layer, _n=name, _r=rng_v: _l.forward(
-                        p, hh, state=states[_n], train=train, rng=_r,
-                        mask=cur_mask)
-                    if train and conf.global_conf.gradient_checkpointing:
-                        # rematerialize activations in the backward pass
-                        fwd = jax.checkpoint(fwd)
-                    y, st = fwd(p_v, h)
-                    new_states[name] = st if st else states[name]
-                    acts[name] = y
-                # per-timestep mask collapses when the time dim disappears;
-                # per-example [N]/[N,1] masks survive (MLN parity)
-                if (cur_mask is not None and acts[name].ndim == 2
-                        and cur_mask.ndim == 2 and cur_mask.shape[1] > 1):
-                    m[name] = None
-                else:
-                    m[name] = cur_mask
-            else:
-                acts[name] = vd.obj.forward(in_acts, in_masks)  # type: ignore[union-attr]
-                m[name] = vd.obj.output_mask(in_masks)  # type: ignore[union-attr]
+                    acts[name] = vd.obj.forward(in_acts, in_masks)  # type: ignore[union-attr]
+                    m[name] = vd.obj.output_mask(in_masks)  # type: ignore[union-attr]
         return acts, new_states, m, (new_carries if carries is not None else None)
 
     def _regularization(self, params: Params) -> Array:
@@ -277,8 +280,11 @@ class ComputationGraph:
                 rng_v = jax.random.split(rng, max(1, len(topo)))[vi]
                 rng_wn = jax.random.split(rng_v)[0]
                 p_out = layer.weight_noise.apply(layer, p_out, rng_wn, train)
-            loss = loss + layer.compute_loss(p_out, h, labels[oi], mask=lm)
-        loss = loss + self._regularization(params)
+            with _scope.layer_scope(out_name, layer), \
+                    jax.named_scope(_scope.LOSS):
+                loss = loss + layer.compute_loss(p_out, h, labels[oi], mask=lm)
+        with jax.named_scope(_scope.REGULARIZATION):
+            loss = loss + self._regularization(params)
         return loss, (new_states, new_carries)
 
     # ------------------------------------------------------------ train step
@@ -292,26 +298,28 @@ class ComputationGraph:
         for vd in self.conf.layer_vertices():
             name = vd.name
             l: Layer = vd.obj  # type: ignore[assignment]
-            g_layer = grads[name]
-            if l.gradient_normalization:
-                g_layer = normalize_gradients(g_layer, l.gradient_normalization,
-                                              l.gradient_normalization_threshold)
-            p_new, s_new = {}, {}
-            for n, g in g_layer.items():
-                u = self._updaters[name][n]
-                lr = u.lr_at(it, ep)
-                if uhelper is not None and uhelper.supports(u, params[name][n], g):
-                    p_new[n], s_new[n] = uhelper.apply(
-                        u, params[name][n], g, upd_states[name][n], lr,
-                        it + 1.0)
-                    continue
-                upd, s = u.update(g, upd_states[name][n], lr, it + 1.0)
-                p_new[n] = params[name][n] - upd.astype(params[name][n].dtype)
-                s_new[n] = s
-            # post-update constraints (BaseConstraint.applyConstraint parity)
-            p_new = apply_constraints(l, p_new)
-            new_params[name] = p_new
-            new_upd[name] = s_new
+            with jax.named_scope(_scope.OPTIMIZER), \
+                    _scope.layer_scope(name, l):
+                g_layer = grads[name]
+                if l.gradient_normalization:
+                    g_layer = normalize_gradients(g_layer, l.gradient_normalization,
+                                                  l.gradient_normalization_threshold)
+                p_new, s_new = {}, {}
+                for n, g in g_layer.items():
+                    u = self._updaters[name][n]
+                    lr = u.lr_at(it, ep)
+                    if uhelper is not None and uhelper.supports(u, params[name][n], g):
+                        p_new[n], s_new[n] = uhelper.apply(
+                            u, params[name][n], g, upd_states[name][n], lr,
+                            it + 1.0)
+                        continue
+                    upd, s = u.update(g, upd_states[name][n], lr, it + 1.0)
+                    p_new[n] = params[name][n] - upd.astype(params[name][n].dtype)
+                    s_new[n] = s
+                # post-update constraints (BaseConstraint.applyConstraint parity)
+                p_new = apply_constraints(l, p_new)
+                new_params[name] = p_new
+                new_upd[name] = s_new
         return new_params, new_upd
 
     def _evict_stale(self, current_version: int) -> None:
@@ -324,8 +332,8 @@ class ComputationGraph:
         if key not in self._jit_cache:
             self._evict_stale(_helpers.version())
 
-            def step(params, states, upd_states, it, ep, inputs, labels,
-                     masks, label_masks, rng, carries=None):
+            def train_step(params, states, upd_states, it, ep, inputs, labels,
+                           masks, label_masks, rng, carries=None):
                 # on-device key split + returned (it+1, next key): the fit
                 # loop re-feeds them with zero per-step host-side device
                 # ops
@@ -344,7 +352,10 @@ class ComputationGraph:
                 return (new_params, new_states, new_upd, loss, new_carries,
                         it + 1.0, rng_next)
 
-            self._jit_cache[key] = jax.jit(step, donate_argnums=(0, 1, 2, 3, 9))
+            # the program's name in the device trace and the HLO
+            train_step.__name__ = "tbptt_step" if with_carries else "train_step"
+            self._jit_cache[key] = jax.jit(train_step,
+                                           donate_argnums=(0, 1, 2, 3, 9))
         return self._jit_cache[key]
 
     def _get_multi_train_step(self):
@@ -357,8 +368,8 @@ class ComputationGraph:
         if key not in self._jit_cache:
             self._evict_stale(_helpers.version())
 
-            def multi(params, states, upd_states, it0, ep, inputs_s,
-                      labels_s, rng0):
+            def train_steps_scan(params, states, upd_states, it0, ep, inputs_s,
+                                 labels_s, rng0):
                 def body(carry, xs):
                     params, states, upd, it, rng = carry
                     inputs, labels = xs
@@ -381,7 +392,8 @@ class ComputationGraph:
                     (inputs_s, labels_s))
                 return params, states, upd, losses
 
-            self._jit_cache[key] = jax.jit(multi, donate_argnums=(0, 1, 2))
+            self._jit_cache[key] = jax.jit(train_steps_scan,
+                                           donate_argnums=(0, 1, 2))
         return self._jit_cache[key]
 
     def fit_batches_on_device(self, datasets) -> "ComputationGraph":
@@ -422,9 +434,7 @@ class ComputationGraph:
         for i in range(k):
             self._score_arr = losses[i]
             self.iteration += 1
-            for listener in self.listeners:
-                if hasattr(listener, "iteration_done"):
-                    listener.iteration_done(self, self.iteration, self.epoch)
+            self._iteration_done()
         return self
 
     # ------------------------------------------------------------------- fit
@@ -434,14 +444,21 @@ class ComputationGraph:
         prefetch (see MultiLayerNetwork.fit): ``prefetch_depth`` queue
         slots (default 2), 0 disables, ``async_supported = False`` opts
         out; ``host_wait`` span + ``training_transfer_bytes_total`` expose
-        any residual input-pipeline stall."""
+        any residual input-pipeline stall.
+
+        Under ``observe.enable_tracing()`` each step records three spans:
+        ``host_wait`` (the wait for its batch), ``step_dispatch`` (the call
+        of the jitted step, attribute ``iteration``; a compile it pays for
+        nests under it) and ``listeners``. None of them waits for the
+        device. The step's name scopes (``observe/scope.py``) are always on:
+        the compiled program is ``jit_train_step`` and its operations carry
+        their layer's ``Class:name``."""
         if self.params is None:
             self.init()
         from deeplearning4j_tpu.datasets.dataset import (DataSet,
                                                          MultiDataSet,
                                                          batch_nbytes)
         from deeplearning4j_tpu.datasets.iterators import wrap_for_prefetch
-        from deeplearning4j_tpu.observe import trace as _trace
 
         if labels is not None:
             iterator = [MultiDataSet(
@@ -509,14 +526,35 @@ class ComputationGraph:
 
         step = self._get_train_step()
         it, ep, rng = self._device_tick()
-        (self.params, self.states, self.updater_states, loss, _,
-         new_it, new_rng) = step(
-            self.params, self.states, self.updater_states, it, ep,
-            inputs, labels, masks, lmasks, rng)
+        # Two spans under tracing, and with it off no span and no context
+        # manager. Neither span's body reads a device value, so neither
+        # drains the device; a compile the call pays for nests under its
+        # step_dispatch. The step is called from one line either way: a
+        # Pallas kernel's compiled form carries its call stack, so a second
+        # call site would be a second program in the compile cache.
+        tracer = _trace.get_active_tracer()
+        opened = None if tracer is None else tracer.enter_span(
+            "step_dispatch", category="train",
+            attrs={"iteration": self.iteration})
+        try:
+            (self.params, self.states, self.updater_states, loss, _,
+             new_it, new_rng) = step(
+                self.params, self.states, self.updater_states, it, ep,
+                inputs, labels, masks, lmasks, rng)
+        finally:
+            if opened is not None:
+                tracer.exit_span(*opened)
         self._score_arr = loss
         self.last_batch_size = int(next(iter(inputs.values())).shape[0])
         self.iteration += 1
         self._store_tick(new_it, new_rng)
+        if tracer is None:
+            self._iteration_done()
+        else:
+            with tracer.span("listeners", category="train"):
+                self._iteration_done()
+
+    def _iteration_done(self) -> None:
         for listener in self.listeners:
             if hasattr(listener, "iteration_done"):
                 listener.iteration_done(self, self.iteration, self.epoch)
@@ -581,9 +619,7 @@ class ComputationGraph:
             self._score_arr = loss
             self.iteration += 1
             self._store_tick(new_it, new_rng)
-        for listener in self.listeners:
-            if hasattr(listener, "iteration_done"):
-                listener.iteration_done(self, self.iteration, self.epoch)
+        self._iteration_done()
 
     # ------------------------------------------------------------- inference
     def _output_fn(self):

@@ -33,6 +33,7 @@ from deeplearning4j_tpu.nn.updaters import (
     normalize_gradients,
     schedule_value,
 )
+from deeplearning4j_tpu.observe import scope as _scope, trace as _trace
 
 Array = jax.Array
 Params = List[Dict[str, Array]]
@@ -157,8 +158,9 @@ class MultiLayerNetwork:
             cast = lambda a: (a.astype(cd)
                               if hasattr(a, "dtype")
                               and jnp.issubdtype(a.dtype, jnp.floating) else a)
-            params = jax.tree_util.tree_map(cast, params)
-            x = cast(x)
+            with jax.named_scope(_scope.CAST_PARAMS):
+                params = jax.tree_util.tree_map(cast, params)
+                x = cast(x)
         h = x
         new_states: States = []
         new_carries: List[Any] = []
@@ -171,32 +173,33 @@ class MultiLayerNetwork:
                 new_carries.append(None if carries is None else carries[i])
                 continue
             layer = self.layers[i]
-            if i in self.conf.preprocessors:
-                h = self.conf.preprocessors[i](h)
-            p_i, rng_i = params[i], rngs[i]
-            if (getattr(layer, "weight_noise", None) is not None and train
-                    and rng_i is not None):
-                # IWeightNoise (DropConnect/WeightNoise): noise the WEIGHTS
-                # at forward time, train only (weightnoise/DropConnect.java:19)
-                rng_wn, rng_i = jax.random.split(rng_i)
-                p_i = layer.weight_noise.apply(layer, p_i, rng_wn, train)
-            if carries is not None and isinstance(layer, BaseRecurrentLayer):
-                y, c = layer.forward_seq(p_i, h, carry=carries[i], mask=cur_mask,
-                                         train=train, rng=rng_i)
-                new_states.append(states[i])
-                new_carries.append(c)
-                h = y
-            else:
-                fwd = lambda p, hh, _l=layer, _i=i, _r=rng_i: _l.forward(
-                    p, hh, state=states[_i], train=train, rng=_r,
-                    mask=cur_mask)
-                if train and self.conf.global_conf.gradient_checkpointing:
-                    # rematerialize this layer's activations in the backward
-                    # pass instead of storing them (HBM ↔ FLOPs trade)
-                    fwd = jax.checkpoint(fwd)
-                h, st = fwd(p_i, h)
-                new_states.append(st if st else states[i])
-                new_carries.append(None)
+            with _scope.layer_scope(i, layer):
+                if i in self.conf.preprocessors:
+                    h = self.conf.preprocessors[i](h)
+                p_i, rng_i = params[i], rngs[i]
+                if (getattr(layer, "weight_noise", None) is not None and train
+                        and rng_i is not None):
+                    # IWeightNoise (DropConnect/WeightNoise): noise the WEIGHTS
+                    # at forward time, train only (weightnoise/DropConnect.java:19)
+                    rng_wn, rng_i = jax.random.split(rng_i)
+                    p_i = layer.weight_noise.apply(layer, p_i, rng_wn, train)
+                if carries is not None and isinstance(layer, BaseRecurrentLayer):
+                    y, c = layer.forward_seq(p_i, h, carry=carries[i], mask=cur_mask,
+                                             train=train, rng=rng_i)
+                    new_states.append(states[i])
+                    new_carries.append(c)
+                    h = y
+                else:
+                    fwd = lambda p, hh, _l=layer, _i=i, _r=rng_i: _l.forward(
+                        p, hh, state=states[_i], train=train, rng=_r,
+                        mask=cur_mask)
+                    if train and self.conf.global_conf.gradient_checkpointing:
+                        # rematerialize this layer's activations in the backward
+                        # pass instead of storing them (HBM ↔ FLOPs trade)
+                        fwd = jax.checkpoint(fwd)
+                    h, st = fwd(p_i, h)
+                    new_states.append(st if st else states[i])
+                    new_carries.append(None)
             # per-TIMESTEP masks collapse when the time dimension disappears;
             # a per-example [N]/[N,1] mask stays valid on 2d activations
             if (cur_mask is not None and h.ndim == 2 and cur_mask.ndim == 2
@@ -226,11 +229,13 @@ class MultiLayerNetwork:
         h, new_states, new_carries = self._forward_all(
             params, states, x, train=train, rng=rng, mask=mask, carries=carries,
             upto=len(self.layers) - 1)
-        if (len(self.layers) - 1) in self.conf.preprocessors:
-            h = self.conf.preprocessors[len(self.layers) - 1](h)
-        if self.conf.global_conf.compute_dtype is not None:
-            # loss head in f32 for stable softmax/log under mixed precision
-            h = h.astype(jnp.float32)
+        last = len(self.layers) - 1
+        with _scope.layer_scope(last, out_layer):
+            if last in self.conf.preprocessors:
+                h = self.conf.preprocessors[last](h)
+            if self.conf.global_conf.compute_dtype is not None:
+                # loss head in f32 for stable softmax/log under mixed precision
+                h = h.astype(jnp.float32)
         if label_mask is not None:
             lm = label_mask
         elif mask is None:
@@ -250,8 +255,11 @@ class MultiLayerNetwork:
             p_out = out_layer.weight_noise.apply(
                 out_layer, p_out, jax.random.fold_in(rng, len(self.layers)),
                 train)
-        loss = out_layer.compute_loss(p_out, h, y, mask=lm)
-        loss = loss + self._regularization(params)
+        with _scope.layer_scope(last, out_layer), \
+                jax.named_scope(_scope.LOSS):
+            loss = out_layer.compute_loss(p_out, h, y, mask=lm)
+        with jax.named_scope(_scope.REGULARIZATION):
+            loss = loss + self._regularization(params)
         return loss, (new_states, new_carries)
 
     # ------------------------------------------------------------ train step
@@ -263,32 +271,34 @@ class MultiLayerNetwork:
         uhelper = _helpers.get_helper("updater")
         new_params, new_upd = [], []
         for i, l in enumerate(self.layers):
-            g_layer = grads[i]
-            if l.gradient_normalization:
-                g_layer = normalize_gradients(g_layer, l.gradient_normalization,
-                                              l.gradient_normalization_threshold)
-            p_new, s_new = {}, {}
-            for n, g in g_layer.items():
-                u = self._updaters[i][n]
-                lr = u.lr_at(it, ep)
-                t = it + 1.0  # 1-based step count for Adam-family bias correction
-                if uhelper is not None and uhelper.supports(u, params[i][n], g):
-                    p_new[n], s_new[n] = uhelper.apply(
-                        u, params[i][n], g, upd_states[i][n], lr, t)
-                    continue
-                upd, s = u.update(g, upd_states[i][n], lr, t)
-                p_new[n] = params[i][n] - upd.astype(params[i][n].dtype)
-                s_new[n] = s
-            # post-update parameter constraints (BaseConstraint.applyConstraint
-            # runs after each iteration in the reference) — fused into the
-            # jitted step, not a separate host pass
-            p_new = apply_constraints(l, p_new)
-            new_params.append(p_new)
-            new_upd.append(s_new)
+            with jax.named_scope(_scope.OPTIMIZER), \
+                    _scope.layer_scope(i, l):
+                g_layer = grads[i]
+                if l.gradient_normalization:
+                    g_layer = normalize_gradients(g_layer, l.gradient_normalization,
+                                                  l.gradient_normalization_threshold)
+                p_new, s_new = {}, {}
+                for n, g in g_layer.items():
+                    u = self._updaters[i][n]
+                    lr = u.lr_at(it, ep)
+                    t = it + 1.0  # 1-based step count for Adam-family bias correction
+                    if uhelper is not None and uhelper.supports(u, params[i][n], g):
+                        p_new[n], s_new[n] = uhelper.apply(
+                            u, params[i][n], g, upd_states[i][n], lr, t)
+                        continue
+                    upd, s = u.update(g, upd_states[i][n], lr, t)
+                    p_new[n] = params[i][n] - upd.astype(params[i][n].dtype)
+                    s_new[n] = s
+                # post-update parameter constraints (BaseConstraint.applyConstraint
+                # runs after each iteration in the reference) — fused into the
+                # jitted step, not a separate host pass
+                p_new = apply_constraints(l, p_new)
+                new_params.append(p_new)
+                new_upd.append(s_new)
         return new_params, new_upd
 
     def _build_train_step(self, tbptt: bool):
-        def step(params, states, upd_states, it, ep, x, y, mask, label_mask, rng, carries):
+        def train_step(params, states, upd_states, it, ep, x, y, mask, label_mask, rng, carries):
             # split on DEVICE and return the next key + iteration: the fit
             # loop then re-feeds them without any per-step host-side device
             # ops (a host rng split + two scalar placements)
@@ -306,7 +316,9 @@ class MultiLayerNetwork:
                 new_carries = jax.tree_util.tree_map(jax.lax.stop_gradient, new_carries)
             return new_params, new_states, new_upd, loss, new_carries, it + 1.0, rng_next
 
-        return jax.jit(step, donate_argnums=(0, 1, 2, 3, 9))
+        # the program's name in the device trace and the HLO
+        train_step.__name__ = "tbptt_step" if tbptt else "train_step"
+        return jax.jit(train_step, donate_argnums=(0, 1, 2, 3, 9))
 
     def _get_train_step(self, tbptt: bool):
         key = ("train", tbptt)
@@ -334,13 +346,20 @@ class MultiLayerNetwork:
         the queue depth (default 2 — double buffering); 0 disables.
         Iterators with ``async_supported = False`` (AsyncShield) are never
         wrapped. The per-batch wait shows up as a ``host_wait`` trace span
-        and the shipped payload as ``training_transfer_bytes_total``."""
+        and the shipped payload as ``training_transfer_bytes_total``.
+
+        Under ``observe.enable_tracing()`` each step records three spans:
+        ``host_wait``, ``step_dispatch`` (the call of the jitted step,
+        attribute ``iteration``; a compile it pays for nests under it) and
+        ``listeners``. None of them waits for the device. The step's name
+        scopes (``observe/scope.py``) are always on: the compiled program
+        is ``jit_train_step`` and its operations carry their layer's
+        ``Class:index``."""
         if self.params is None:
             self.init()
         from deeplearning4j_tpu.datasets.dataset import (DataSet,  # no cycle
                                                          batch_nbytes)
         from deeplearning4j_tpu.datasets.iterators import wrap_for_prefetch
-        from deeplearning4j_tpu.observe import trace as _trace
 
         if labels is not None:
             iterator = [DataSet(data, labels, features_mask, labels_mask)]
@@ -382,7 +401,7 @@ class MultiLayerNetwork:
         if key not in self._jit_cache:
             self._evict_stale(_helpers.version())
 
-            def multi(params, states, upd_states, it0, ep, xs, ys, rng0):
+            def train_steps_scan(params, states, upd_states, it0, ep, xs, ys, rng0):
                 def body(carry, batch):
                     params, states, upd, it, rng = carry
                     x, y = batch
@@ -404,7 +423,8 @@ class MultiLayerNetwork:
                     body, (params, states, upd_states, it0, rng0), (xs, ys))
                 return params, states, upd, losses
 
-            self._jit_cache[key] = jax.jit(multi, donate_argnums=(0, 1, 2))
+            self._jit_cache[key] = jax.jit(train_steps_scan,
+                                           donate_argnums=(0, 1, 2))
         return self._jit_cache[key]
 
     def fit_batches_on_device(self, datasets) -> "MultiLayerNetwork":
@@ -437,9 +457,7 @@ class MultiLayerNetwork:
         for i in range(len(datasets)):
             self._score_arr = losses[i]
             self.iteration += 1
-            for listener in self.listeners:
-                if hasattr(listener, "iteration_done"):
-                    listener.iteration_done(self, self.iteration, self.epoch)
+            self._iteration_done()
         return self
 
     def _fit_batch(self, ds) -> None:
@@ -463,14 +481,35 @@ class MultiLayerNetwork:
 
         step = self._get_train_step(False)
         it, ep, rng = self._device_tick()
-        (self.params, self.states, self.updater_states, loss, _,
-         new_it, new_rng) = step(
-            self.params, self.states, self.updater_states, it, ep,
-            x, y, mask, lmask, rng, None)
+        # Two spans under tracing, and with it off no span and no context
+        # manager. Neither span's body reads a device value, so neither
+        # drains the device; a compile the call pays for nests under its
+        # step_dispatch. The step is called from one line either way: a
+        # Pallas kernel's compiled form carries its call stack, so a second
+        # call site would be a second program in the compile cache.
+        tracer = _trace.get_active_tracer()
+        opened = None if tracer is None else tracer.enter_span(
+            "step_dispatch", category="train",
+            attrs={"iteration": self.iteration})
+        try:
+            (self.params, self.states, self.updater_states, loss, _,
+             new_it, new_rng) = step(
+                self.params, self.states, self.updater_states, it, ep,
+                x, y, mask, lmask, rng, None)
+        finally:
+            if opened is not None:
+                tracer.exit_span(*opened)
         self._score_arr = loss
         self.last_batch_size = int(x.shape[0])
         self.iteration += 1
         self._store_tick(new_it, new_rng)
+        if tracer is None:
+            self._iteration_done()
+        else:
+            with tracer.span("listeners", category="train"):
+                self._iteration_done()
+
+    def _iteration_done(self) -> None:
         for listener in self.listeners:
             if hasattr(listener, "iteration_done"):
                 listener.iteration_done(self, self.iteration, self.epoch)
@@ -507,9 +546,7 @@ class MultiLayerNetwork:
             self._score_arr = loss
             self.iteration += 1
             self._store_tick(new_it, new_rng)
-        for listener in self.listeners:
-            if hasattr(listener, "iteration_done"):
-                listener.iteration_done(self, self.iteration, self.epoch)
+        self._iteration_done()
 
     # ------------------------------------------------------------- inference
     def _output_fn(self):

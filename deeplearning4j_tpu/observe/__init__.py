@@ -7,11 +7,18 @@ Dapper-style request tracing the reference never had):
 - ``trace``    — ``Span``/``Tracer``/``TraceRecorder``: contextvar-nested
   spans, explicit cross-thread handoff, W3C ``traceparent`` in/out,
   bounded ring buffer; ``enable_tracing()`` flips every instrumented hot
-  path (ParallelWrapper steps, the ParallelInference dispatcher, the
-  ModelServer request path, streaming routes) from no-op to recording;
+  path (``fit()``'s own ``host_wait`` / ``step_dispatch`` / ``listeners``
+  spans in both engines, ParallelWrapper steps, the ParallelInference
+  dispatcher, the ModelServer request path, streaming routes) from no-op to
+  recording;
+- ``scope``    — the ``jax.named_scope`` labels the train step carries into
+  the compiled HLO and the device trace (``Class:name`` per layer, ``loss``,
+  ``regularization``, ``optimizer``, ``cast_params``). Metadata only: they
+  cost nothing when the step runs, so they are always on, tracing or not;
 - ``jaxhook``  — JAX compile/lowering attribution: ``jax.monitoring``
   events become ``xla_compile``/``jax_lowering`` spans nested under
-  whatever span triggered them, so recompiles show up loudly;
+  whatever span triggered them (a train step's under its ``step_dispatch``),
+  so recompiles show up loudly;
 - ``export``   — Chrome trace-event JSON (``chrome://tracing``/Perfetto)
   with flow arrows across threads, plus a terminal text timeline;
 - ``metrics``  — the Prometheus registry core (promoted from

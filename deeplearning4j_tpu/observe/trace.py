@@ -222,17 +222,28 @@ class Tracer:
         """Open a span as the current context; on exit it is timed, closed
         and recorded — even when the body raises (the error is noted on the
         span, then propagates)."""
-        sp = self.start_span(name, parent=parent, category=category,
-                             attrs=attrs)
-        token = _current_ctx.set((sp.trace_id, sp.span_id))
+        sp, token = self.enter_span(name, parent=parent, category=category,
+                                    attrs=attrs)
         try:
             yield sp
         except BaseException as e:
             sp.error = f"{type(e).__name__}: {e}"
             raise
         finally:
-            _current_ctx.reset(token)
-            self.end_span(sp)
+            self.exit_span(sp, token)
+
+    def enter_span(self, name: str, **kw) -> Tuple[Span, Any]:
+        """:meth:`span` without the ``with``: open a span as the current
+        context and return ``(span, token)`` for :meth:`exit_span`. For a
+        site that must run the same source line with tracing on and off
+        (the train step's call: a Pallas kernel's compiled form carries its
+        call stack, so a second call site is a second program to compile)."""
+        sp = self.start_span(name, **kw)
+        return sp, _current_ctx.set((sp.trace_id, sp.span_id))
+
+    def exit_span(self, span: Span, token) -> None:
+        _current_ctx.reset(token)
+        self.end_span(span)
 
     def start_span(self, name: str, *, parent: Optional[SpanContext] = None,
                    category: str = "app",
