@@ -146,6 +146,7 @@ _REGISTRY: dict[str, ActivationFn] = {
     "softsign": softsign,
     "cube": cube,
     "swish": swish,
+    "silu": swish,
     "mish": mish,
     "thresholdedrelu": thresholdedrelu,
 }

@@ -22,7 +22,7 @@ import numpy as np
 
 from deeplearning4j_tpu.nn.conf.graph_conf import ComputationGraphConfiguration
 from deeplearning4j_tpu.nn.constraints import apply_constraints
-from deeplearning4j_tpu.nn.layers.base import Layer
+from deeplearning4j_tpu.nn.layers.base import Layer, cast_params
 from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrentLayer, check_carry_capacity
 from deeplearning4j_tpu.nn.updaters import Sgd, Updater, normalize_gradients
 from deeplearning4j_tpu.observe import scope as _scope, trace as _trace
@@ -158,7 +158,7 @@ class ComputationGraph:
                               if hasattr(a, "dtype")
                               and jnp.issubdtype(a.dtype, jnp.floating) else a)
             with jax.named_scope(_scope.CAST_PARAMS):
-                params = jax.tree_util.tree_map(cast, params)
+                params = cast_params(lambda n: conf.vertices[n].obj, params, cast)
                 inputs = {k: cast(v) for k, v in inputs.items()}
         acts: Dict[str, Array] = dict(inputs)
         m: Dict[str, Optional[Array]] = dict(masks or {})

@@ -50,6 +50,7 @@ from deeplearning4j_tpu.nn.layers.norm import (  # noqa: F401
     BatchNormalizationLayer,
     LayerNormalizationLayer,
     LocalResponseNormalizationLayer,
+    RMSNormLayer,
 )
 from deeplearning4j_tpu.nn.layers.recurrent import (  # noqa: F401
     ConvLSTM2DLayer,
@@ -79,11 +80,13 @@ from deeplearning4j_tpu.nn.layers.objdetect import (  # noqa: F401
     nms,
 )
 from deeplearning4j_tpu.nn.layers.moe import MixtureOfExpertsLayer  # noqa: F401
+from deeplearning4j_tpu.nn.layers.shortconv import GatedShortConvLayer  # noqa: F401
 from deeplearning4j_tpu.nn.layers.wrappers import FrozenLayer, TimeDistributedWrapper  # noqa: F401
 from deeplearning4j_tpu.nn.layers.samediff import SameDiffLayer, SameDiffLambdaLayer  # noqa: F401
 from deeplearning4j_tpu.nn.layers.attention import (  # noqa: F401
     CausalSelfAttentionLayer,
     CrossAttentionLayer,
+    GroupedQueryAttentionLayer,
     SelfAttentionLayer,
     LearnedSelfAttentionLayer,
 )

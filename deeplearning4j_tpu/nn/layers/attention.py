@@ -187,8 +187,30 @@ class CausalSelfAttentionLayer(SelfAttentionLayer, BaseRecurrentLayer):
     def carry_capacity(self):
         return self.max_cache
 
+    def _kv_heads(self) -> int:
+        """Heads the KV cache holds: a subclass with grouped heads has fewer
+        than ``n_heads``, each serving a group of query heads."""
+        return self.n_heads
+
+    def _qkv(self, params, x, positions):
+        """q ``[N,H,T,Dh]`` and k, v ``[N,Hkv,T,Dh]`` of the tokens ``x``
+        at ``positions`` (which only a layer with rotary positions reads)."""
+        n, t, _ = x.shape
+        qkv = x @ params["Wqkv"] + params["bqkv"]
+        qkv = qkv.reshape(n, t, self.n_heads, 3, self._dh())
+        qkv = qkv.transpose(3, 0, 2, 1, 4)
+        return qkv[0], qkv[1], qkv[2]
+
+    def _project(self, params, out):
+        """``[N,H,T,Dh]`` attention output to the layer's ``[N,T,n_out]``."""
+        n, h, t, dh = out.shape
+        y = out.transpose(0, 2, 1, 3).reshape(n, t, h * dh)
+        if self.project_input:
+            y = y @ params["Wo"] + params["bo"]
+        return self.act_fn()(y)
+
     def init_carry(self, batch: int, dtype=jnp.float32):
-        h, dh, tc = self.n_heads, self._dh(), self.max_cache
+        h, dh, tc = self._kv_heads(), self._dh(), self.max_cache
         return (jnp.zeros((batch, h, tc, dh), dtype),   # K cache
                 jnp.zeros((batch, h, tc, dh), dtype),   # V cache
                 jnp.zeros((batch, tc), dtype),          # key validity
@@ -199,16 +221,14 @@ class CausalSelfAttentionLayer(SelfAttentionLayer, BaseRecurrentLayer):
             y, _ = self.forward(params, x, train=train, rng=rng, mask=mask)
             return y, None
         n, t, _ = x.shape
-        h, dh, tc = self.n_heads, self._dh(), self.max_cache
+        hkv, dh, tc = self._kv_heads(), self._dh(), self.max_cache
         kc, vc, valid, pos = carry
         if not isinstance(pos, jax.core.Tracer) and int(pos) + t > tc:
             raise ValueError(
                 f"KV cache overflow: writing {t} token(s) at position "
                 f"{int(pos)} exceeds max_cache={tc}; raise max_cache or "
                 f"rnn_clear_previous_state() first")
-        qkv = x @ params["Wqkv"] + params["bqkv"]
-        qkv = qkv.reshape(n, t, h, 3, dh).transpose(3, 0, 2, 1, 4)
-        q, k, v = qkv[0], qkv[1], qkv[2]
+        q, k, v = self._qkv(params, x, pos + jnp.arange(t))
         zero = jnp.zeros((), pos.dtype)  # match pos dtype (x64 mode safe)
         kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype),
                                           (zero, zero, pos, zero))
@@ -220,9 +240,13 @@ class CausalSelfAttentionLayer(SelfAttentionLayer, BaseRecurrentLayer):
         # query i (absolute position pos+i) may see cache slots <= pos+i that
         # hold valid keys
         causal = jnp.arange(tc)[None, :] <= (pos + jnp.arange(t))[:, None]
-        m = jnp.logical_and(causal[None, None], (valid > 0)[:, None, None, :])
+        m = jnp.logical_and(causal[None, None, None],
+                            (valid > 0)[:, None, None, None, :])
+        # each cached head serves its group of query heads (a group of one
+        # where the head counts are equal)
+        q = q.reshape(n, hkv, -1, t, dh)
         scale = 1.0 / jnp.sqrt(jnp.asarray(dh, q.dtype))
-        scores = jnp.einsum("nhqd,nhkd->nhqk", q, kc.astype(q.dtype)) * scale
+        scores = jnp.einsum("ngiqd,ngkd->ngiqk", q, kc.astype(q.dtype)) * scale
         scores = jnp.where(m, scores, jnp.finfo(scores.dtype).min)
         w = jax.nn.softmax(scores, axis=-1)
         if train and self.attn_dropout > 0 and rng is not None:
@@ -230,11 +254,9 @@ class CausalSelfAttentionLayer(SelfAttentionLayer, BaseRecurrentLayer):
             # full-sequence path
             keep = jax.random.bernoulli(rng, 1.0 - self.attn_dropout, w.shape)
             w = jnp.where(keep, w / (1.0 - self.attn_dropout), 0.0)
-        out = jnp.einsum("nhqk,nhkd->nhqd", w, vc.astype(q.dtype))
-        y = out.transpose(0, 2, 1, 3).reshape(n, t, h * dh)
-        if self.project_input:
-            y = y @ params["Wo"] + params["bo"]
-        return self.act_fn()(y), (kc, vc, valid, pos + t)
+        out = jnp.einsum("ngiqk,ngkd->ngiqd", w, vc.astype(q.dtype))
+        return (self._project(params, out.reshape(n, self.n_heads, t, dh)),
+                (kc, vc, valid, pos + t))
 
 
 #: stamped into checkpoint metadata by the serializers; its absence marks a
@@ -446,3 +468,119 @@ class CrossAttentionLayer(Layer):
         return self.forward_multi(params, [x], state=state, train=train,
                                   rng=rng,
                                   masks=None if mask is None else [mask])
+
+
+def rotary_embedding(x, positions, theta: float):
+    """Rotary position embedding over the whole last axis of ``x``
+    ``[N, H, T, Dh]`` in the rotate-half convention: dimension ``i`` pairs
+    with ``i + Dh/2``, both turned by ``positions * theta**(-2i/Dh)``.
+    Angles and the rotation are float32; the result has ``x``'s dtype."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)
+    sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)
+    x32 = x.astype(jnp.float32)
+    turned = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
+    return (x32 * cos + turned * sin).astype(x.dtype)
+
+
+@register_layer
+@dataclasses.dataclass
+class GroupedQueryAttentionLayer(CausalSelfAttentionLayer):
+    """Causal self-attention as today's decoders have it: ``n_kv_heads`` key
+    and value heads, each serving ``n_heads / n_kv_heads`` query heads
+    (``Wq`` and a head-major ``Wkv``, columns ``[kv head, (k, v), Dh]``);
+    biases only where ``use_bias``; with ``qk_norm`` an RMSNorm over each
+    head of q and of k (one weight of ``Dh`` each), before the rotation;
+    with ``rope_theta`` rotary positions on q and k and no learned ones.
+    The stateful path is :class:`CausalSelfAttentionLayer`'s, its KV cache
+    holding ``n_kv_heads`` heads.
+
+    The full-sequence path repeats K and V to ``n_heads`` and goes through
+    :func:`dot_product_attention`, so it takes the same road to a fused
+    kernel as every other attention layer.
+    """
+
+    n_kv_heads: Optional[int] = None
+    use_bias: bool = True
+    qk_norm: bool = False
+    qk_norm_eps: float = 1e-5
+    rope_theta: Optional[float] = None
+
+    def _kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    def param_shapes(self):
+        dh, h, hkv = self._dh(), self.n_heads, self._kv_heads()
+        shapes = {"Wq": (self.n_in, h * dh), "Wkv": (self.n_in, 2 * hkv * dh)}
+        if self.use_bias:
+            shapes.update(bq=(h * dh,), bkv=(2 * hkv * dh,))
+        if self.qk_norm:
+            shapes.update(q_norm=(dh,), k_norm=(dh,))
+        if self.project_input:
+            shapes["Wo"] = (h * dh, self.n_out)
+            if self.use_bias:
+                shapes["bo"] = (self.n_out,)
+        return shapes
+
+    def weight_param_names(self):
+        return tuple(n for n in self.param_shapes() if n.startswith("W"))
+
+    def init_params(self, rng, dtype=jnp.float32):
+        if self.n_heads % self._kv_heads():
+            raise ValueError(f"n_heads ({self.n_heads}) is no multiple of "
+                             f"n_kv_heads ({self._kv_heads()})")
+        if not self.project_input and self.n_heads * self._dh() != self.n_out:
+            raise ValueError("project_input=False requires "
+                             "n_heads*head_size == n_out")
+        keys = dict(zip(("Wq", "Wkv", "Wo"), jax.random.split(rng, 3)))
+        params = {}
+        for name, shape in self.param_shapes().items():
+            if name.startswith("W"):
+                params[name] = self._init_w(keys[name], shape, shape[0],
+                                            shape[1], dtype)
+            elif name.endswith("_norm"):
+                params[name] = jnp.ones(shape, dtype)
+            else:
+                params[name] = jnp.zeros(shape, dtype)
+        return params
+
+    def _qkv(self, params, x, positions):
+        from deeplearning4j_tpu.nn.layers.norm import rms_norm
+
+        n, t, _ = x.shape
+        h, hkv, dh = self.n_heads, self._kv_heads(), self._dh()
+        q, kv = x @ params["Wq"], x @ params["Wkv"]
+        if self.use_bias:
+            q, kv = q + params["bq"], kv + params["bkv"]
+        q = q.reshape(n, t, h, dh).transpose(0, 2, 1, 3)
+        kv = kv.reshape(n, t, hkv, 2, dh).transpose(3, 0, 2, 1, 4)
+        k, v = kv[0], kv[1]
+        if self.qk_norm:
+            q = rms_norm(q, params["q_norm"], self.qk_norm_eps)
+            k = rms_norm(k, params["k_norm"], self.qk_norm_eps)
+        if self.rope_theta is not None:
+            q = rotary_embedding(q, positions, self.rope_theta)
+            k = rotary_embedding(k, positions, self.rope_theta)
+        return q, k, v
+
+    def _project(self, params, out):
+        n, h, t, dh = out.shape
+        y = out.transpose(0, 2, 1, 3).reshape(n, t, h * dh)
+        if self.project_input:
+            y = y @ params["Wo"]
+            if self.use_bias:
+                y = y + params["bo"]
+        return self.act_fn()(y)
+
+    def forward(self, params, x, *, state=None, train=False, rng=None,
+                mask=None):
+        q, k, v = self._qkv(params, x, jnp.arange(x.shape[1]))
+        groups = self.n_heads // self._kv_heads()
+        if groups > 1:
+            k, v = jnp.repeat(k, groups, axis=1), jnp.repeat(v, groups, axis=1)
+        out = dot_product_attention(q, k, v, mask=mask, causal=True,
+                                    dropout_rate=self.attn_dropout,
+                                    rng=rng, train=train)
+        return self._project(params, out), state or {}

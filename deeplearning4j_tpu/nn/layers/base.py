@@ -62,6 +62,9 @@ class Layer:
     gradient_normalization_threshold: float = 1.0
     dtype: Optional[Any] = None
 
+    #: parameters that mixed precision leaves in float32 (:func:`cast_params`)
+    float32_params = ()
+
     # ---- filled in by the network builder --------------------------------
     def apply_global_defaults(self, g: "Layer") -> None:
         """Inherit unset hyperparams from the global NeuralNetConfiguration."""
@@ -186,6 +189,22 @@ class Layer:
     @staticmethod
     def from_dict(d: dict) -> "Layer":
         return layer_from_dict(d)
+
+
+def cast_params(layer_of, params, cast):
+    """Mixed precision's cast of the master parameters (a dict by vertex
+    name, or a list by layer index) for the forward pass: every leaf through
+    ``cast``, but the parameters a layer names in ``float32_params`` (a
+    router's weights), which it computes with as they are. ``layer_of(key)``
+    is the layer that owns ``params[key]``."""
+    def one(key, owned):
+        keep = layer_of(key).float32_params
+        return {n: v if n in keep else jax.tree_util.tree_map(cast, v)
+                for n, v in owned.items()}
+
+    if isinstance(params, dict):
+        return {key: one(key, owned) for key, owned in params.items()}
+    return [one(i, owned) for i, owned in enumerate(params)]
 
 
 def activation_from_config(v):

@@ -1,17 +1,28 @@
-"""Mixture-of-experts layer with expert-parallel mesh execution.
+"""Mixture-of-experts layer: sparse dispatch, an expert share, and
+expert-parallel mesh execution.
 
 Not in the reference (SURVEY.md §2.b lists expert parallelism as absent) —
-a TPU-first addition: a gated expert FFN layer usable like any other layer,
-plus :func:`ep_forward`, which shards the expert dimension over a mesh axis
-(each device holds its experts' weights, computes their weighted contribution
-for all tokens, and one ``psum`` combines — parameter memory scales 1/E_axis
-while the math stays identical to the single-device layer).
+a TPU-first addition: a gated expert FFN layer usable like any other layer.
+The router scores every expert for every token in float32; only the
+(token, expert) pairs it chose are computed: the pairs are sorted by expert,
+each expert multiplies its own run of rows (grouped matrix products), and
+the results go back to their tokens weighted by the router. Shapes are
+static under ``jit`` (the sorted buffer always holds tokens x ``top_k``
+rows) and no pair is ever dropped, however uneven the routing: there is no
+capacity factor.
+
+A layer may hold a *share* of the experts (``experts_held=(first, count)``):
+it routes over all ``n_experts``, holds the weights of its own ``count``, and
+returns what those add to the result — the weights normalised over every
+selected expert, held or not. That is what one chip of an expert-parallel
+group computes. :func:`ep_forward` runs the shares of a whole layer over a
+mesh axis and sums them with one ``psum``, numerically the uncut layer.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -21,38 +32,209 @@ from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
 
 EXPERT_AXIS = "expert"
+GATES = ("softmax_topk", "sigmoid")
+#: standard deviation at which a selection bias is drawn: small beside the
+#: sigmoid scores it is added to, and not zero, so that selecting by
+#: ``s + b`` and weighting by ``s`` differ
+EXPERT_BIAS_SCALE = 0.02
 
 
-def _route(wg, x, top_k: int):
-    """Router: dense [..., E] gate vector, top-k renormalized. Shared by the
-    single-device apply and the expert-parallel worker so the two paths can
-    never diverge."""
-    logits = x @ wg                                 # [..., E]
-    e = logits.shape[-1]
-    k = min(top_k, e)
-    top_vals, top_idx = jax.lax.top_k(logits, k)    # [..., k]
-    gates_k = jax.nn.softmax(top_vals, axis=-1)     # renormalized over top-k
-    return jnp.sum(
-        jax.nn.one_hot(top_idx, e, dtype=x.dtype) * gates_k[..., None],
-        axis=-2)                                    # [..., E]
+def _route(wg, x, top_k: int, gate: str = "softmax_topk", bias=None,
+           norm_topk: bool = False, scaling: float = 1.0):
+    """Router, in float32 whatever the stream's dtype: ``(experts, weights)``,
+    both ``[..., k]``. Shared by the layer and the expert-parallel worker so
+    the two paths can never diverge.
+
+    - ``softmax_topk``: the top k logits, softmax over those k;
+    - ``sigmoid``: scores ``s = sigmoid(logits)``; the top k of ``s + bias``
+      are selected (``bias`` moves the selection only and takes no
+      gradient); the weights are ``s`` on the selected, divided by their
+      sum where ``norm_topk``.
+    """
+    logits = jnp.matmul(x.astype(jnp.float32), wg.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)   # [..., E]
+    k = min(top_k, logits.shape[-1])
+    if gate == "softmax_topk":
+        top_vals, experts = jax.lax.top_k(logits, k)
+        weights = jax.nn.softmax(top_vals, axis=-1)
+    elif gate == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        biased = scores if bias is None else \
+            scores + jax.lax.stop_gradient(bias.astype(jnp.float32))
+        _, experts = jax.lax.top_k(biased, k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+        if norm_topk:
+            weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-6)
+    else:
+        raise ValueError(f"gate {gate!r} is none of {GATES}")
+    return experts, weights * scaling
+
+
+def _dense_gates(experts, weights, n_experts: int):
+    """``[..., E]``: each token's weight on every expert, zero off its k."""
+    return jnp.sum(jax.nn.one_hot(experts, n_experts, dtype=weights.dtype)
+                   * weights[..., None], axis=-2)
+
+
+@jax.custom_vjp
+def _take_rows(rows, index, inverse):
+    """``rows[index]`` for a permutation ``index`` whose inverse is
+    ``inverse``: the backward pass is a gather too, not a scatter."""
+    return rows[index]
+
+
+def _take_rows_fwd(rows, index, inverse):
+    return rows[index], inverse
+
+
+def _take_rows_bwd(inverse, g):
+    return g[inverse], None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@jax.custom_vjp
+def _rows_of_pairs(tokens, order, inverse):
+    """The token of each (token, expert) pair, pairs in the order ``order``.
+    Pair ``j * m + t`` is token ``t``'s ``j``-th expert (``m`` tokens), so
+    that ``[k * m, d]`` splits into ``[k, m, d]`` without moving anything.
+    Backward, each token sums the rows of its ``k`` pairs, found through
+    ``inverse``: gathers both ways."""
+    return tokens[order % tokens.shape[0]]
+
+
+def _rows_of_pairs_fwd(tokens, order, inverse):
+    return tokens[order % tokens.shape[0]], (inverse, tokens.shape[0])
+
+
+def _rows_of_pairs_bwd(res, g):
+    inverse, m = res
+    by_pair = g[inverse].reshape(-1, m, g.shape[-1])
+    return (jnp.sum(by_pair.astype(jnp.float32), 0).astype(g.dtype),
+            None, None)
+
+
+_rows_of_pairs.defvjp(_rows_of_pairs_fwd, _rows_of_pairs_bwd)
+
+
+#: megablox tile sizes (rows, contraction, columns) from a sweep on a v5e at
+#: 32,768 rows of 2048 by 8 experts of 1792: PERF.md §6, PR 27, has every
+#: candidate's numbers
+_GMM_TILING = (256, 1024, 1024)
+
+
+def _megablox_tiling(rows, weights):
+    """Tile sizes for the grouped-matmul kernel bundled with jax
+    (``jax.experimental.pallas.ops.tpu.megablox``), or None where it cannot
+    serve: a backend that is no TPU, a program the compiler partitions
+    (Mosaic kernels cannot be), a dtype other than bfloat16 (float32 tiles
+    of this size do not fit VMEM), or a row count its row tile does not
+    divide."""
+    from deeplearning4j_tpu.nn import helpers as _helpers
+
+    tile_rows, tile_in, tile_out = _GMM_TILING
+    if (jax.default_backend() != "tpu" or rows.dtype != jnp.bfloat16
+            or weights.dtype != jnp.bfloat16 or rows.shape[0] % tile_rows
+            or _helpers.partitioned_by_compiler(rows)):
+        return None
+    return (tile_rows, min(tile_in, weights.shape[1]),
+            min(tile_out, weights.shape[2]))
+
+
+def _grouped_matmul(rows, weights, group_sizes):
+    """``rows[i] @ weights[g]`` for the group ``g`` that row ``i`` falls in:
+    rows ``[R, a]`` sorted by group, weights ``[G, a, b]``, ``group_sizes``
+    ``[G]``. Rows past the last group come back undefined. The megablox
+    kernels where they can serve (their work follows the rows that are in a
+    group), else ``jax.lax.ragged_dot``."""
+    tiling = _megablox_tiling(rows, weights)
+    if tiling is None:
+        return jax.lax.ragged_dot(rows, weights, group_sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    return megablox.gmm(rows, weights, group_sizes, rows.dtype, tiling)
+
+
+def _moe_share(params, x, *, top_k: int, act, gate: str = "softmax_topk",
+               norm_topk: bool = False, scaling: float = 1.0,
+               gated: bool = False, first=0):
+    """What the experts held here add to the layer's result.
+
+    x: [..., d_in] → [..., d_out]. ``params`` hold the router for all experts
+    and the weights of ``count`` of them (the leading axis of ``W`` or
+    ``W1``), which are the experts ``first .. first + count`` of the layer.
+    Returns the output and ``(experts, weights, expert_rows,
+    rows_elsewhere)``: the router's choice ``[..., k]`` twice, the pairs each
+    held expert computed ``[count]`` and the pairs whose expert is not held.
+    """
+    lead, tokens = x.shape[:-1], x.reshape(-1, x.shape[-1])
+    count = params["W1" if gated else "W"].shape[0]
+    with jax.named_scope("route"):
+        experts, weights = _route(params["Wg"], tokens, top_k, gate,
+                                  params.get("expert_bias"), norm_topk,
+                                  scaling)
+    m, k = experts.shape
+    with jax.named_scope("dispatch"):
+        # a pair's group: its expert's place among those held, or `count`;
+        # pairs run choice by choice (see `_rows_of_pairs`)
+        local = experts.T.reshape(-1) - first
+        group = jnp.where((local >= 0) & (local < count), local, count)
+        order = jnp.argsort(group, stable=True)     # pairs sorted by group
+        inverse = jnp.argsort(order)
+        sizes = jnp.bincount(group, length=count + 1).astype(jnp.int32)
+        expert_rows, rows_elsewhere = sizes[:count], sizes[count]
+        computed = (jnp.arange(m * k) < jnp.sum(expert_rows))[:, None]
+        rows = jnp.where(computed,
+                         _rows_of_pairs(tokens, order, inverse), 0)
+    with jax.named_scope("experts"):
+        if gated:
+            hidden = act(_grouped_matmul(rows, params["W1"], expert_rows)) \
+                * _grouped_matmul(rows, params["W3"], expert_rows)
+            out_rows = _grouped_matmul(hidden, params["W2"], expert_rows)
+        else:
+            expert_of_row = jnp.minimum(group[order], count - 1)
+            out_rows = act(_grouped_matmul(rows, params["W"], expert_rows)
+                           + params["b"][expert_of_row])
+        out_rows = jnp.where(computed, out_rows, 0)
+    with jax.named_scope("combine"):
+        by_pair = _take_rows(out_rows, inverse, order).reshape(k, m, -1)
+        # choice by choice, so that the float32 product is never stored
+        out = sum(by_pair[j].astype(jnp.float32) * weights[:, j, None]
+                  for j in range(k)).astype(x.dtype)
+    return (out.reshape(lead + out.shape[-1:]),
+            (experts.reshape(lead + (k,)), weights.reshape(lead + (k,)),
+             expert_rows, rows_elsewhere))
 
 
 def _moe_apply(params, x, top_k: int, act):
-    """Dense-compute MoE: every expert runs, gates select/weight.
-
-    x: [..., d_in] → [..., d_out]. Dense all-expert compute keeps shapes
-    static (jit-friendly) and is exactly what the EP sharding distributes.
-    """
-    gates = _route(params["Wg"], x, top_k)
-    hidden = jnp.einsum("...d,edh->...eh", x, params["W"]) + params["b"]
-    hidden = act(hidden)
-    return jnp.einsum("...eh,...e->...h", hidden, gates), gates
+    """The whole layer with its first options (softmax over the top-k
+    logits, one biased matrix per expert): ``(out, gates [..., E])``."""
+    out, (experts, weights, _, _) = _moe_share(params, x, top_k=top_k,
+                                               act=act)
+    return out, _dense_gates(experts, weights, params["Wg"].shape[-1])
 
 
 @register_layer
 @dataclasses.dataclass
 class MixtureOfExpertsLayer(Layer):
-    """Gated expert FFN: router picks top_k of n_experts per token."""
+    """Expert FFN: a router picks ``top_k`` of ``n_experts`` per token, and
+    only the chosen (token, expert) pairs are computed (module docstring).
+
+    - ``gate``: ``softmax_topk`` (softmax over the top-k logits) or
+      ``sigmoid`` (see :func:`_route`), with ``expert_bias`` (a constant
+      ``[n_experts]`` added for the selection only, drawn at
+      ``EXPERT_BIAS_SCALE``; nothing updates it in training), ``norm_topk``
+      and ``routed_scaling``;
+    - ``gated``: each expert is ``W2(act(W1 x) * W3 x)`` with hidden width
+      ``n_hidden`` and no bias, else ``act(W x + b)``;
+    - ``experts_held=(first, count)``: the layer holds that share.
+
+    The layer's state carries, from the last forward pass, ``expert_rows``
+    (int32 per held expert: the pairs it computed) and ``rows_elsewhere``
+    (the pairs whose expert is not held); together they are tokens x
+    ``top_k``, always.
+    """
 
     n_in: int = 0
     n_out: int = 0
@@ -63,10 +245,29 @@ class MixtureOfExpertsLayer(Layer):
     # the last batch's gates with checkpoints — leave off unless inspecting
     # router behaviour)
     collect_gates: bool = False
+    gate: str = "softmax_topk"
+    expert_bias: bool = False
+    norm_topk: bool = False
+    routed_scaling: float = 1.0
+    gated: bool = False
+    n_hidden: int = 0
+    experts_held: Optional[Tuple[int, int]] = None
+
+    float32_params = ("Wg", "expert_bias")
 
     def __post_init__(self):
         if self.activation is None:
             self.activation = "relu"
+        if self.gate not in GATES:
+            raise ValueError(f"gate {self.gate!r} is none of {GATES}")
+        if self.experts_held is not None:
+            first, count = self.experts_held = tuple(self.experts_held)
+            if first < 0 or count < 1 or first + count > self.n_experts:
+                raise ValueError(f"experts_held {self.experts_held} is no "
+                                 f"part of {self.n_experts} experts")
+
+    def _held(self) -> Tuple[int, int]:
+        return self.experts_held or (0, self.n_experts)
 
     def set_n_in(self, input_type: InputType) -> None:
         if not self.n_in:
@@ -80,30 +281,73 @@ class MixtureOfExpertsLayer(Layer):
         return InputType.feed_forward(self.n_out)
 
     def param_shapes(self):
-        return {"Wg": (self.n_in, self.n_experts),
-                "W": (self.n_experts, self.n_in, self.n_out),
-                "b": (self.n_experts, self.n_out)}
+        count = self._held()[1]
+        shapes = {"Wg": (self.n_in, self.n_experts)}
+        if self.expert_bias:
+            shapes["expert_bias"] = (self.n_experts,)
+        if self.gated:
+            shapes.update(W1=(count, self.n_in, self.n_hidden),
+                          W3=(count, self.n_in, self.n_hidden),
+                          W2=(count, self.n_hidden, self.n_out))
+        else:
+            shapes.update(W=(count, self.n_in, self.n_out),
+                          b=(count, self.n_out))
+        return shapes
+
+    def weight_param_names(self):
+        return tuple(n for n in self.param_shapes()
+                     if n not in ("b", "expert_bias"))
 
     def init_params(self, rng, dtype=jnp.float32):
-        k1, k2 = jax.random.split(rng)
-        w = jnp.stack([
-            self._init_w(k, (self.n_in, self.n_out), self.n_in, self.n_out,
-                         dtype)
-            for k in jax.random.split(k2, self.n_experts)])
-        return {"Wg": self._init_w(k1, (self.n_in, self.n_experts),
-                                   self.n_in, self.n_experts, dtype),
-                "W": w,
-                "b": jnp.zeros((self.n_experts, self.n_out), dtype)}
+        """An expert's weights come from the seed and its place in the whole
+        layer, so a share holds what the uncut layer has there."""
+        first, count = self._held()
+        k_router, k_experts, k_bias = jax.random.split(rng, 3)
+        keys = jax.random.split(k_experts, self.n_experts)[first:first + count]
+
+        def experts(salt, fan_in, fan_out):
+            return jnp.stack([
+                self._init_w(jax.random.fold_in(k, salt), (fan_in, fan_out),
+                             fan_in, fan_out, dtype) for k in keys])
+
+        params = {"Wg": self._init_w(k_router, (self.n_in, self.n_experts),
+                                     self.n_in, self.n_experts, dtype)}
+        if self.expert_bias:
+            params["expert_bias"] = EXPERT_BIAS_SCALE * jax.random.normal(
+                k_bias, (self.n_experts,), dtype)
+        if self.gated:
+            params.update(W1=experts(1, self.n_in, self.n_hidden),
+                          W3=experts(3, self.n_in, self.n_hidden),
+                          W2=experts(2, self.n_hidden, self.n_out))
+        else:
+            params.update(W=experts(0, self.n_in, self.n_out),
+                          b=jnp.zeros((count, self.n_out), dtype))
+        return params
+
+    def init_state(self):
+        return {"expert_rows": jnp.zeros((self._held()[1],), jnp.int32),
+                "rows_elsewhere": jnp.zeros((), jnp.int32)}
+
+    def share(self, params, x, first=None):
+        """:func:`_moe_share` with this layer's options; ``first`` (which may
+        be traced: a shard's index) in place of ``experts_held``'s."""
+        return _moe_share(
+            params, x, top_k=self.top_k, act=self.act_fn(), gate=self.gate,
+            norm_topk=self.norm_topk, scaling=self.routed_scaling,
+            gated=self.gated,
+            first=self._held()[0] if first is None else first)
 
     def forward(self, params, x, *, state=None, train=False, rng=None,
                 mask=None):
         x = self._dropout(x, train, rng)
-        out, gates = _moe_apply(params, x, self.top_k, self.act_fn())
+        out, (experts, weights, expert_rows, rows_elsewhere) = \
+            self.share(params, x)
+        new_state = dict(state or {}, expert_rows=expert_rows,
+                         rows_elsewhere=rows_elsewhere)
         if self.collect_gates:
-            new_state = dict(state or {})
-            new_state["gates"] = gates
-            return out, new_state
-        return out, state or {}
+            new_state["gates"] = _dense_gates(experts, weights,
+                                              self.n_experts)
+        return out, new_state
 
 
 def load_balancing_loss(gates: jax.Array) -> jax.Array:
@@ -128,35 +372,29 @@ def ep_forward(layer: MixtureOfExpertsLayer, params, x, mesh: Mesh,
                axis_name: str = EXPERT_AXIS):
     """Expert-parallel execution: expert tensors sharded over ``axis_name``.
 
-    Router weights stay replicated (they're tiny); each device computes its
-    expert shard's gated contribution for every token and a psum combines.
-    Numerically identical to the single-device forward.
+    Router weights stay replicated (they're tiny); each device routes every
+    token over all experts, computes the share of the experts it holds
+    (:func:`_moe_share`, ``first`` from its place on the axis) and a psum
+    combines. Numerically identical to the single-device forward. Every
+    device sees every token: there is no all-to-all yet.
     """
     from deeplearning4j_tpu.parallel.mesh import shard_map
 
-    act = layer.act_fn()
-    top_k = layer.top_k
     n_exp = layer.n_experts
     n_shards = int(mesh.shape[axis_name])
     if n_exp % n_shards:
         raise ValueError(f"n_experts ({n_exp}) must divide over the "
                          f"{axis_name!r} axis ({n_shards})")
     per = n_exp // n_shards
+    names = sorted(params)
+    specs = tuple(P() if n in ("Wg", "expert_bias") else P(axis_name)
+                  for n in names)
 
-    def worker(wg, w, b, xx):
-        # gating needs ALL experts' logits: router replicated
-        gates = _route(wg, xx, top_k)                # [..., E]
-        # this shard's slice of the gate vector
-        s = jax.lax.axis_index(axis_name)
-        local_gates = jax.lax.dynamic_slice_in_dim(
-            gates, s * per, per, axis=gates.ndim - 1)
-        hidden = jnp.einsum("...d,edh->...eh", xx, w) + b
-        hidden = act(hidden)
-        partial = jnp.einsum("...eh,...e->...h", hidden, local_gates)
+    def worker(xx, *shard):
+        partial, _ = layer.share(dict(zip(names, shard)), xx,
+                                 first=jax.lax.axis_index(axis_name) * per)
         return jax.lax.psum(partial, axis_name)
 
-    mapped = shard_map(
-        worker, mesh=mesh,
-        in_specs=(P(), P(axis_name), P(axis_name), P()),
-        out_specs=P())
-    return mapped(params["Wg"], params["W"], params["b"], jnp.asarray(x))
+    mapped = shard_map(worker, mesh=mesh, in_specs=(P(),) + specs,
+                       out_specs=P())
+    return mapped(jnp.asarray(x), *(params[n] for n in names))

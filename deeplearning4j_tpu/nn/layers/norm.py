@@ -191,3 +191,37 @@ class LayerNormalizationLayer(Layer):
             var = jnp.var(x, axis=-1, keepdims=True)
             xhat = (x - mean) / jnp.sqrt(var + self.eps)
         return self.act_fn()(xhat * params["gamma"] + params["beta"]), state or {}
+
+
+def rms_norm(x, gamma, eps: float):
+    """``x / sqrt(mean(x^2, -1) + eps) * gamma``: the mean of squares in
+    float32 whatever the stream's dtype, the result in the stream's."""
+    x32 = x.astype(jnp.float32)
+    scale = jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True) + eps)
+    return (x32 * scale).astype(x.dtype) * gamma
+
+
+@register_layer
+@dataclasses.dataclass
+class RMSNormLayer(Layer):
+    """Root-mean-square normalization over the last axis: no mean is
+    subtracted and there is no shift, one scale ``gamma`` per feature."""
+
+    n_in: int = 0
+    eps: float = 1e-5
+
+    def set_n_in(self, input_type: InputType) -> None:
+        if not self.n_in:
+            self.n_in = input_type.size
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return input_type
+
+    def param_shapes(self):
+        return {"gamma": (self.n_in,)}
+
+    def init_params(self, rng, dtype=jnp.float32):
+        return {"gamma": jnp.ones((self.n_in,), dtype)}
+
+    def forward(self, params, x, *, state=None, train=False, rng=None, mask=None):
+        return self.act_fn()(rms_norm(x, params["gamma"], self.eps)), state or {}

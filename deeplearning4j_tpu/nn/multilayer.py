@@ -25,7 +25,7 @@ import numpy as np
 
 from deeplearning4j_tpu.nn.conf.network import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.constraints import apply_constraints
-from deeplearning4j_tpu.nn.layers.base import Layer
+from deeplearning4j_tpu.nn.layers.base import Layer, cast_params
 from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrentLayer, check_carry_capacity
 from deeplearning4j_tpu.nn.updaters import (
     Sgd,
@@ -159,7 +159,7 @@ class MultiLayerNetwork:
                               if hasattr(a, "dtype")
                               and jnp.issubdtype(a.dtype, jnp.floating) else a)
             with jax.named_scope(_scope.CAST_PARAMS):
-                params = jax.tree_util.tree_map(cast, params)
+                params = cast_params(self.layers.__getitem__, params, cast)
                 x = cast(x)
         h = x
         new_states: States = []

@@ -16,6 +16,7 @@ from deeplearning4j_tpu.zoo.models import (
     Darknet19,
     FaceNetNN4Small2,
     GoogLeNet,
+    HybridConvMoELM,
     InceptionResNetV1,
     LeNet,
     ResNet50,
@@ -38,6 +39,7 @@ __all__ = [
     "ModelMetaData", "ModelSelector", "PretrainedType", "ZooModel",
     "register_zoo_model",
     "AlexNet", "Darknet19", "FaceNetNN4Small2", "GoogLeNet",
+    "HybridConvMoELM",
     "InceptionResNetV1", "LeNet", "ResNet50", "SimpleCNN",
     "TextGenerationLSTM", "TinyYOLO", "TransformerEncoder", "TransformerLM",
     "VisionTransformer",

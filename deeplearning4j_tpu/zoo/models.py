@@ -1267,3 +1267,144 @@ class VisionTransformer(ZooModel):
                     "pool")
         g.set_outputs("out")
         return g.build()
+
+
+def hybrid_conv_moe_block(g, name: str, src: str, operator: str, *,
+                          d_model: int, n_heads: int, n_kv_heads: int,
+                          rope_theta: float, conv_kernel: int, norm_eps: float,
+                          max_len: int, dense_ff: int = 0,
+                          experts: dict = None) -> str:
+    """One pre-norm block of the LFM2 family: RMSNorm → operator → residual,
+    RMSNorm → feed-forward → residual. ``operator`` is ``conv`` (a gated
+    short convolution) or ``full_attention`` (grouped-query rotary attention
+    with QK-norm, no biases); the feed-forward is a gated dense one of width
+    ``dense_ff``, or a sparse expert layer built from ``experts`` (the
+    keyword arguments of :class:`MixtureOfExpertsLayer`). Returns the output
+    vertex name."""
+    from deeplearning4j_tpu.nn.layers import (
+        GatedShortConvLayer,
+        GroupedQueryAttentionLayer,
+        MixtureOfExpertsLayer,
+        RMSNormLayer,
+    )
+    from deeplearning4j_tpu.nn.vertices import ElementWiseVertex
+
+    g.add_layer(f"{name}-norm1", RMSNormLayer(eps=norm_eps), src)
+    if operator == "conv":
+        op = f"{name}-conv"
+        g.add_layer(op, GatedShortConvLayer(n_out=d_model,
+                                            kernel_size=conv_kernel,
+                                            activation="identity"),
+                    f"{name}-norm1")
+    elif operator == "full_attention":
+        op = f"{name}-att"
+        g.add_layer(op, GroupedQueryAttentionLayer(
+            n_heads=n_heads, n_kv_heads=n_kv_heads,
+            head_size=d_model // n_heads, use_bias=False, qk_norm=True,
+            qk_norm_eps=norm_eps, rope_theta=rope_theta, max_cache=max_len,
+            activation="identity"), f"{name}-norm1")
+    else:
+        raise ValueError(f"layer type {operator!r} is neither 'conv' nor "
+                         f"'full_attention'")
+    g.add_vertex(f"{name}-res1", ElementWiseVertex(op="add"), src, op)
+    g.add_layer(f"{name}-norm2", RMSNormLayer(eps=norm_eps), f"{name}-res1")
+    if experts is None:
+        # W2(silu(W1 x) * W3 x), no biases
+        for part, activation in (("ff1", "silu"), ("ff3", "identity")):
+            g.add_layer(f"{name}-{part}",
+                        DenseLayer(n_in=d_model, n_out=dense_ff,
+                                   has_bias=False, activation=activation),
+                        f"{name}-norm2")
+        g.add_vertex(f"{name}-ffg", ElementWiseVertex(op="product"),
+                     f"{name}-ff1", f"{name}-ff3")
+        ff = f"{name}-ff2"
+        g.add_layer(ff, DenseLayer(n_in=dense_ff, n_out=d_model,
+                                   has_bias=False, activation="identity"),
+                    f"{name}-ffg")
+    else:
+        ff = f"{name}-moe"
+        g.add_layer(ff, MixtureOfExpertsLayer(n_in=d_model, n_out=d_model,
+                                              **experts), f"{name}-norm2")
+    g.add_vertex(f"{name}-res2", ElementWiseVertex(op="add"),
+                 f"{name}-res1", ff)
+    return f"{name}-res2"
+
+
+@register_zoo_model
+class HybridConvMoELM(ZooModel):
+    """Causal language model of the LFM2-MoE family (LiquidAI LFM2-8B-A1B):
+    token ids [N,T] → embedding → blocks whose operator differs by layer
+    (``layer_types[l]``: ``conv`` or ``full_attention``) and whose
+    feed-forward is dense for the first ``num_dense_layers`` blocks and a
+    sparse expert layer after them (sigmoid routing with a selection-only
+    expert bias, normalised top-k weights, SwiGLU experts) → RMSNorm →
+    untied softmax head. No positional-embedding vertex: the attention
+    layers rotate q and k. Labels as for :class:`TransformerLM`
+    (:func:`lm_labels`).
+
+    ``experts_held=(first, count)`` builds every expert layer as that share
+    of the experts (see :class:`MixtureOfExpertsLayer`): what one chip of an
+    expert-parallel group holds. Defaults are the published sizes.
+    """
+
+    def __init__(self, num_labels: int = 0, seed: int = 123,
+                 vocab_size: int = 65536, max_length: int = 8192,
+                 layer_types=("conv", "conv", "full_attention", "conv",
+                              "conv", "conv", "full_attention", "conv",
+                              "conv", "conv", "full_attention", "conv",
+                              "conv", "conv", "full_attention", "conv",
+                              "conv", "conv", "full_attention", "conv",
+                              "conv", "full_attention", "conv", "conv"),
+                 num_dense_layers: int = 2, d_model: int = 2048,
+                 n_heads: int = 32, n_kv_heads: int = 8, d_ff: int = 7168,
+                 n_experts: int = 32, experts_per_token: int = 4,
+                 expert_d_ff: int = 1792, experts_held=None,
+                 use_expert_bias: bool = True, norm_topk: bool = True,
+                 routed_scaling: float = 1.0, conv_kernel: int = 3,
+                 rope_theta: float = 1e6, norm_eps: float = 1e-5):
+        vocab_size = num_labels or vocab_size
+        super().__init__(vocab_size, seed)
+        self.vocab_size = vocab_size
+        self.max_length = max_length
+        self.layer_types = tuple(layer_types)
+        self.num_dense_layers = num_dense_layers
+        self.block = dict(d_model=d_model, n_heads=n_heads,
+                          n_kv_heads=n_kv_heads, rope_theta=rope_theta,
+                          conv_kernel=conv_kernel, norm_eps=norm_eps,
+                          max_len=max_length)
+        self.d_ff = d_ff
+        self.experts = dict(
+            n_experts=n_experts, top_k=experts_per_token, n_hidden=expert_d_ff,
+            gated=True, activation="silu", gate="sigmoid",
+            expert_bias=use_expert_bias, norm_topk=norm_topk,
+            routed_scaling=routed_scaling, experts_held=experts_held)
+
+    def meta_data(self):
+        return ModelMetaData(((self.max_length,),), 1, "rnn")
+
+    def conf(self):
+        from deeplearning4j_tpu.nn.layers import (
+            EmbeddingSequenceLayer,
+            RMSNormLayer,
+        )
+
+        d_model = self.block["d_model"]
+        g = (NeuralNetConfiguration.builder().seed(self.seed)
+             .weight_init("xavier").updater(Adam(3e-4)).graph_builder()
+             .add_inputs("tokens")
+             .set_input_types(InputType.recurrent(1, self.max_length)))
+        g.add_layer("embed", EmbeddingSequenceLayer(n_in=self.vocab_size,
+                                                    n_out=d_model), "tokens")
+        src = "embed"
+        for i, operator in enumerate(self.layer_types):
+            dense = i < self.num_dense_layers
+            src = hybrid_conv_moe_block(
+                g, f"block{i}", src, operator, dense_ff=self.d_ff,
+                experts=None if dense else self.experts, **self.block)
+        g.add_layer("norm_f", RMSNormLayer(eps=self.block["norm_eps"]), src)
+        g.add_layer("out", RnnOutputLayer(n_in=d_model, n_out=self.vocab_size,
+                                          has_bias=False,
+                                          activation="softmax", loss="mcxent"),
+                    "norm_f")
+        g.set_outputs("out")
+        return g.build()

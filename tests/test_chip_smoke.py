@@ -257,3 +257,39 @@ def test_flash_attention_lowers_for_tpu(causal, dtype):
     operands = calls[1][1]
     assert operands.count("tensor<12x8x2048xf32>") == 2
     assert not [t for t in operands if t.endswith("x128xf32>")]
+
+
+def test_expert_grouped_products_lower_for_tpu(monkeypatch):
+    """The sparse expert layer at LFM2-8B-A1B's widths (8 held experts of
+    2048 x 1792, 8,192 tokens x 4): on a TPU its nine grouped products are
+    megablox kernels, three forward (`gmm`) and, backward, three for the
+    rows (`gmm`) and three for the weights (`tgmm`); float32 and the CPU
+    take `ragged_dot`."""
+    from deeplearning4j_tpu.nn.layers import MixtureOfExpertsLayer
+    from deeplearning4j_tpu.nn.layers import moe
+
+    layer = MixtureOfExpertsLayer(
+        n_in=2048, n_out=2048, n_hidden=1792, n_experts=32, top_k=4,
+        gated=True, activation="silu", gate="sigmoid", expert_bias=True,
+        norm_topk=True, experts_held=(0, 8))
+    dtype_of = lambda n: (jnp.float32 if n in layer.float32_params
+                          else jnp.bfloat16)
+    params = {n: jax.ShapeDtypeStruct(s, dtype_of(n))
+              for n, s in layer.param_shapes().items()}
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16)
+    rows = jax.ShapeDtypeStruct((32768, 2048), jnp.bfloat16)
+    assert moe._megablox_tiling(rows, params["W1"]) is None     # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert moe._megablox_tiling(rows, params["W1"]) == (256, 1024, 1024)
+    assert moe._megablox_tiling(
+        jax.ShapeDtypeStruct((32768, 2048), jnp.float32), params["W1"]) is None
+    assert moe._megablox_tiling(
+        jax.ShapeDtypeStruct((100, 2048), jnp.bfloat16), params["W1"]) is None
+    loss = lambda p, xx: layer.forward(p, xx)[0].astype(jnp.float32).sum()
+    text = _lowers_for_tpu(jax.value_and_grad(loss, argnums=(0, 1)),
+                           params, x)
+    # a jitted kernel is one function of the lowered module however often it
+    # is called: W1 and W3 share theirs, forward and backward apart
+    assert len(_kernel_calls(text)) == 6
+    assert len(re.findall(r"call @gmm", text)) == 6
+    assert len(re.findall(r"call @tgmm", text)) == 3
