@@ -122,9 +122,9 @@ class ComputationGraph:
         self.epoch = 0
         return self
 
-    def _device_tick(self):
+    def _device_tick(self, batch=None):
         from deeplearning4j_tpu.nn.tick import device_tick
-        return device_tick(self)
+        return device_tick(self, batch)
 
     def _store_tick(self, new_it, new_rng) -> None:
         from deeplearning4j_tpu.nn.tick import store_tick
@@ -525,7 +525,7 @@ class ComputationGraph:
                 return
 
         step = self._get_train_step()
-        it, ep, rng = self._device_tick()
+        it, ep, rng = self._device_tick(inputs)
         # Two spans under tracing, and with it off no span and no context
         # manager. Neither span's body reads a device value, so neither
         # drains the device; a compile the call pays for nests under its

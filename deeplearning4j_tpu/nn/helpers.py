@@ -84,11 +84,12 @@ def partitioned_by_compiler(x) -> bool:
 
 
 # -- flash-attention auto-registration ---------------------------------------
-# When NO attention helper is registered, causal attention at T >= 2048 on a
-# TPU backend automatically uses the causal PallasFlashAttentionHelper — the
-# measured win region (LM training 1.45x at T=2048, 2.64x at T=4096; the
-# kernel skips the masked upper triangle the einsum path still computes) —
-# unless the program is partitioned by the compiler (see above).
+# When NO attention helper is registered, causal attention on a TPU backend
+# at the sequence lengths where the kernel was measured to win
+# (layers/attention.py:_AUTO_FLASH_MIN_T and up; PERF.md §6 has the sweep)
+# automatically uses the causal PallasFlashAttentionHelper, which skips the
+# masked upper triangle the einsum path still computes, unless the program
+# is partitioned by the compiler (see above).
 # Registering any helper, or set_auto_flash_attention(False), overrides.
 _AUTO_FLASH = True
 

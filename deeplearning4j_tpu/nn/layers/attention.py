@@ -20,11 +20,12 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
 from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrentLayer
+from deeplearning4j_tpu.observe import trace as _trace
 
 
-#: causal flash auto-use threshold — below this the einsum path ties or
-#: wins (measured v5e; see PallasFlashAttentionHelper docstring)
-_AUTO_FLASH_MIN_T = 2048
+#: shortest causal sequence the auto gate hands to the kernel: the sweep
+#: that set it, and every speed on either side of it, is in PERF.md (§6)
+_AUTO_FLASH_MIN_T = 1024
 _auto_flash_cache: dict = {}
 
 
@@ -51,22 +52,25 @@ def dot_product_attention(q, k, v, mask=None, dropout_rate=0.0, rng=None,
     from deeplearning4j_tpu.nn import helpers as _helpers
     helper = _helpers.get_helper("attention")
     dropout_active = bool(train and dropout_rate > 0 and rng is not None)
-    if (helper is not None
-            and helper.supports(None, q.shape, mask, dropout_active,
-                                causal=causal)
-            and q.shape == k.shape == v.shape):
-        return helper.attend(q, k, v)
     if (helper is None and causal and q.shape[-2] >= _AUTO_FLASH_MIN_T
             and _helpers.auto_flash_attention_enabled()
             and not _helpers.partitioned_by_compiler(q)):
-        # no helper registered: auto-use the causal flash kernel in its
-        # measured win region (1.45x T=2048 / 2.64x T=4096 LM training) so
-        # the speedup doesn't depend on knowing the seam exists; opt out
-        # via helpers.set_auto_flash_attention(False)
-        cand = _auto_flash_helper()
-        if (cand.supports(None, q.shape, mask, dropout_active, causal=True)
-                and q.shape == k.shape == v.shape):
-            return cand.attend(q, k, v)
+        # no helper registered: the causal kernel serves the lengths at
+        # which it was measured to win (PERF.md §6), so that the gain does
+        # not depend on knowing the seam exists; opt out via
+        # helpers.set_auto_flash_attention(False)
+        helper = _auto_flash_helper()
+    kernel = (helper is not None
+              and helper.supports(None, q.shape, mask, dropout_active,
+                                  causal=causal)
+              and q.shape == k.shape == v.shape)
+    tracer = _trace.get_active_tracer()
+    if tracer is not None:
+        # which path this call took, counted while its step is traced
+        tracer.count("attention.kernel_calls" if kernel
+                     else "attention.einsum_calls")
+    if kernel:
+        return helper.attend(q, k, v)
     scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(q.dtype)
     scores = jnp.einsum("nhqd,nhkd->nhqk", q, k) * scale
     m = None

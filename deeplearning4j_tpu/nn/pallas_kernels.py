@@ -317,29 +317,37 @@ class PallasUpdaterHelper(UpdaterHelper):
         return new_param, new_state
 
 
-#: splash block sizes, by `BlockSizes` field, from a sweep on a v5e at
-#: [4,12,2048,64] bf16: PERF.md §6, PR 26, has every candidate's numbers
-_SPLASH_BLOCKS = dict(block_q=1024, block_kv=1024, block_kv_compute=512,
-                      block_q_dkv=1024, block_kv_dkv=1024,
-                      block_kv_dkv_compute=512)
+#: splash block sizes in `BlockSizes`' order (q, kv, kv_compute, q_dkv,
+#: kv_dkv, kv_dkv_compute) by the shortest sequence and the widest row of q
+#: (Dh times the bytes of an element) that a row of this table serves; the
+#: first row that fits is taken. Each is the fastest forward + backward of a
+#: sweep on a v5e over 8,192 tokens (PERF.md §6: PR 26 for T=2048, PR 29
+#: below it), where Mosaic had the VMEM for it: at T=1024 it refuses
+#: 1024-row blocks for 512-byte rows (bf16 at Dh=256), and at any length
+#: for 1,024-byte rows (float32 at Dh=256); those take the last row.
+_SPLASH_BLOCKS = (
+    (2048, 512, (1024, 1024, 512, 1024, 1024, 512)),
+    (1024, 256, (1024, 1024, 512, 1024, 1024, 1024)),
+    (0, 1024, (512, 512, 512, 512, 512, 512)),
+)
+_SPLASH_BLOCK_FIELDS = ("block_q", "block_kv", "block_kv_compute",
+                        "block_q_dkv", "block_kv_dkv", "block_kv_dkv_compute")
 
 
 def _splash_block_sizes(t: int, row_bytes: int):
-    """The measured block sizes, each brought down by halves until it
-    divides ``t`` (``supports()`` holds ``t`` to a multiple of 128), and
-    held to 512 where a row of q is wider than 512 bytes (float32 at
-    Dh=256), whose 1024-row blocks Mosaic refuses for want of VMEM."""
+    """The table's blocks for a sequence of ``t`` and rows of ``row_bytes``,
+    each brought down by halves until it divides ``t`` (``supports()``
+    holds ``t`` to a multiple of 128)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes
 
     def fit(block):
-        if row_bytes > 512:
-            block = min(block, 512)
         while t % block:
             block //= 2
         return block
 
-    return BlockSizes(**{name: fit(block)
-                         for name, block in _SPLASH_BLOCKS.items()},
+    blocks = next(blocks for min_t, widest, blocks in _SPLASH_BLOCKS
+                  if t >= min_t and row_bytes <= widest)
+    return BlockSizes(**dict(zip(_SPLASH_BLOCK_FIELDS, map(fit, blocks))),
                       use_fused_bwd_kernel=True)
 
 
@@ -391,7 +399,7 @@ class PallasFlashAttentionHelper(AttentionHelper):
     log-sum-exp kept as one row per head between them. Under a causal mask
     the blocks above the diagonal are neither fetched nor computed, and the
     blocks wholly below it skip the mask. Every speed measured with it is
-    in PERF.md (§5, and §6 under PR 26), and nowhere else.
+    in PERF.md (§5, and §6 under PRs 26 and 29), and nowhere else.
 
     Conservative support gate: TPU backend, no mask, no attention dropout,
     sequence length a multiple of 128, head dim in {64, 128, 256} (the tile

@@ -17,7 +17,9 @@ from __future__ import annotations
 import contextlib
 import threading
 
+import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 _TLS = threading.local()
 
@@ -47,18 +49,60 @@ def current_schedule_tick():
     return t if t is not None else (0.0, 0.0)
 
 
-def device_tick(model):
-    """(it, ep, rng) device arrays for the next step — cached while the
-    host-side mirrors are unchanged."""
+def _placement(params, batch):
+    """``(where, settle)``: where the step will leave the tick it returns,
+    and whether the model's trees have yet to be put there.
+
+    Committed parameters decide: their device, or replicated over their
+    mesh. Uncommitted ones follow a batch that was placed on one device,
+    as jit makes them (``settle``). Anything else is ``None``: every
+    argument uncommitted, or a placement of the caller's own, and jit is
+    left to it."""
+    def committed_leaf(tree):
+        return next((leaf for leaf in jax.tree_util.tree_leaves(tree)
+                     if getattr(leaf, "committed", False)), None)
+
+    leaf = committed_leaf(params)
+    settle = leaf is None
+    if settle:
+        leaf = committed_leaf(batch)
+    if leaf is None:
+        return None, False
+    sharding = leaf.sharding
+    if isinstance(sharding, NamedSharding) and not settle:
+        return NamedSharding(sharding.mesh, PartitionSpec()), False
+    if len(sharding.device_set) != 1:
+        return None, False
+    return sharding, settle
+
+
+def device_tick(model, batch=None):
+    """(it, ep, rng) device arrays for the next step, which will be given
+    ``batch``: cached while the host-side mirrors are unchanged.
+
+    A fresh tick is committed to where the step will leave the one it
+    returns, and where a batch placed on a device decides that, so are the
+    model's trees (no copy is made on their own device: the committed
+    array shares the buffer). jit keeps one executable for uncommitted
+    arguments and another for committed ones, and everything a step
+    returns is committed as soon as one argument was; left as ``init()``
+    makes them, the second call traces, lowers and loads the step a second
+    time (PERF.md §6, PR 29)."""
     mirror = (model.iteration, model.epoch)
     cached = getattr(model, "_tick", None)
     if cached is not None and cached[0] == mirror:
         return cached[1]
-    it = jnp.asarray(float(model.iteration), jnp.float32)
-    ep = jnp.asarray(float(model.epoch), jnp.float32)
-    rng = model._next_rng()
-    model._tick = (mirror, (it, ep, rng))
-    return it, ep, rng
+    tick = (jnp.asarray(float(model.iteration), jnp.float32),
+            jnp.asarray(float(model.epoch), jnp.float32),
+            model._next_rng())
+    where, settle = _placement(model.params, batch)
+    if settle:
+        model.params, model.states, model.updater_states = jax.device_put(
+            (model.params, model.states, model.updater_states), where)
+    if where is not None:
+        tick = jax.device_put(tick, where)
+    model._tick = (mirror, tick)
+    return tick
 
 
 def store_tick(model, new_it, new_rng) -> None:

@@ -197,6 +197,9 @@ class Tracer:
         self.metrics = metrics
         self.service = service
         self.compile_count = 0  # xla_compile spans seen (the recompile alarm)
+        # named tallies of what instrumented code chose while a program was
+        # being traced (which path each attention call took, ...)
+        self.counters: Dict[str, int] = {}
         self._compiles_by_thread: Dict[int, int] = {}
         # per-thread compile+lowering SECONDS (xla_compile AND
         # jax_lowering): the cost plane's exclusion source — a dispatcher
@@ -286,6 +289,11 @@ class Tracer:
         sp.end_ns = int(end_ns)
         self.recorder.add(sp)
         return sp
+
+    def count(self, name: str) -> None:
+        """One more for the tally ``counters[name]``."""
+        with self._compile_lock:
+            self.counters[name] = self.counters.get(name, 0) + 1
 
     # -------------------------------------------- compile attribution sink
     def note_compile_event(self, span_name: str, duration_s: float) -> None:

@@ -272,7 +272,7 @@ class TestCausalFlashAttentionHelper:
 
 
 class TestAutoFlashAttention:
-    """With NO helper registered, causal attention at T >= 2048 auto-uses
+    """With NO helper registered, causal attention at T >= 1024 auto-uses
     the causal flash kernel (opt-out via set_auto_flash_attention) — the
     measured LM-training win should not depend on knowing the seam exists."""
 
@@ -302,7 +302,7 @@ class TestAutoFlashAttention:
         out = A.dot_product_attention(q, k, v, causal=True)
         assert len(calls) == 1 and float(out[0, 0, 0, 0]) == 7.0
         # below the threshold: einsum path
-        q2, k2, v2 = self._qkv(1024)
+        q2, k2, v2 = self._qkv(512)
         A.dot_product_attention(q2, k2, v2, causal=True)
         assert len(calls) == 1
         # non-causal: never auto (the kernel's semantics are causal)
