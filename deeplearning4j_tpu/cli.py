@@ -436,26 +436,13 @@ def cluster_setup_main(argv: Optional[List[str]] = None, runner=None):
 
 
 def _lower_step_hlo(net, ds) -> str:
-    """Compiled HLO text of the net's jitted train step (MLN or graph)."""
+    """Compiled HLO text of the net's jitted train step."""
     import jax.numpy as jnp
-    dtype = net.conf.global_conf.jnp_dtype()
     it = jnp.asarray(net.iteration, jnp.float32)
     ep = jnp.asarray(net.epoch, jnp.float32)
-    rng = net._next_rng()
-    if hasattr(net, "_to_mds"):  # ComputationGraph
-        mds = net._to_mds(ds)
-        inputs = {n: jnp.asarray(f, dtype)
-                  for n, f in zip(net.conf.inputs, mds.features)}
-        labels = [jnp.asarray(l, dtype) for l in mds.labels]
-        step = net._get_train_step()
-        lowered = step.lower(net.params, net.states, net.updater_states,
-                             it, ep, inputs, labels, None, None, rng)
-    else:  # MultiLayerNetwork
-        x = jnp.asarray(np.asarray(ds.features), dtype)
-        y = jnp.asarray(np.asarray(ds.labels), dtype)
-        step = net._get_train_step(False)
-        lowered = step.lower(net.params, net.states, net.updater_states,
-                             it, ep, x, y, None, None, rng, None)
+    lowered = net._get_train_step().lower(
+        net.params, net.states, net.updater_states, it, ep,
+        *net._to_batch(ds), net._next_rng())
     return lowered.compile().as_text()
 
 

@@ -223,13 +223,9 @@ def compiled_memory_analysis(net, batch: int = 32) -> Dict[str, int]:
     y = jnp.zeros(out_shape, dtype)
 
     def step(params, upd_states, x, y):
-        def lf(p):
-            loss, _ = net._loss_fn(p, net.states, x, y, None, None, None,
-                                   train=True)
-            return loss
-        loss, grads = jax.value_and_grad(lf)(params)
-        new_params, new_upd = net._apply_updates(
-            params, grads, upd_states, jnp.float32(0), jnp.float32(0))
+        new_params, _, new_upd, loss, _ = net._step_body(
+            params, net.states, upd_states, jnp.float32(0), jnp.float32(0),
+            (x, y, None, None), None)
         return new_params, new_upd, loss
 
     lowered = jax.jit(step).lower(net.params, net.updater_states, x, y)

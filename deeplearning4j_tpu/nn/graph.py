@@ -4,11 +4,12 @@ Reference: ``nn/graph/ComputationGraph.java`` (3.9k LoC): topological
 execution (``topologicalOrder:152``), ``fit(DataSetIterator):886`` /
 ``fit(MultiDataSetIterator):1010``, ``output``, ``rnnTimeStep``, evaluation.
 
-TPU design mirrors MultiLayerNetwork: params are a dict keyed by vertex name,
-the whole train step (forward over the topo order, summed output losses,
-``jax.grad``, updaters) is ONE jitted donated-buffer function. Vertices are
-pure functions, so the DAG is just function composition — XLA sees a single
-fused program, not an object graph.
+TPU design: params are a dict keyed by vertex name, the whole train step
+(forward over the topo order, summed output losses, ``jax.grad``, updaters) is
+ONE jitted donated-buffer function. Vertices are pure functions, so the DAG is
+just function composition — XLA sees a single fused program, not an object
+graph. The step and the fit loop are ``TrainingEngine``'s (``nn/engine.py``),
+shared with MultiLayerNetwork; this file holds what a DAG needs of its own.
 """
 
 from __future__ import annotations
@@ -21,11 +22,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.nn.conf.graph_conf import ComputationGraphConfiguration
-from deeplearning4j_tpu.nn.constraints import apply_constraints
+from deeplearning4j_tpu.nn.engine import TrainingEngine
 from deeplearning4j_tpu.nn.layers.base import Layer, cast_params
 from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrentLayer, check_carry_capacity
-from deeplearning4j_tpu.nn.updaters import Sgd, Updater, normalize_gradients
-from deeplearning4j_tpu.observe import scope as _scope, trace as _trace
+from deeplearning4j_tpu.nn.updaters import Sgd, Updater
+from deeplearning4j_tpu.observe import scope as _scope
 
 Array = jax.Array
 Params = Dict[str, Dict[str, Array]]
@@ -40,30 +41,8 @@ def _as_jnp(x, dtype=None):
     return x
 
 
-class ComputationGraph:
+class ComputationGraph(TrainingEngine):
     """DAG network over a ComputationGraphConfiguration."""
-
-    # set by parallel.sharding.shard_model_with_rules: when present, fit()/
-    # output() place incoming batches over the mesh's data axis so pjit sees
-    # a consistent DP x MP layout end to end (GSPMD handles the rest), and
-    # the train step pins updated params/opt-state back to the placed specs
-    _mesh = None
-    _param_shardings = None
-    _upd_shardings = None
-
-    def _pin_placements(self, new_params, new_upd):
-        """Inside-jit: constrain step outputs to the rule-placed shardings
-        (see MultiLayerNetwork._pin_placements — one GSPMD-drifted leaf
-        re-layouts every later compile)."""
-        if self._param_shardings is not None:
-            new_params = jax.tree_util.tree_map(
-                jax.lax.with_sharding_constraint, new_params,
-                self._param_shardings)
-        if self._upd_shardings is not None and new_upd is not None:
-            new_upd = jax.tree_util.tree_map(
-                jax.lax.with_sharding_constraint, new_upd,
-                self._upd_shardings)
-        return new_params, new_upd
 
     def __init__(self, conf: ComputationGraphConfiguration):
         conf.finalize()
@@ -83,15 +62,6 @@ class ComputationGraph:
         # cumulative host→device batch payload shipped by fit(); the
         # TraceListener exports deltas as training_transfer_bytes_total
         self.transfer_bytes = 0
-
-    # ---------------------------------------------------------------- score
-    @property
-    def score_(self) -> float:
-        return float("nan") if self._score_arr is None else float(self._score_arr)
-
-    @score_.setter
-    def score_(self, v) -> None:
-        self._score_arr = v
 
     # ----------------------------------------------------------------- init
     def init(self, seed: Optional[int] = None) -> "ComputationGraph":
@@ -121,18 +91,6 @@ class ComputationGraph:
         self.iteration = 0
         self.epoch = 0
         return self
-
-    def _device_tick(self, batch=None):
-        from deeplearning4j_tpu.nn.tick import device_tick
-        return device_tick(self, batch)
-
-    def _store_tick(self, new_it, new_rng) -> None:
-        from deeplearning4j_tpu.nn.tick import store_tick
-        store_tick(self, new_it, new_rng)
-
-    def _next_rng(self) -> jax.Array:
-        self._rng_key, k = jax.random.split(self._rng_key)
-        return k
 
     # -------------------------------------------------------------- forward
     def _forward_all(self, params: Params, states: States,
@@ -288,113 +246,10 @@ class ComputationGraph:
         return loss, (new_states, new_carries)
 
     # ------------------------------------------------------------ train step
-    def _apply_updates(self, params, grads, upd_states, it, ep):
-        # "updater" helper seam (see MultiLayerNetwork._apply_updates):
-        # a registered fused kernel takes the whole per-param RMW
-        from deeplearning4j_tpu.nn import helpers as _helpers
-        uhelper = _helpers.get_helper("updater")
-        new_params: Params = {}
-        new_upd = {}
-        for vd in self.conf.layer_vertices():
-            name = vd.name
-            l: Layer = vd.obj  # type: ignore[assignment]
-            with jax.named_scope(_scope.OPTIMIZER), \
-                    _scope.layer_scope(name, l):
-                g_layer = grads[name]
-                if l.gradient_normalization:
-                    g_layer = normalize_gradients(g_layer, l.gradient_normalization,
-                                                  l.gradient_normalization_threshold)
-                p_new, s_new = {}, {}
-                for n, g in g_layer.items():
-                    u = self._updaters[name][n]
-                    lr = u.lr_at(it, ep)
-                    if uhelper is not None and uhelper.supports(u, params[name][n], g):
-                        p_new[n], s_new[n] = uhelper.apply(
-                            u, params[name][n], g, upd_states[name][n], lr,
-                            it + 1.0)
-                        continue
-                    upd, s = u.update(g, upd_states[name][n], lr, it + 1.0)
-                    p_new[n] = params[name][n] - upd.astype(params[name][n].dtype)
-                    s_new[n] = s
-                # post-update constraints (BaseConstraint.applyConstraint parity)
-                p_new = apply_constraints(l, p_new)
-                new_params[name] = p_new
-                new_upd[name] = s_new
-        return new_params, new_upd
+    def _layer_items(self):
+        return [(vd.name, vd.obj) for vd in self.conf.layer_vertices()]
 
-    def _evict_stale(self, current_version: int) -> None:
-        from deeplearning4j_tpu.nn import helpers as _helpers
-        _helpers.evict_stale_jit_entries(self._jit_cache, current_version)
-
-    def _get_train_step(self, with_carries: bool = False):
-        from deeplearning4j_tpu.nn import helpers as _helpers
-        key = ("train", with_carries, _helpers.version())
-        if key not in self._jit_cache:
-            self._evict_stale(_helpers.version())
-
-            def train_step(params, states, upd_states, it, ep, inputs, labels,
-                           masks, label_masks, rng, carries=None):
-                # on-device key split + returned (it+1, next key): the fit
-                # loop re-feeds them with zero per-step host-side device
-                # ops
-                rng_use, rng_next = jax.random.split(rng)
-
-                def lf(p):
-                    return self._loss_fn(p, states, inputs, labels, rng_use,
-                                         masks, label_masks, train=True,
-                                         carries=carries)
-                from deeplearning4j_tpu.nn.tick import schedule_tick
-                with schedule_tick(it, ep):  # dropout pSchedule sees the tick
-                    (loss, (new_states, new_carries)), grads = \
-                        jax.value_and_grad(lf, has_aux=True)(params)
-                new_params, new_upd = self._apply_updates(params, grads, upd_states, it, ep)
-                new_params, new_upd = self._pin_placements(new_params, new_upd)
-                return (new_params, new_states, new_upd, loss, new_carries,
-                        it + 1.0, rng_next)
-
-            # the program's name in the device trace and the HLO
-            train_step.__name__ = "tbptt_step" if with_carries else "train_step"
-            self._jit_cache[key] = jax.jit(train_step,
-                                           donate_argnums=(0, 1, 2, 3, 9))
-        return self._jit_cache[key]
-
-    def _get_multi_train_step(self):
-        """K train steps as ONE compiled ``lax.scan`` over stacked batches —
-        a single dispatch executes the whole window on device. This is the
-        TPU training-loop idiom: per-step host dispatch disappears, and
-        XLA pipelines the step boundary."""
-        from deeplearning4j_tpu.nn import helpers as _helpers
-        key = ("train_scan", _helpers.version())
-        if key not in self._jit_cache:
-            self._evict_stale(_helpers.version())
-
-            def train_steps_scan(params, states, upd_states, it0, ep, inputs_s,
-                                 labels_s, rng0):
-                def body(carry, xs):
-                    params, states, upd, it, rng = carry
-                    inputs, labels = xs
-                    rng, sub = jax.random.split(rng)
-                    def lf(p):
-                        return self._loss_fn(p, states, inputs, labels, sub,
-                                             None, None, train=True)
-                    from deeplearning4j_tpu.nn.tick import schedule_tick
-                    with schedule_tick(it, ep):
-                        (loss, (new_states, _)), grads = jax.value_and_grad(
-                            lf, has_aux=True)(params)
-                    new_params, new_upd = self._apply_updates(
-                        params, grads, upd, it, ep)
-                    new_params, new_upd = self._pin_placements(new_params,
-                                                               new_upd)
-                    return (new_params, new_states, new_upd, it + 1.0, rng), loss
-
-                (params, states, upd, _, _), losses = jax.lax.scan(
-                    body, (params, states, upd_states, it0, rng0),
-                    (inputs_s, labels_s))
-                return params, states, upd, losses
-
-            self._jit_cache[key] = jax.jit(train_steps_scan,
-                                           donate_argnums=(0, 1, 2))
-        return self._jit_cache[key]
+    _tree_of = staticmethod(dict)
 
     def fit_batches_on_device(self, datasets) -> "ComputationGraph":
         """Train on a window of equal-shape batches in ONE device dispatch
@@ -440,11 +295,11 @@ class ComputationGraph:
     # ------------------------------------------------------------------- fit
     def fit(self, data, labels=None, *, epochs: int = 1,
             prefetch_depth: Optional[int] = None) -> "ComputationGraph":
-        """Train. Iterator sources are auto-wrapped in async host→device
-        prefetch (see MultiLayerNetwork.fit): ``prefetch_depth`` queue
-        slots (default 2), 0 disables, ``async_supported = False`` opts
-        out; ``host_wait`` span + ``training_transfer_bytes_total`` expose
-        any residual input-pipeline stall.
+        """Train on ``(features, labels)`` arrays or lists of them, a DataSet,
+        a MultiDataSet or an iterator of either (``prefetch_depth``: see
+        ``TrainingEngine._fit_epochs``). The per-batch wait shows up as a
+        ``host_wait`` trace span and the shipped payload as
+        ``training_transfer_bytes_total``.
 
         Under ``observe.enable_tracing()`` each step records three spans:
         ``host_wait`` (the wait for its batch), ``step_dispatch`` (the call
@@ -455,10 +310,7 @@ class ComputationGraph:
         their layer's ``Class:name``."""
         if self.params is None:
             self.init()
-        from deeplearning4j_tpu.datasets.dataset import (DataSet,
-                                                         MultiDataSet,
-                                                         batch_nbytes)
-        from deeplearning4j_tpu.datasets.iterators import wrap_for_prefetch
+        from deeplearning4j_tpu.datasets.dataset import DataSet, MultiDataSet
 
         if labels is not None:
             iterator = [MultiDataSet(
@@ -468,26 +320,7 @@ class ComputationGraph:
             iterator = [data]
         else:
             iterator = data
-        iterator = wrap_for_prefetch(iterator, prefetch_depth)
-
-        for _ in range(epochs):
-            for listener in self.listeners:
-                if hasattr(listener, "on_epoch_start"):
-                    listener.on_epoch_start(self)
-            if hasattr(iterator, "reset"):
-                iterator.reset()
-            batches = iter(iterator)
-            while True:
-                with _trace.span("host_wait", category="train"):
-                    ds = next(batches, None)
-                if ds is None:
-                    break
-                self.transfer_bytes += batch_nbytes(ds)
-                self._fit_batch(ds)
-            self.epoch += 1
-            for listener in self.listeners:
-                if hasattr(listener, "on_epoch_end"):
-                    listener.on_epoch_end(self)
+        self._fit_epochs(iterator, epochs, prefetch_depth)
         return self
 
     def _to_mds(self, ds):
@@ -499,7 +332,7 @@ class ComputationGraph:
                 None if ds.labels_mask is None else [ds.labels_mask])
         return ds
 
-    def _fit_batch(self, ds) -> None:
+    def _to_batch(self, ds):
         mds = self._to_mds(ds)
         dtype = self.conf.global_conf.jnp_dtype()
         inputs = {n: _as_jnp(f, dtype) for n, f in zip(self.conf.inputs, mds.features)}
@@ -516,48 +349,7 @@ class ComputationGraph:
             mesh = self._mesh
             inputs, labels, masks, lmasks = jax.tree_util.tree_map(
                 lambda a: place_batch(a, mesh), (inputs, labels, masks, lmasks))
-
-        from deeplearning4j_tpu.nn.conf.network import normalize_backprop_type
-        if normalize_backprop_type(self.conf.backprop_type) == "truncated_bptt":
-            t_total = self._temporal_length(inputs)
-            if t_total is not None:
-                self._fit_tbptt(inputs, labels, masks, lmasks, t_total)
-                return
-
-        step = self._get_train_step()
-        it, ep, rng = self._device_tick(inputs)
-        # Two spans under tracing, and with it off no span and no context
-        # manager. Neither span's body reads a device value, so neither
-        # drains the device; a compile the call pays for nests under its
-        # step_dispatch. The step is called from one line either way: a
-        # Pallas kernel's compiled form carries its call stack, so a second
-        # call site would be a second program in the compile cache.
-        tracer = _trace.get_active_tracer()
-        opened = None if tracer is None else tracer.enter_span(
-            "step_dispatch", category="train",
-            attrs={"iteration": self.iteration})
-        try:
-            (self.params, self.states, self.updater_states, loss, _,
-             new_it, new_rng) = step(
-                self.params, self.states, self.updater_states, it, ep,
-                inputs, labels, masks, lmasks, rng)
-        finally:
-            if opened is not None:
-                tracer.exit_span(*opened)
-        self._score_arr = loss
-        self.last_batch_size = int(next(iter(inputs.values())).shape[0])
-        self.iteration += 1
-        self._store_tick(new_it, new_rng)
-        if tracer is None:
-            self._iteration_done()
-        else:
-            with tracer.span("listeners", category="train"):
-                self._iteration_done()
-
-    def _iteration_done(self) -> None:
-        for listener in self.listeners:
-            if hasattr(listener, "iteration_done"):
-                listener.iteration_done(self, self.iteration, self.epoch)
+        return inputs, labels, masks, lmasks
 
     def _temporal_inputs(self, inputs) -> set:
         """Input names carrying a time axis: decided by the declared
@@ -576,27 +368,25 @@ class ComputationGraph:
             raise ValueError(f"temporal inputs disagree on sequence length: {ts}")
         return ts.pop() if ts else None
 
-    def _fit_tbptt(self, inputs, labels, masks, lmasks, t_total) -> None:
+    def _fit_tbptt(self, batch, t_total) -> None:
         """Truncated BPTT over the DAG (ComputationGraph's TBPTT dispatch in
         the reference fit loop): slice the declared-temporal inputs (and
         per-timestep labels/masks) into tbptt_fwd_length chunks, carrying
         recurrent state (KV caches, positional offsets, LSTM carries)
         between the jitted chunk steps. Per-sequence (2D) labels are fed
         whole to every chunk, as in the sequential-network TBPTT."""
+        inputs, labels, masks, lmasks = batch
         check_carry_capacity(
             ((vd.name, vd.obj) for vd in self.conf.layer_vertices()),
             t_total, "TBPTT")
         temporal = self._temporal_inputs(inputs)
         length = self.conf.tbptt_fwd_length
         n_chunks = max(1, math.ceil(t_total / length))
-        batch = next(iter(inputs.values())).shape[0]
-        self.last_batch_size = int(batch)
+        batch_size = next(iter(inputs.values())).shape[0]
         dtype = self.conf.global_conf.jnp_dtype()
-        carries = {vd.name: vd.obj.init_carry(batch, dtype)
+        carries = {vd.name: vd.obj.init_carry(batch_size, dtype)
                    for vd in self.conf.layer_vertices()
                    if isinstance(vd.obj, BaseRecurrentLayer)}
-
-        step = self._get_train_step(True)
         for c in range(n_chunks):
             s, e = c * length, min((c + 1) * length, t_total)
             ic = {n: (a[:, s:e] if n in temporal else a)
@@ -611,15 +401,7 @@ class ComputationGraph:
                 a[:, s:e] if a is not None and labels[i].ndim == 3
                 and a.shape[1] == t_total else a
                 for i, a in enumerate(lmasks)]
-            it, ep, rng = self._device_tick()
-            (self.params, self.states, self.updater_states, loss, carries,
-             new_it, new_rng) = \
-                step(self.params, self.states, self.updater_states, it, ep,
-                     ic, lc, mc, lmc, rng, carries)
-            self._score_arr = loss
-            self.iteration += 1
-            self._store_tick(new_it, new_rng)
-        self._iteration_done()
+            carries = self._dispatch_step((ic, lc, mc, lmc), carries)
 
     # ------------------------------------------------------------- inference
     def _output_fn(self):
@@ -1111,12 +893,6 @@ class ComputationGraph:
         if self.params is None:
             return self.conf.num_params()
         return sum(v.size for p in self.params.values() for v in p.values())
-
-    def set_listeners(self, *listeners) -> None:
-        self.listeners = list(listeners)
-
-    def add_listeners(self, *listeners) -> None:
-        self.listeners.extend(listeners)
 
     def clone(self) -> "ComputationGraph":
         # jnp.array COPIES the buffers: the original's donating train step

@@ -10,7 +10,9 @@ updater, param update — is ONE jitted function with donated buffers, so XLA
 fuses it and params never leave HBM. There is no Solver/ConvexOptimizer object
 tree; the optimizer loop IS the compiled function (the reference's
 StochasticGradientDescent.optimize():58-98 collapses into it). TBPTT runs the
-jitted chunk step in a host loop carrying stopped-gradient RNN state.
+jitted chunk step in a host loop carrying stopped-gradient RNN state. The step
+and the fit loop are ``TrainingEngine``'s (``nn/engine.py``), shared with
+ComputationGraph; this file holds what a chain of layers needs of its own.
 """
 
 from __future__ import annotations
@@ -24,16 +26,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.nn.conf.network import MultiLayerConfiguration
-from deeplearning4j_tpu.nn.constraints import apply_constraints
+from deeplearning4j_tpu.nn.engine import TrainingEngine
 from deeplearning4j_tpu.nn.layers.base import Layer, cast_params
 from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrentLayer, check_carry_capacity
 from deeplearning4j_tpu.nn.updaters import (
     Sgd,
     Updater,
-    normalize_gradients,
     schedule_value,
 )
-from deeplearning4j_tpu.observe import scope as _scope, trace as _trace
+from deeplearning4j_tpu.observe import scope as _scope
 
 Array = jax.Array
 Params = List[Dict[str, Array]]
@@ -48,32 +49,8 @@ def _as_jnp(x, dtype=None):
     return x
 
 
-class MultiLayerNetwork:
+class MultiLayerNetwork(TrainingEngine):
     """Sequential network over a MultiLayerConfiguration."""
-
-    # set by parallel.sharding.shard_model_with_rules: when present, fit()/
-    # output() place incoming batches over the mesh's data axis so pjit sees
-    # a consistent DP x MP layout end to end (GSPMD handles the rest), and
-    # the train step pins updated params/opt-state back to the placed specs
-    _mesh = None
-    _param_shardings = None
-    _upd_shardings = None
-
-    def _pin_placements(self, new_params, new_upd):
-        """Inside-jit: constrain step outputs to the rule-placed shardings.
-        Without this GSPMD may emit one param with a sharding of its own
-        choosing and every subsequent compile re-layouts around the drifted
-        leaf (observed: a replicated positional table coming back
-        model-sharded cost 18 forward all-gathers)."""
-        if self._param_shardings is not None:
-            new_params = jax.tree_util.tree_map(
-                jax.lax.with_sharding_constraint, new_params,
-                self._param_shardings)
-        if self._upd_shardings is not None and new_upd is not None:
-            new_upd = jax.tree_util.tree_map(
-                jax.lax.with_sharding_constraint, new_upd,
-                self._upd_shardings)
-        return new_params, new_upd
 
     def __init__(self, conf: MultiLayerConfiguration):
         conf.finalize()
@@ -121,28 +98,6 @@ class MultiLayerNetwork:
         self.iteration = 0
         self.epoch = 0
         return self
-
-    @property
-    def score_(self) -> float:
-        """Last minibatch loss. Reading this syncs with the device; the train
-        loop itself never blocks on it (PerformanceListener-friendly)."""
-        return float("nan") if self._score_arr is None else float(self._score_arr)
-
-    @score_.setter
-    def score_(self, v) -> None:
-        self._score_arr = v
-
-    def _next_rng(self) -> jax.Array:
-        self._rng_key, k = jax.random.split(self._rng_key)
-        return k
-
-    def _device_tick(self, batch=None):
-        from deeplearning4j_tpu.nn.tick import device_tick
-        return device_tick(self, batch)
-
-    def _store_tick(self, new_it, new_rng) -> None:
-        from deeplearning4j_tpu.nn.tick import store_tick
-        store_tick(self, new_it, new_rng)
 
     # ------------------------------------------------------------- forward
     def _forward_all(self, params: Params, states: States, x: Array, *,
@@ -263,75 +218,12 @@ class MultiLayerNetwork:
         return loss, (new_states, new_carries)
 
     # ------------------------------------------------------------ train step
-    def _apply_updates(self, params, grads, upd_states, it, ep):
-        # "updater" helper seam: a registered fused kernel (e.g.
-        # PallasUpdaterHelper) takes the whole per-param read-modify-write;
-        # consulted at trace time, versioned into the train-step cache key
-        from deeplearning4j_tpu.nn import helpers as _helpers
-        uhelper = _helpers.get_helper("updater")
-        new_params, new_upd = [], []
-        for i, l in enumerate(self.layers):
-            with jax.named_scope(_scope.OPTIMIZER), \
-                    _scope.layer_scope(i, l):
-                g_layer = grads[i]
-                if l.gradient_normalization:
-                    g_layer = normalize_gradients(g_layer, l.gradient_normalization,
-                                                  l.gradient_normalization_threshold)
-                p_new, s_new = {}, {}
-                for n, g in g_layer.items():
-                    u = self._updaters[i][n]
-                    lr = u.lr_at(it, ep)
-                    t = it + 1.0  # 1-based step count for Adam-family bias correction
-                    if uhelper is not None and uhelper.supports(u, params[i][n], g):
-                        p_new[n], s_new[n] = uhelper.apply(
-                            u, params[i][n], g, upd_states[i][n], lr, t)
-                        continue
-                    upd, s = u.update(g, upd_states[i][n], lr, t)
-                    p_new[n] = params[i][n] - upd.astype(params[i][n].dtype)
-                    s_new[n] = s
-                # post-update parameter constraints (BaseConstraint.applyConstraint
-                # runs after each iteration in the reference) — fused into the
-                # jitted step, not a separate host pass
-                p_new = apply_constraints(l, p_new)
-                new_params.append(p_new)
-                new_upd.append(s_new)
-        return new_params, new_upd
+    def _layer_items(self):
+        return list(enumerate(self.layers))
 
-    def _build_train_step(self, tbptt: bool):
-        def train_step(params, states, upd_states, it, ep, x, y, mask, label_mask, rng, carries):
-            # split on DEVICE and return the next key + iteration: the fit
-            # loop then re-feeds them without any per-step host-side device
-            # ops (a host rng split + two scalar placements)
-            rng_use, rng_next = jax.random.split(rng)
-
-            def lf(p):
-                return self._loss_fn(p, states, x, y, rng_use, mask, label_mask,
-                                     train=True, carries=carries if tbptt else None)
-            from deeplearning4j_tpu.nn.tick import schedule_tick
-            with schedule_tick(it, ep):  # dropout pSchedule sees the device tick
-                (loss, (new_states, new_carries)), grads = jax.value_and_grad(lf, has_aux=True)(params)
-            new_params, new_upd = self._apply_updates(params, grads, upd_states, it, ep)
-            new_params, new_upd = self._pin_placements(new_params, new_upd)
-            if tbptt:
-                new_carries = jax.tree_util.tree_map(jax.lax.stop_gradient, new_carries)
-            return new_params, new_states, new_upd, loss, new_carries, it + 1.0, rng_next
-
-        # the program's name in the device trace and the HLO
-        train_step.__name__ = "tbptt_step" if tbptt else "train_step"
-        return jax.jit(train_step, donate_argnums=(0, 1, 2, 3, 9))
-
-    def _get_train_step(self, tbptt: bool):
-        key = ("train", tbptt)
-        from deeplearning4j_tpu.nn import helpers as _helpers
-        key = key + (_helpers.version(),)
-        if key not in self._jit_cache:
-            self._evict_stale(_helpers.version())
-            self._jit_cache[key] = self._build_train_step(tbptt)
-        return self._jit_cache[key]
-
-    def _evict_stale(self, current_version: int) -> None:
-        from deeplearning4j_tpu.nn import helpers as _helpers
-        _helpers.evict_stale_jit_entries(self._jit_cache, current_version)
+    @staticmethod
+    def _tree_of(pairs):
+        return [v for _, v in pairs]
 
     # ------------------------------------------------------------------- fit
     def fit(self, data, labels=None, *, epochs: int = 1,
@@ -357,9 +249,7 @@ class MultiLayerNetwork:
         ``Class:index``."""
         if self.params is None:
             self.init()
-        from deeplearning4j_tpu.datasets.dataset import (DataSet,  # no cycle
-                                                         batch_nbytes)
-        from deeplearning4j_tpu.datasets.iterators import wrap_for_prefetch
+        from deeplearning4j_tpu.datasets.dataset import DataSet  # no cycle
 
         if labels is not None:
             iterator = [DataSet(data, labels, features_mask, labels_mask)]
@@ -367,65 +257,8 @@ class MultiLayerNetwork:
             iterator = [data]
         else:
             iterator = data  # assume iterable of DataSet
-        iterator = wrap_for_prefetch(iterator, prefetch_depth)
-
-        for ep in range(epochs):
-            for listener in self.listeners:
-                if hasattr(listener, "on_epoch_start"):
-                    listener.on_epoch_start(self)
-            epoch_iter = iterator
-            if hasattr(epoch_iter, "reset"):
-                epoch_iter.reset()
-            batches = iter(epoch_iter)
-            while True:
-                # host_wait = time the training thread blocks on the input
-                # pipeline; ~zero when prefetch keeps the queue warm
-                with _trace.span("host_wait", category="train"):
-                    ds = next(batches, None)
-                if ds is None:
-                    break
-                self.transfer_bytes += batch_nbytes(ds)
-                self._fit_batch(ds)
-            self.epoch += 1
-            for listener in self.listeners:
-                if hasattr(listener, "on_epoch_end"):
-                    listener.on_epoch_end(self)
+        self._fit_epochs(iterator, epochs, prefetch_depth)
         return self
-
-    def _get_multi_train_step(self):
-        """K train steps as ONE compiled ``lax.scan`` over stacked batches
-        (ComputationGraph._get_multi_train_step counterpart — see
-        :meth:`fit_batches_on_device`)."""
-        from deeplearning4j_tpu.nn import helpers as _helpers
-        key = ("train_scan", _helpers.version())
-        if key not in self._jit_cache:
-            self._evict_stale(_helpers.version())
-
-            def train_steps_scan(params, states, upd_states, it0, ep, xs, ys, rng0):
-                def body(carry, batch):
-                    params, states, upd, it, rng = carry
-                    x, y = batch
-                    rng, sub = jax.random.split(rng)
-                    def lf(p):
-                        return self._loss_fn(p, states, x, y, sub, None, None,
-                                             train=True)
-                    from deeplearning4j_tpu.nn.tick import schedule_tick
-                    with schedule_tick(it, ep):
-                        (loss, (new_states, _)), grads = jax.value_and_grad(
-                            lf, has_aux=True)(params)
-                    new_params, new_upd = self._apply_updates(
-                        params, grads, upd, it, ep)
-                    new_params, new_upd = self._pin_placements(new_params,
-                                                               new_upd)
-                    return (new_params, new_states, new_upd, it + 1.0, rng), loss
-
-                (params, states, upd, _, _), losses = jax.lax.scan(
-                    body, (params, states, upd_states, it0, rng0), (xs, ys))
-                return params, states, upd, losses
-
-            self._jit_cache[key] = jax.jit(train_steps_scan,
-                                           donate_argnums=(0, 1, 2))
-        return self._jit_cache[key]
 
     def fit_batches_on_device(self, datasets) -> "MultiLayerNetwork":
         """Train on a window of equal-shape batches in ONE device dispatch
@@ -460,7 +293,7 @@ class MultiLayerNetwork:
             self._iteration_done()
         return self
 
-    def _fit_batch(self, ds) -> None:
+    def _to_batch(self, ds):
         dtype = self.conf.global_conf.jnp_dtype()
         x = _as_jnp(ds.features, dtype)
         y = _as_jnp(ds.labels, dtype)
@@ -472,53 +305,17 @@ class MultiLayerNetwork:
             y = place_batch(y, self._mesh)
             mask = place_batch(mask, self._mesh)
             lmask = place_batch(lmask, self._mesh)
+        return x, y, mask, lmask
 
-        from deeplearning4j_tpu.nn.conf.network import normalize_backprop_type
-        if (normalize_backprop_type(self.conf.backprop_type) == "truncated_bptt"
-                and x.ndim == 3):
-            self._fit_tbptt(x, y, mask, lmask)
-            return
+    @staticmethod
+    def _temporal_length(x):
+        return x.shape[1] if x.ndim == 3 else None
 
-        step = self._get_train_step(False)
-        it, ep, rng = self._device_tick(x)
-        # Two spans under tracing, and with it off no span and no context
-        # manager. Neither span's body reads a device value, so neither
-        # drains the device; a compile the call pays for nests under its
-        # step_dispatch. The step is called from one line either way: a
-        # Pallas kernel's compiled form carries its call stack, so a second
-        # call site would be a second program in the compile cache.
-        tracer = _trace.get_active_tracer()
-        opened = None if tracer is None else tracer.enter_span(
-            "step_dispatch", category="train",
-            attrs={"iteration": self.iteration})
-        try:
-            (self.params, self.states, self.updater_states, loss, _,
-             new_it, new_rng) = step(
-                self.params, self.states, self.updater_states, it, ep,
-                x, y, mask, lmask, rng, None)
-        finally:
-            if opened is not None:
-                tracer.exit_span(*opened)
-        self._score_arr = loss
-        self.last_batch_size = int(x.shape[0])
-        self.iteration += 1
-        self._store_tick(new_it, new_rng)
-        if tracer is None:
-            self._iteration_done()
-        else:
-            with tracer.span("listeners", category="train"):
-                self._iteration_done()
-
-    def _iteration_done(self) -> None:
-        for listener in self.listeners:
-            if hasattr(listener, "iteration_done"):
-                listener.iteration_done(self, self.iteration, self.epoch)
-
-    def _fit_tbptt(self, x, y, mask, lmask) -> None:
+    def _fit_tbptt(self, batch, t_total) -> None:
         """Truncated BPTT (MultiLayerNetwork.doTruncatedBPTT:1309 parity):
         process the sequence in chunks of tbptt_fwd_length, carrying RNN state
         (stop-gradient) between chunks."""
-        t_total = x.shape[1]
+        x, y, mask, lmask = batch
         # the chunk steps are jitted, where a finite carry (KV cache,
         # positional offset) cannot raise on overflow — reject here instead
         check_carry_capacity(
@@ -526,10 +323,7 @@ class MultiLayerNetwork:
              for i, l in enumerate(self.layers)), t_total, "TBPTT")
         length = self.conf.tbptt_fwd_length
         n_chunks = max(1, math.ceil(t_total / length))
-        batch = x.shape[0]
-        self.last_batch_size = int(batch)
-        dtype = x.dtype
-        carries = [l.init_carry(batch, dtype) if isinstance(l, BaseRecurrentLayer) else None
+        carries = [l.init_carry(x.shape[0], x.dtype) if isinstance(l, BaseRecurrentLayer) else None
                    for l in self.layers]
         for c in range(n_chunks):
             s, e = c * length, min((c + 1) * length, t_total)
@@ -537,16 +331,7 @@ class MultiLayerNetwork:
             yc = y[:, s:e] if y.ndim == 3 else y
             mc = None if mask is None else mask[:, s:e]
             lc = None if lmask is None else lmask[:, s:e]
-            step = self._get_train_step(True)
-            it, ep, rng = self._device_tick()
-            (self.params, self.states, self.updater_states, loss, carries,
-             new_it, new_rng) = step(
-                self.params, self.states, self.updater_states, it, ep,
-                xc, yc, mc, lc, rng, carries)
-            self._score_arr = loss
-            self.iteration += 1
-            self._store_tick(new_it, new_rng)
-        self._iteration_done()
+            carries = self._dispatch_step((xc, yc, mc, lc), carries)
 
     # ------------------------------------------------------------- inference
     def _output_fn(self):
@@ -978,13 +763,6 @@ class MultiLayerNetwork:
             out = self.output(ds.features)
             e.eval(np.asarray(ds.labels), np.asarray(out))
         return e
-
-    # -------------------------------------------------------------- listeners
-    def set_listeners(self, *listeners) -> None:
-        self.listeners = list(listeners)
-
-    def add_listeners(self, *listeners) -> None:
-        self.listeners.extend(listeners)
 
     def summary(self) -> str:
         """Layer table with parameter counts

@@ -301,17 +301,10 @@ class SharedTrainingMaster(TrainingMaster):
             # arrive as this worker's [1, *param_shape] slice of the stacked
             # per-worker residual state.
             rng = jax.random.fold_in(rng, jax.lax.axis_index(daxis))
-
-            def lf(p):
-                return net._loss_fn(p, states, x, y, rng, None, None, train=True)
-
-            from deeplearning4j_tpu.nn.tick import schedule_tick
-            with schedule_tick(it, ep):  # dropout pSchedule sees the tick
-                (loss, (new_states, _)), grads = jax.value_and_grad(
-                    lf, has_aux=True)(params)
             # local updater: update magnitudes, not raw grads, are shared
             # (StochasticGradientDescent.java:66-73 stores the UPDATE)
-            stepped, new_upd = net._apply_updates(params, grads, upd, it, ep)
+            stepped, new_states, new_upd, loss, _ = net._step_body(
+                params, states, upd, it, ep, (x, y, None, None), rng)
             update = jax.tree_util.tree_map(lambda a, b: a - b, params, stepped)
             acc = jax.tree_util.tree_map(lambda r, u: r + u[None], residual, update)
             quant = jax.tree_util.tree_map(

@@ -41,21 +41,13 @@ from deeplearning4j_tpu.parallel.sharding import batch_sharding, shard_model
 from deeplearning4j_tpu.datasets.dataset import batch_nbytes as _batch_nbytes
 
 
-def make_pure_step(net, train: bool = True):
-    """Extract the model's train step as a pure function
-    ``(params, states, upd, it, ep, x, y, mask, lmask, rng) ->
+def make_pure_step(net):
+    """The model's train step (``TrainingEngine._step_body``) as a pure
+    function ``(params, states, upd, it, ep, x, y, mask, lmask, rng) ->
     (params, states, upd, loss)`` suitable for scan/shard_map composition."""
-
     def step(params, states, upd, it, ep, x, y, mask, lmask, rng):
-        def lf(p):
-            return net._loss_fn(p, states, x, y, rng, mask, lmask, train=train)
-
-        from deeplearning4j_tpu.nn.tick import schedule_tick
-        with schedule_tick(it, ep):  # dropout pSchedule sees the tick here too
-            (loss, (new_states, _)), grads = jax.value_and_grad(lf, has_aux=True)(params)
-        new_params, new_upd = net._apply_updates(params, grads, upd, it, ep)
-        return new_params, new_states, new_upd, loss
-
+        return net._step_body(params, states, upd, it, ep,
+                              (x, y, mask, lmask), rng)[:4]
     return step
 
 
