@@ -426,7 +426,7 @@ class StepProbe:
         self._t = now
 
 
-def _flash_in_train_step(net, vocab: int, batch: int) -> bool:
+def _flash_in_train_step(net, batch: int) -> bool:
     """Whether the lowered train step holds the causal flash kernel (the
     layers share one definition of it, so there is nothing to count)."""
     import jax
@@ -435,7 +435,7 @@ def _flash_in_train_step(net, vocab: int, batch: int) -> bool:
     step = net._get_train_step()
     it, ep, rng = net._device_tick()
     tokens = jax.ShapeDtypeStruct((batch, LM_T), jnp.int32)
-    labels = jax.ShapeDtypeStruct((batch, LM_T, vocab), jnp.float32)
+    labels = jax.ShapeDtypeStruct((batch, LM_T), jnp.int32)
     text = step.lower(net.params, net.states, net.updater_states, it, ep,
                       {"tokens": tokens}, [labels], None, None, rng).as_text()
     check("tpu_custom_call" in text, "train step holds no tpu_custom_call")
@@ -463,7 +463,7 @@ def phase_train(ctx) -> dict:
     steady = probe.rows[LM_WARMUP - 1]["compiles"]
     check(probe.rows[-1]["compiles"] == steady,
           f"steps after warm-up compiled: {[r['compiles'] for r in probe.rows]}")
-    check(_flash_in_train_step(net, vocab, LM_BATCH),
+    check(_flash_in_train_step(net, LM_BATCH),
           "the causal flash gate did not open at T=2048: no "
           f"{FLASH_KERNEL_IN_STEP} in the lowered train step")
     stats = jax.devices()[0].memory_stats() or {}

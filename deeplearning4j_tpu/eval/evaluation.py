@@ -57,10 +57,12 @@ class Evaluation:
              record_meta_data: Optional[List[Any]] = None) -> None:
         labels = np.asarray(labels)
         predictions = np.asarray(predictions)
-        if labels.ndim == 3:  # [N,T,C] → flatten time, applying mask
-            n, t, c = labels.shape
-            labels = labels.reshape(n * t, c)
-            predictions = predictions.reshape(n * t, -1)
+        if predictions.ndim == 3:  # [N,T,C] → flatten time, applying mask
+            n, t, c = predictions.shape
+            # one-hot rows [N,T,C], or integer class ids [N,T]
+            labels = (labels.reshape(n * t, -1) if labels.ndim == 3
+                      else labels.reshape(n * t))
+            predictions = predictions.reshape(n * t, c)
             if record_meta_data is not None:
                 record_meta_data = [m for m in record_meta_data
                                     for _ in range(t)]

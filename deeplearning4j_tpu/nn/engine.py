@@ -17,6 +17,7 @@ engine's own shapes.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn import helpers as _helpers
 from deeplearning4j_tpu.nn.conf.network import normalize_backprop_type
@@ -24,6 +25,15 @@ from deeplearning4j_tpu.nn.constraints import apply_constraints
 from deeplearning4j_tpu.nn.tick import device_tick, schedule_tick, store_tick
 from deeplearning4j_tpu.nn.updaters import normalize_gradients
 from deeplearning4j_tpu.observe import scope as _scope, trace as _trace
+
+
+def per_timestep_labels(labels, t_total: int) -> bool:
+    """Whether TBPTT cuts ``labels`` along time with the inputs: one-hot or
+    soft rows ``[N,T,C]``, or integer class ids ``[N,T]``. Per-sequence
+    labels (``[N,C]``, ids ``[N]``) go whole to every chunk."""
+    timed = labels.ndim == 3 or (
+        labels.ndim == 2 and jnp.issubdtype(labels.dtype, jnp.integer))
+    return timed and labels.shape[1] == t_total
 
 
 class TrainingEngine:

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.nn.conf.graph_conf import ComputationGraphConfiguration
-from deeplearning4j_tpu.nn.engine import TrainingEngine
+from deeplearning4j_tpu.nn.engine import TrainingEngine, per_timestep_labels
 from deeplearning4j_tpu.nn.layers.base import Layer, cast_params
 from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrentLayer, check_carry_capacity
 from deeplearning4j_tpu.nn.updaters import Sgd, Updater
@@ -373,8 +373,9 @@ class ComputationGraph(TrainingEngine):
         the reference fit loop): slice the declared-temporal inputs (and
         per-timestep labels/masks) into tbptt_fwd_length chunks, carrying
         recurrent state (KV caches, positional offsets, LSTM carries)
-        between the jitted chunk steps. Per-sequence (2D) labels are fed
-        whole to every chunk, as in the sequential-network TBPTT."""
+        between the jitted chunk steps. Per-sequence labels (``[N,C]``, class
+        ids ``[N]``) are fed whole to every chunk, as in the
+        sequential-network TBPTT."""
         inputs, labels, masks, lmasks = batch
         check_carry_capacity(
             ((vd.name, vd.obj) for vd in self.conf.layer_vertices()),
@@ -391,14 +392,15 @@ class ComputationGraph(TrainingEngine):
             s, e = c * length, min((c + 1) * length, t_total)
             ic = {n: (a[:, s:e] if n in temporal else a)
                   for n, a in inputs.items()}
-            lc = [a[:, s:e] if a.ndim == 3 and a.shape[1] == t_total else a
+            lc = [a[:, s:e] if per_timestep_labels(a, t_total) else a
                   for a in labels]
             mc = None if masks is None else {
                 n: (a[:, s:e] if a is not None and n in temporal
                     and a.shape[1] == t_total else a)
                 for n, a in masks.items()}
             lmc = None if lmasks is None else [
-                a[:, s:e] if a is not None and labels[i].ndim == 3
+                a[:, s:e] if a is not None
+                and per_timestep_labels(labels[i], t_total)
                 and a.shape[1] == t_total else a
                 for i, a in enumerate(lmasks)]
             carries = self._dispatch_step((ic, lc, mc, lmc), carries)

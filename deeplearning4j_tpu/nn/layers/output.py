@@ -113,7 +113,9 @@ class CnnLossLayer(LossLayer):
         out = x if wants_logits else self.act_fn()(x)
         n = out.shape[0]
         out2 = out.reshape(n, -1, out.shape[-1])
-        lab2 = labels.reshape(n, -1, labels.shape[-1])
+        # one-hot maps [N,H,W,C], or class ids [N,H,W]
+        lab2 = (labels.reshape(n, -1, labels.shape[-1])
+                if labels.ndim == out.ndim else labels.reshape(n, -1))
         m2 = None if mask is None else mask.reshape(n, -1)
         return fn(lab2, out2, mask=m2)
 
@@ -143,6 +145,8 @@ class CenterLossOutputLayer(OutputLayer):
     def compute_loss(self, params, x, labels, mask=None):
         base = super().compute_loss(params, x, labels, mask)
         # center loss: ||x - c_y||^2 / 2 averaged over batch
-        centers = labels @ params["cL"]  # one-hot labels pick centers
+        # one-hot labels pick centers, as class ids do
+        centers = (labels @ params["cL"] if labels.ndim == x.ndim
+                   else jnp.take(params["cL"], labels, axis=0))
         center_l = 0.5 * jnp.mean(jnp.sum((x - centers) ** 2, axis=-1))
         return base + self.lambda_ * center_l

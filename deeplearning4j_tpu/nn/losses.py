@@ -23,6 +23,8 @@ from typing import Callable, Optional, Union
 import jax
 import jax.numpy as jnp
 
+from deeplearning4j_tpu.observe import trace as _trace
+
 Array = jax.Array
 _EPS = 1e-7
 
@@ -87,25 +89,84 @@ def msle(labels: Array, preds: Array, mask=None, weights=None) -> Array:
     return _apply_mask_mean(per, mask)
 
 
+def _are_class_ids(labels: Array, scores: Array) -> bool:
+    """Whether ``labels`` name each target by its class id: an integer array
+    with one dimension fewer than the scores it is compared with. One-hot
+    and soft labels have the scores' own rank, whatever their dtype."""
+    ids = labels.ndim == scores.ndim - 1
+    if ids and not jnp.issubdtype(labels.dtype, jnp.integer):
+        raise ValueError(
+            f"labels of shape {labels.shape} for scores of shape "
+            f"{scores.shape} must be integer class ids, not {labels.dtype}")
+    tracer = _trace.get_active_tracer()
+    if tracer is not None:
+        # which form this loss was given, counted while its step is traced
+        tracer.count("loss.class_id_calls" if ids else "loss.one_hot_calls")
+    return ids
+
+
+def _is_target(ids: Array, n_classes: int) -> Array:
+    """The one-hot of ``ids`` as a comparison with an iota: a fusion reads
+    it from the ids, so it never exists as an array of the scores' shape."""
+    return jax.lax.broadcasted_iota(
+        jnp.int32, ids.shape + (n_classes,), ids.ndim
+    ) == ids[..., None].astype(jnp.int32)
+
+
+@jax.custom_vjp
+def softmax_xent_ids(logits: Array, ids: Array) -> Array:
+    """Per-position softmax cross entropy ``lse(logits) - logits[ids]`` for
+    integer class ``ids`` of the logits' leading shape.
+
+    The backward is written by hand: one pass that reads the logits and
+    writes their gradient, ``(softmax - onehot) * g``, with the target as a
+    comparison inside the fusion. Autodiff of ``log_softmax`` would add a
+    ``sum`` over the cotangent (it cannot know a one-hot sums to one), and
+    of a gather a scatter into a zero-filled array of the logits' shape."""
+    return _softmax_xent_ids_fwd(logits, ids)[0]
+
+
+def _softmax_xent_ids_fwd(logits, ids):
+    top = jnp.max(logits, axis=-1)
+    lse = top + jnp.log(jnp.sum(jnp.exp(logits - top[..., None]), axis=-1))
+    picked = jnp.sum(
+        jnp.where(_is_target(ids, logits.shape[-1]), logits, 0.0), axis=-1)
+    return lse - picked, (logits, lse, ids)
+
+
+def _softmax_xent_ids_bwd(res, g):
+    logits, lse, ids = res
+    softmax = jnp.exp(logits - lse[..., None])
+    onehot = _is_target(ids, logits.shape[-1]).astype(logits.dtype)
+    return (softmax - onehot) * g[..., None], None
+
+
+softmax_xent_ids.defvjp(_softmax_xent_ids_fwd, _softmax_xent_ids_bwd)
+
+
 def mcxent_logits(labels: Array, logits: Array, mask=None, weights=None) -> Array:
-    """Multi-class cross entropy fused with softmax (stable)."""
+    """Multi-class cross entropy fused with softmax (stable). ``labels`` are
+    one-hot or soft rows of the logits' shape, or integer class ids with one
+    dimension fewer (``sparse_mcxent``): the same loss, without the rows."""
+    if _are_class_ids(labels, logits):
+        per = softmax_xent_ids(logits, labels)
+        if weights is not None:
+            per = per * jnp.take(weights, labels)
+        return _apply_mask_mean(per, mask)
     logp = jax.nn.log_softmax(logits, axis=-1)
     per = -_featurewise(labels * logp, weights)
     return _apply_mask_mean(per, mask)
 
 
 def mcxent_probs(labels: Array, probs: Array, mask=None, weights=None) -> Array:
+    if _are_class_ids(labels, probs):
+        labels = _is_target(labels, probs.shape[-1]).astype(probs.dtype)
     per = -_featurewise(labels * jnp.log(jnp.clip(probs, _EPS, 1.0)), weights)
     return _apply_mask_mean(per, mask)
 
 
-def sparse_mcxent_logits(labels: Array, logits: Array, mask=None, weights=None) -> Array:
-    """Labels are integer class indices, not one-hot."""
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    per = -jnp.take_along_axis(logp, labels[..., None].astype(jnp.int32), axis=-1)[..., 0]
-    if weights is not None:
-        per = per * jnp.take(weights, labels.astype(jnp.int32))
-    return _apply_mask_mean(per, mask)
+# class ids are a form of label, not another loss
+sparse_mcxent_logits = mcxent_logits
 
 
 def xent_logits(labels: Array, logits: Array, mask=None, weights=None) -> Array:
@@ -180,7 +241,7 @@ _REGISTRY: dict[str, tuple[LossFn, Optional[str]]] = {
     "mean_absolute_percentage_error": (mape, None),
     "mcxent": (mcxent_logits, "softmax"),
     "negativeloglikelihood": (negativeloglikelihood_logits, "softmax"),
-    "sparse_mcxent": (sparse_mcxent_logits, "softmax"),
+    "sparse_mcxent": (mcxent_logits, "softmax"),
     "xent": (xent_logits, "sigmoid"),
     "binary_xent": (xent_logits, "sigmoid"),
     "hinge": (hinge, None),
@@ -197,6 +258,7 @@ _REGISTRY: dict[str, tuple[LossFn, Optional[str]]] = {
 _PROB_SPACE: dict[str, LossFn] = {
     "mcxent": mcxent_probs,
     "negativeloglikelihood": mcxent_probs,
+    "sparse_mcxent": mcxent_probs,
     "xent": xent_probs,
     "binary_xent": xent_probs,
 }

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.nn.conf.network import MultiLayerConfiguration
-from deeplearning4j_tpu.nn.engine import TrainingEngine
+from deeplearning4j_tpu.nn.engine import TrainingEngine, per_timestep_labels
 from deeplearning4j_tpu.nn.layers.base import Layer, cast_params
 from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrentLayer, check_carry_capacity
 from deeplearning4j_tpu.nn.updaters import (
@@ -328,7 +328,7 @@ class MultiLayerNetwork(TrainingEngine):
         for c in range(n_chunks):
             s, e = c * length, min((c + 1) * length, t_total)
             xc = x[:, s:e]
-            yc = y[:, s:e] if y.ndim == 3 else y
+            yc = y[:, s:e] if per_timestep_labels(y, t_total) else y
             mc = None if mask is None else mask[:, s:e]
             lc = None if lmask is None else lmask[:, s:e]
             carries = self._dispatch_step((xc, yc, mc, lc), carries)

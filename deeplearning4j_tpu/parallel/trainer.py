@@ -302,7 +302,10 @@ class ParallelWrapper:
                 self._m_transfer.inc(sum(_batch_nbytes(d) for d in pending),
                                      model=self._metrics_name)
             xs = jnp.stack([jnp.asarray(d.features, dtype) for d in pending])
-            ys = jnp.stack([jnp.asarray(d.labels, dtype) for d in pending])
+            # class ids stay integers; one-hot and soft labels take dtype
+            ys = jnp.stack([
+                y if jnp.issubdtype(y.dtype, jnp.integer) else y.astype(dtype)
+                for y in (jnp.asarray(d.labels) for d in pending)])
             fms = stack_masks([d.features_mask for d in pending],
                               [d.features for d in pending])
             lms = stack_masks([d.labels_mask for d in pending],

@@ -709,8 +709,9 @@ class TransformerLM(ZooModel):
     of ``TextGenerationLSTM`` (``zoo/model/TextGenerationLSTM.java``): token
     ids [N,T] → embedding + learned positions → n pre-LN causal decoder
     blocks → final LayerNorm → per-timestep softmax over the vocabulary
-    (RnnOutputLayer, MCXENT). Labels are the inputs shifted left by one
-    (see :func:`lm_labels`).
+    (RnnOutputLayer, MCXENT). Labels are the inputs shifted left by one, as
+    int32 class ids [N,T] (see :func:`lm_labels`): the loss picks each
+    target by its id, and no [N,T,V] one-hot exists on host or device.
 
     Generation uses the network's stateful ``rnn_time_step`` path: every
     causal attention layer carries a fixed-capacity KV cache, so sampling N
@@ -771,15 +772,18 @@ class TransformerLM(ZooModel):
 
 
 def lm_labels(tokens, vocab_size: int):
-    """Next-token one-hot targets for causal LM training: labels[t] =
-    onehot(tokens[t+1]); the last step repeats the last token (give it a
-    [N,T] label mask with 0 in the final column to drop it from the loss)."""
+    """Next-token targets for causal LM training, as int32 class ids [N,T]:
+    labels[t] = tokens[t+1]; the last step repeats the last token (give it a
+    [N,T] label mask with 0 in the final column to drop it from the loss).
+    An id outside ``[0, vocab_size)`` raises here, on the host: the compiled
+    loss would clamp it in silence."""
     import numpy as np
     ids = np.asarray(tokens).astype(np.int64)
-    shifted = np.concatenate([ids[:, 1:], ids[:, -1:]], axis=1)
-    out = np.zeros(shifted.shape + (vocab_size,), np.float32)
-    np.put_along_axis(out, shifted[..., None], 1.0, axis=-1)
-    return out
+    if ids.size and not (0 <= ids.min() and ids.max() < vocab_size):
+        raise ValueError(
+            f"token ids must lie in [0, {vocab_size}); got "
+            f"[{ids.min()}, {ids.max()}]")
+    return np.concatenate([ids[:, 1:], ids[:, -1:]], axis=1).astype(np.int32)
 
 
 def generate(net, prompt_ids, n_new_tokens: int, temperature: float = 0.0,
@@ -1339,8 +1343,8 @@ class HybridConvMoELM(ZooModel):
     sparse expert layer after them (sigmoid routing with a selection-only
     expert bias, normalised top-k weights, SwiGLU experts) → RMSNorm →
     untied softmax head. No positional-embedding vertex: the attention
-    layers rotate q and k. Labels as for :class:`TransformerLM`
-    (:func:`lm_labels`).
+    layers rotate q and k. Labels as for :class:`TransformerLM`: int32
+    class ids [N,T] (:func:`lm_labels`).
 
     ``experts_held=(first, count)`` builds every expert layer as that share
     of the experts (see :class:`MixtureOfExpertsLayer`): what one chip of an

@@ -45,7 +45,7 @@ def main():
                       d_model=32, n_heads=4, d_ff=64, seed=3)
     net = ComputationGraph(m.conf()).init()
     x = cycle(rng, 64, 32)
-    y = lm_labels(x, VOCAB)
+    y = lm_labels(x, VOCAB)                  # int32 class ids [N,T]
     lmask = np.ones(x.shape[:2], np.float32)
     lmask[:, -1] = 0.0                       # final step has no next token
     ds = DataSet(x, y, labels_mask=lmask)

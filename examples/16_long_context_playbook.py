@@ -88,7 +88,7 @@ def main():
         print("single device: sequence parallelism needs a mesh — skipped")
 
     # -- 3. gradient checkpointing ------------------------------------------
-    y = lm_labels(ids, VOCAB)
+    y = lm_labels(ids, VOCAB)     # next-token class ids [N,T], no one-hot
     for remat in (False, True):
         net_r = small_lm(gradient_checkpointing=remat)
         net_r.fit(ids, y)

@@ -114,9 +114,35 @@ class TestTraining:
     def test_lm_labels_shift(self):
         ids = np.array([[0, 1, 2, 3]])
         lab = lm_labels(ids, 5)
-        assert lab.shape == (1, 4, 5)
-        assert lab[0, 0, 1] == 1.0 and lab[0, 2, 3] == 1.0
-        assert lab[0, 3, 3] == 1.0  # final step repeats last id
+        assert lab.shape == (1, 4) and lab.dtype == np.int32
+        assert lab[0, 0] == 1 and lab[0, 2] == 3
+        assert lab[0, 3] == 3  # final step repeats last id
+
+    def test_class_id_labels_score_like_one_hot_rows(self):
+        net = tiny_lm()
+        x = cycle_batch(np.random.default_rng(2), 4, 16)
+        ids = lm_labels(x, VOCAB)
+        rows = np.eye(VOCAB, dtype=np.float32)[ids]
+        lmask = np.ones(x.shape[:2], np.float32)
+        lmask[:, -1] = 0.0
+        np.testing.assert_allclose(
+            net.score(DataSet(x, ids, labels_mask=lmask)),
+            net.score(DataSet(x, rows, labels_mask=lmask)), rtol=1e-6)
+        np.testing.assert_allclose(net.score_examples(DataSet(x, ids)),
+                                   net.score_examples(DataSet(x, rows)),
+                                   rtol=1e-5)
+
+    def test_evaluate_counts_next_token_hits_from_ids(self):
+        net = tiny_lm()
+        x = cycle_batch(np.random.default_rng(3), 4, 16)
+        lmask = np.ones(x.shape[:2], np.float32)
+        lmask[:, -1] = 0.0
+        ev = net.evaluate([DataSet(x, lm_labels(x, VOCAB),
+                                   labels_mask=lmask)])
+        assert ev.confusion.sum() == 4 * 15
+        hits = (np.asarray(net.output(x)).argmax(-1)[:, :-1]
+                == x[:, 1:]).sum()
+        assert np.trace(ev.confusion) == hits
 
 
 class TestGuards:
