@@ -300,8 +300,8 @@ class Model(gpt2.Model):
 
     def score(self, tokens: np.ndarray) -> float:
         """`net.score` a sequence at a time (they are of one length, so the
-        mean of their means is the mean): two sequences' float32 labels and
-        logits at once are 2 GB beside the step's reserved memory."""
+        mean of their means is the mean): two sequences' float32 logits at
+        once are 1.07 GB beside the step's reserved memory."""
         return float(np.mean([self.net.score(self.make_batch(tokens[i:i + 1]))
                               for i in range(len(tokens))]))
 

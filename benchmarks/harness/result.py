@@ -55,18 +55,27 @@ def line(run) -> str:
         device["busy_s"] = run.device_trace.busy_s
         device["window_s"] = run.device_trace.window_s
         out["breakdown"] = breakdown(run)
+    # last: every comparison behind `correct`, its numbers beside its limit
+    out["checks"] = {name: {"ok": ok, "compared": detail}
+                     for name, (ok, detail) in run.checks.items()}
     return json.dumps(out)
 
 
 def report(run) -> None:
     """The lines before the last: what a person wants to see."""
-    for name, (ok, detail) in run.checks.items():
-        say(f"check {name}: {'ok' if ok else 'FAILED'} ({detail})")
+    checks = [f"check {name}: {'ok' if ok else 'FAILED'} ({detail})"
+              for name, (ok, detail) in run.checks.items()]
+    for check in checks:
+        say(check)
     say(f"window {run.window_s:.3f} s, {run.steps} steps, {run.items} items "
         f"({run.items / run.window_s:.1f} a second over the whole of it); "
         f"set-up {run.setup_s:.2f} s; counters "
         + json.dumps({k: v for k, v in run.counters.items()
                       if not isinstance(v, list)}))
-    if not run.correct:
-        print(f"{run.cell.name}: the run is NOT correct", file=sys.stderr,
-              flush=True)
+    # the same comparisons as the last lines of standard error: a record
+    # of a run that is not correct keeps the end of each stream
+    for check in checks:
+        print(check, file=sys.stderr)
+    print(f"{run.cell.name}: the run is "
+          f"{'correct' if run.correct else 'NOT correct'}", file=sys.stderr,
+          flush=True)

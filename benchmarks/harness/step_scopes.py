@@ -119,6 +119,27 @@ def _operands(after_opcode: str) -> tuple:
     return ()
 
 
+def _whole_lines(text: str):
+    """The text's lines, an instruction that the text breaks over several
+    joined into one. jax 0.9.0 writes a Pallas kernel's metadata as JSON
+    with a newline after every brace and comma, so its custom call ends on
+    a line of its own that starts with `}}`: a line belongs to the one
+    before it for as long as that one's braces are open. The line that
+    opens a computation ends in its open brace and stands alone."""
+    pending, depth = [], 0
+    for line in text.splitlines():
+        if not pending and _COMPUTATION.match(line):
+            yield line
+            continue
+        pending.append(line)
+        depth += line.count("{") - line.count("}")
+        if depth <= 0:
+            yield " ".join(pending)
+            pending, depth = [], 0
+    if pending:
+        yield " ".join(pending)
+
+
 def parse(text: str):
     """`(instructions, computations)`: `{instruction name: Instruction}` and
     `{computation name: [its Instructions]}` of a compiled module's
@@ -126,7 +147,7 @@ def parse(text: str):
     parameters are), the one outside a fused computation is kept: only such
     instructions run as events of their own."""
     computations, current = {}, None
-    for line in text.splitlines():
+    for line in _whole_lines(text):
         opened = _COMPUTATION.match(line)
         if opened:
             current = computations.setdefault(opened.group(1), [])

@@ -1,7 +1,9 @@
-"""The flash kernels' share of their roofline: the least time the chip
-could take for what the three kernels need in every layer of one step
-(`harness/kernel_costs.py`, from the shapes) over the device time the trace
-shows for them. At these shapes the bound is compute."""
+"""The attention kernels' share of their roofline: the least time the chip
+could take for what the kernels need in every layer of one step
+(`harness/kernel_costs.py`, from the shapes: nine products in three kernels
+for the stock flash split, seven in two for the splash kernels with a fused
+backward, by the kernel names the trace holds) over the device time the
+trace shows for them. At these shapes the bound is compute."""
 
 from benchmarks.harness import kernel_costs
 
@@ -14,8 +16,8 @@ def read(run):
     if not seconds:
         return None
     config, mix = run.cell.config, run.cell.traffic
-    cost = kernel_costs.flash_attention_causal(
-        mix["batch"], config["n_head"], mix["seq_len"],
-        config["n_embd"] // config["n_head"])
+    cost = kernel_costs.attention_causal(
+        trace.first.ops.names, mix["batch"], config["n_head"],
+        mix["seq_len"], config["n_embd"] // config["n_head"])
     least, _bound = kernel_costs.min_seconds(cost, run.peaks)
     return 100.0 * config["n_layer"] * least * len(trace.first.steps) / seconds

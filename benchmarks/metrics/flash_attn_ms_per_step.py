@@ -1,6 +1,7 @@
-"""Device milliseconds per step in the three kernels of
-`jax.experimental.pallas.ops.tpu.flash_attention` (forward, dK/dV, dQ), on
-the `XLA Ops` line of the first device."""
+"""Device milliseconds per step in the attention kernels, on the `XLA Ops`
+line of the first device: the stock flash kernels (forward, dK/dV, dQ) or
+the splash kernels (forward, and one fused backward), whichever the trace
+holds (`harness/kernel_costs.py:FLASH_ATTENTION_OPS`)."""
 
 from benchmarks.harness import kernel_costs
 

@@ -1,7 +1,7 @@
-"""How many flash-attention kernel calls the compiled train step holds (an
-exact count from its HLO: custom calls to `tpu_custom_call` under the
-kernels' names). A metric and not a correctness condition: where the gate
-sits is the program's choice."""
+"""How many attention kernel calls the compiled train step holds (an exact
+count from its HLO: custom calls to `tpu_custom_call` under the kernels'
+names, the stock flash kernels' or the splash kernels'). A metric and not a
+correctness condition: where the gate sits is the program's choice."""
 
 import re
 
@@ -11,8 +11,10 @@ CALL = re.compile(r"^\s*(?:ROOT )?%?(\S+) = .*custom_call_target=\"tpu_custom_ca
                   re.M)
 
 
-def read(run):
-    if run.step_text is None:
-        return None
-    return sum(1 for name in CALL.findall(run.step_text)
+def count(step_text: str) -> int:
+    return sum(1 for name in CALL.findall(step_text)
                if kernel_costs.FLASH_ATTENTION_OPS.search(name))
+
+
+def read(run):
+    return None if run.step_text is None else count(run.step_text)

@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
 """Run cells the way the driver's check does and report the spread: for each
 cell, `--sets` sets of `--runs` runs, every run a new process with another
-`--seed`, all in one call so that they share the machine and the compile
-cache. This process never touches JAX, so each run gets the chip.
+`--seed` and every set with the same seeds as the first, all in one call so
+that they share the machine and the compile cache. This process never
+touches JAX, so each run gets the chip.
 
     chiprun -- python3 benchmarks/tools/measure.py --cells a,b --sets 2 --runs 6
 
@@ -83,7 +84,9 @@ def one_run(cell: str, seed: int, seconds: float, trace: int, log,
 def spread(values: list) -> float:
     if len(values) < 2:
         return float("nan")
-    q = statistics.quantiles(values, n=4, method="inclusive")
+    # the contract's quartiles (the default, exclusive method); numpy's
+    # and `method="inclusive"` lie closer together
+    q = statistics.quantiles(values, n=4)
     return (q[2] - q[0]) / statistics.median(values)
 
 
@@ -113,14 +116,14 @@ def main(argv=None) -> int:
             sets = []
             for _ in range(args.sets):
                 runs = []
-                for _ in range(args.runs):
-                    seed += 1
-                    values = one_run(cell, seed, seconds, 0, log, extra)
+                for i in range(1, args.runs + 1):
+                    values = one_run(cell, seed + i, seconds, 0, log, extra)
                     if values is None:
                         failures += 1
                     else:
                         runs.append(values)
                 sets.append(runs)
+            seed += args.runs
             for metric in (sets[0][0] if sets and sets[0] else {}):
                 widest = 0.0
                 for i, runs in enumerate(sets):

@@ -88,9 +88,9 @@ def main(argv=None) -> int:
     data = NamedSharding(mesh, P("data"))
     tokens = jax.ShapeDtypeStruct((batch, mix["seq_len"]), jnp.int32,
                                   sharding=data)
-    labels = jax.ShapeDtypeStruct(
-        (batch, mix["seq_len"], config["vocab_size"]), jnp.float32,
-        sharding=data)
+    # class ids, as `lm_labels` makes them: the path the cells run
+    labels = jax.ShapeDtypeStruct((batch, mix["seq_len"]), jnp.int32,
+                                  sharding=data)
     scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=repl)
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=repl)
 

@@ -38,6 +38,9 @@ def test_required_flops_per_token(name, seq_len, multiplied, flops):
     assert got == by_hand == flops
 
 
+PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+
+
 def test_flash_attention_cost_by_hand():
     # B=4, H=12, T=2048, dh=64: one full product is 2*T*T*dh*B*H =
     # 25,769,803,776 operations, the causal half 12,884,901,888, and the
@@ -46,12 +49,104 @@ def test_flash_attention_cost_by_hand():
     assert cost["flops"] == 9 * 12_884_901_888
     # q, k, v, o, dO, dq, dk, dv are 4*12*2048*64 bf16 = 12,582,912 bytes
     assert cost["bytes"] == 17 * 12_582_912
-    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-    seconds, bound = kernel_costs.min_seconds(cost, peaks)
+    seconds, bound = kernel_costs.min_seconds(cost, PEAKS)
     assert bound == "compute"
     assert seconds == pytest.approx(5.8865e-4, rel=1e-4)
     assert kernel_costs.min_seconds({"flops": 1.0, "bytes": 819e9},
-                                    peaks) == (1.0, "memory")
+                                    PEAKS) == (1.0, "memory")
+
+
+@pytest.mark.parametrize("shape, product, tensor, row, least_s", [
+    # B=4, H=12, T=2048, dh=64 (gpt2s-resident-t2048): the causal half of
+    # 2*T*T*dh*B*H; q is 4*12*2048*64 bf16; a float32 per query row is
+    # 4*12*2048*4 bytes; 7 * 12,884,901,888 / 197e12
+    ((4, 12, 2048, 64), 12_884_901_888, 12_582_912, 393_216, 4.5784e-4),
+    # B=8, H=12, T=1024, dh=64 (gpt2s-resident-t1024): half the products
+    # for the same tokens, the same bytes
+    ((8, 12, 1024, 64), 6_442_450_944, 12_582_912, 393_216, 2.2892e-4),
+])
+def test_fused_backward_attention_cost_by_hand(shape, product, tensor, row,
+                                               least_s):
+    # forward QK^T, PV; backward QK^T again, dP, dV, dK, dQ: 7 products in
+    # 2 kernels. Forward reads q, k, v and writes o and the log-sum-exp
+    # row; backward reads q, k, v, dO and two rows, writes dQ, dK, dV.
+    cost = kernel_costs.fused_backward_attention_causal(*shape)
+    assert cost["flops"] == 7 * product
+    assert cost["bytes"] == (4 + 7) * tensor + (1 + 2) * row
+    seconds, bound = kernel_costs.min_seconds(cost, PEAKS)
+    assert bound == "compute"
+    assert seconds == pytest.approx(least_s, rel=1e-4)
+    # the stock split of the same shape: two products and a kernel more
+    stock = kernel_costs.flash_attention_causal(*shape)
+    assert stock["flops"] == 9 * product
+    assert stock["bytes"] == 17 * tensor
+    # twelve layers of it a step, against the issue's 5.49 / 2.75 ms
+    assert 12 * seconds == pytest.approx(
+        {2048: 5.494e-3, 1024: 2.747e-3}[shape[2]], rel=1e-3)
+
+
+#: names as a trace's `XLA Ops` line and a compiled step hold them, by the
+#: generation of kernels: what the recorded fixtures hold (my chip run,
+#: PR 22), what the cells run since PR 26 (my chip run, PR 32), and what
+#: `splash_attention_kernel.get_kernel_name` gives a backward in two
+#: kernels, grouped queries and segment ids
+GENERATIONS = {
+    "stock": (["jvp_jit_flash_attention__.12",
+               "flash_mha_bwd_dkv_block_q_major_512.3",
+               "flash_mha_bwd_dq_block_q_major_512.7"], 9),
+    "splash_fused_backward": (["splash_mha_fwd_residuals.12",
+                               "splash_mha_dkv_no_residuals.3"], 7),
+    "splash_backward_in_two": (["splash_mqa_fwd_segmented_residuals.1",
+                                "splash_mqa_dkv_segmented_no_residuals.2",
+                                "splash_mqa_dq_segmented_no_residuals.3"],
+                               9),
+}
+OTHERS = ["fusion.12", "all-reduce.3", "gmm.4", "tgmm.1", "copy-done.127",
+          "splash_mask_info", "flashy_fusion"]
+
+
+@pytest.mark.parametrize("generation", sorted(GENERATIONS))
+def test_the_pattern_finds_each_generation_of_attention_kernels(generation):
+    names, products = GENERATIONS[generation]
+    assert all(kernel_costs.FLASH_ATTENTION_OPS.search(n) for n in names)
+    assert not any(kernel_costs.FLASH_ATTENTION_OPS.search(n) for n in OTHERS)
+    # the cost follows the kernels found, whatever else the line holds
+    cost = kernel_costs.attention_causal(OTHERS + names, 4, 12, 2048, 64)
+    assert cost["flops"] == products * 12_884_901_888
+    assert kernel_costs.attention_causal(OTHERS, 4, 12, 2048, 64) is None
+
+
+def compiled_step_with(names):
+    """A short compiled step: each name a `tpu_custom_call`, among a fusion,
+    a custom call that is no kernel and the grouped matmul's kernel."""
+    calls = "".join(
+        f'  %{name} = bf16[8,12,1024,64]{{3,2,1,0}} custom-call(%q, %k, %v), '
+        f'custom_call_target="tpu_custom_call", frontend_attributes='
+        f'{{kernel_metadata={{"xprof_metadata":"{{}}"}}}}, metadata='
+        f'{{op_name="jit(train_step)/jvp(A:att)/{name}/pallas_call"}}\n'
+        for name in names)
+    return f"""
+HloModule jit_train_step, is_scheduled=true
+
+ENTRY %main.1 (q: bf16[8,12,1024,64]) -> bf16[8,12,1024,64] {{
+  %q = bf16[8,12,1024,64]{{3,2,1,0}} parameter(0)
+  %fusion.3 = bf16[8,12,1024,64]{{3,2,1,0}} fusion(%q), kind=kLoop, calls=%fused_computation.3, metadata={{op_name="jit(train_step)/jvp(A:att)/splash_mha_fwd_residuals/mul"}}
+  %gmm.4 = bf16[8,64]{{1,0}} custom-call(%q), custom_call_target="tpu_custom_call", metadata={{op_name="jit(train_step)/jvp(M:moe)/experts/jit(gmm)/pallas_call"}}
+  %custom-call.9 = bf16[8,64]{{1,0}} custom-call(%q), custom_call_target="Sharding"
+{calls}  ROOT %out.1 = bf16[8,12,1024,64]{{3,2,1,0}} copy(%q)
+}}
+"""
+
+
+@pytest.mark.parametrize("generation", sorted(GENERATIONS))
+def test_flash_kernels_in_step_counts_each_generation(generation):
+    count = manifest._load_module(
+        manifest.metric_path("flash_kernels_in_step")).count
+    names, _ = GENERATIONS[generation]
+    two_layers = [f"{n.rsplit('.', 1)[0]}.{10 * layer + i}"
+                  for layer in range(2) for i, n in enumerate(names)]
+    assert count(compiled_step_with(two_layers)) == 2 * len(names)
+    assert count(compiled_step_with([])) == 0
 
 
 def test_collectives_are_counted_once_each():

@@ -122,22 +122,26 @@ def test_added_files_and_entries_are_enough(copy):
 
 
 def test_the_stream_mix_becomes_a_cell_by_entries_alone(copy):
-    """`traffic/stream-b4-t2048.json` and its three readers are in the
-    benchmark without a cell (PERF.md says why). The cell they wait for is
-    one entry in `workloads` and one in `per_layer` for each reader."""
+    """`traffic/stream-b4-t2048.json` and its three readers lay in the
+    benchmark without a cell until PR 32. The cell is one entry in
+    `workloads` and one in `per_layer` for each reader, and nothing else:
+    it is rehearsed here as it stands in `BENCHMARK.json`."""
     doc = json.loads((copy / "BENCHMARK.json").read_text())
-    doc["workloads"].append({
-        "name": "gpt2s-stream-t2048", "config": "gpt2-small",
-        "traffic": "stream-b4-t2048", "chips": 1, "why": "a test"})
-    for name, unit, source in (
-            ("batch_build_ms", "ms", "program_span"),
-            ("host_wait_ms_per_step", "ms", "program_span"),
-            ("transfer_gb_per_step", "GB", "program_counter")):
-        doc["per_layer"].append({
-            "name": name, "unit": unit, "better": "lower", "source": source,
-            "layer": "input_pipeline", "moves": "tokens_per_s",
-            "workloads": ["gpt2s-stream-t2048"]})
-    (copy / "BENCHMARK.json").write_text(json.dumps(doc))
+    cell, = [w for w in doc["workloads"] if w["name"] == "gpt2s-stream-t2048"]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "gpt2-small", "stream-b4-t2048", 1)
+    mix = json.loads((copy / "benchmarks" / "traffic"
+                      / "stream-b4-t2048.json").read_text())
+    assert (mix["batches"], mix["batch"], mix["seq_len"]) == ("stream", 4,
+                                                              2048)
+    assert mix["prefetch_depth"] is None    # fit()'s own default
+    readers = {m["name"]: m for m in doc["per_layer"]
+               if m["layer"] == "input_pipeline"}
+    assert sorted(readers) == ["batch_build_ms", "host_wait_ms_per_step",
+                               "transfer_gb_per_step"]
+    for m in readers.values():
+        assert (m["moves"], m["workloads"]) == ("tokens_per_s",
+                                                ["gpt2s-stream-t2048"])
     done = run_in(copy, "--workload", "gpt2s-stream-t2048", "--seed", "3",
                   "--seconds", "1", "--trace", "1", "--rehearse",
                   pythonpath=REPO)
@@ -145,12 +149,15 @@ def test_the_stream_mix_becomes_a_cell_by_entries_alone(copy):
     result = json.loads(done.stdout.strip().splitlines()[-1])
     assert result["correct"] is True and result["failed"] == 0
     metrics = result["metrics"]
-    # 2 * 32 int32 ids and their [2, 32, 512] float32 one-hot, every step
-    assert metrics["transfer_gb_per_step"]["value"] == \
-        (2 * 32 * 4 + 2 * 32 * 512 * 4) / 1e9
+    # 2 * 32 int32 ids and as many int32 class ids, every step
+    assert metrics["transfer_gb_per_step"]["value"] == 2 * (2 * 32 * 4) / 1e9
     assert metrics["batch_build_ms"] == {"value": None, "unit": "ms"}
     assert metrics["host_wait_ms_per_step"] == {"value": None, "unit": "ms"}
     assert "loop_gap_ms_per_step" not in metrics  # the resident cells' own
+    # the kernels' readers list this cell; a CPU's step holds no kernel
+    assert metrics["flash_kernels_in_step"] == {"value": 0, "unit": "count"}
+    assert metrics["flash_attn_ms_per_step"]["value"] is None
+    assert metrics["steps_that_compiled"]["value"] >= 1
 
 
 def test_the_benchmark_alone_refuses_to_run(copy):

@@ -59,19 +59,20 @@ def test_config_keeps_every_published_size_but_the_reduced_ones(cell):
 def test_cell_and_its_metrics_are_entries_of_their_own():
     doc = manifest.load()
     entry = next(w for w in doc["workloads"] if w["name"] == CELL)
-    assert doc["workloads"][-1] == entry and entry["chips"] == 1
-    assert doc["configs"][-1]["name"] == entry["config"] == "lfm2-8b-a1b"
+    assert entry["chips"] == 1 and entry["config"] == "lfm2-8b-a1b"
+    assert "lfm2-8b-a1b" in [c["name"] for c in doc["configs"]]
     mine = [m for m in doc["per_layer"] if m.get("workloads") == [CELL]]
     assert [m["name"] for m in mine] == [
         "expert_ffn_ms_per_step", "expert_dispatch_ms_per_step",
         "expert_matmul_ms_per_step", "expert_matmul_roofline",
         "short_conv_ms_per_step", "gqa_attention_ms_per_step",
         "expert_rows_per_step", "expert_load_max_over_mean"]
-    assert doc["per_layer"][-len(mine):] == mine
     assert all(m["moves"] == "tokens_per_s" for m in mine)
-    # no metric of another cell took this one in
-    assert not [m for m in doc["per_layer"]
-                if CELL in m.get("workloads", ()) and m not in mine]
+    # of the metrics that list their cells, only those whose spans are made
+    # in one place for every model (`nn/engine.py`) took this one in
+    assert [m["name"] for m in doc["per_layer"]
+            if CELL in m.get("workloads", ()) and m not in mine] == [
+        "dispatch_ms_per_step", "steps_that_compiled"]
 
 
 def test_parameters_and_required_flops_by_hand(cell):

@@ -32,9 +32,13 @@ def last_line(done):
 
 def check_result(result, cell, group, chips):
     doc = manifest.load()
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    assert set(result) - {"breakdown"} == {"correct", "attempted", "failed",
+                                           "metrics", "device", "checks"}
     assert result["correct"] is True
+    # last in the line: every comparison, its numbers beside its limit
+    assert list(result)[-1] == "checks" and result["checks"]
+    for check in result["checks"].values():
+        assert check["ok"] is True and check["compared"]
     assert result["failed"] == 0 and result["attempted"] > 0
     assert result["device"] == {"platform": "cpu", "kind": "cpu",
                                 "count": chips, "memory_peak_bytes": None}

@@ -140,6 +140,43 @@ def test_parse_finds_every_instruction_and_what_a_fusion_calls():
     assert instructions["custom-call.7"].opcode == "custom-call"
 
 
+def test_parse_joins_an_instruction_that_the_text_breaks_over_lines():
+    """jax 0.9.0 writes a Pallas kernel's metadata as JSON with a newline
+    after every brace and comma; the custom call then ends on a line that
+    starts with `}}`, which is no end of the computation."""
+    one_line = (
+        '  %splash_mha_fwd_residuals.12 = bf16[8,4]{1,0} custom-call('
+        '%fusion.1), custom_call_target="tpu_custom_call", '
+        'frontend_attributes={kernel_metadata={"xprof_metadata": '
+        '"{\\"block_q\\": 1024, \\"use_fused_bwd_kernel\\": true}"}}, '
+        'metadata={op_name="' + FWD.replace("DenseLayer:fc", "A:att")
+        + '/splash_mha_fwd_residuals/pallas_call"}')
+    broken = one_line.replace('kernel_metadata={', 'kernel_metadata={\n') \
+        .replace('"}}, metadata', '"\n}}, metadata')
+    assert broken.count("\n") == 2 and "\n}}, metadata=" in broken
+    before = "  %custom-call.7 = "
+    at = HLO.index(before)
+    parsed = {}
+    for name, kernel in (("one_line", one_line), ("broken", broken)):
+        text = HLO[:at] + kernel + "\n" + HLO[at:]
+        instructions, computations = step_scopes.parse(text)
+        parsed[name] = instructions
+        kernel = instructions["splash_mha_fwd_residuals.12"]
+        assert (kernel.opcode, kernel.operands) == ("custom-call",
+                                                    ("fusion.1",))
+        assert kernel.op_name.endswith("splash_mha_fwd_residuals/pallas_call")
+        # nothing after the kernel is lost, and nothing is added
+        assert [i.name for i in computations["main.9"]][-2:] == [
+            "divide_subtract_fusion", "out.1"]
+        assert set(instructions) == set(step_scopes.parse(HLO)[0]) \
+            | {"splash_mha_fwd_residuals.12"}
+        assert step_scopes.Labels(text).of_event(
+            "splash_mha_fwd_residuals.12") == step_scopes.Label(
+                "forward", "A", "A:att")
+    assert {k: vars(v) for k, v in parsed["broken"].items()} \
+        == {k: vars(v) for k, v in parsed["one_line"].items()}
+
+
 def test_label_of_each_event():
     labels = step_scopes.Labels(HLO)
     for event, (_, phase, cls, name, mixed) in EVENTS.items():
@@ -200,9 +237,9 @@ def test_traced_rehearsal_prints_the_new_metrics_and_counts_compiling_steps():
 def test_manifest_is_sound_with_the_eight_entries():
     doc = manifest.load()
     assert manifest.problems(doc) == []
-    names = [m["name"] for m in doc["per_layer"]]
-    assert names[-8:] == [
-        "forward_ms_per_step", "backward_ms_per_step",
-        "optimizer_ms_per_step", "attention_ms_per_step",
-        "vocab_path_ms_per_step", "step_scope_coverage",
-        "dispatch_ms_per_step", "steps_that_compiled"]
+    eight = ["forward_ms_per_step", "backward_ms_per_step",
+             "optimizer_ms_per_step", "attention_ms_per_step",
+             "vocab_path_ms_per_step", "step_scope_coverage",
+             "dispatch_ms_per_step", "steps_that_compiled"]
+    # all there, in this order among themselves; later PRs append their own
+    assert [m["name"] for m in doc["per_layer"] if m["name"] in eight] == eight

@@ -3,7 +3,9 @@ first on lines made by hand, where every answer can be worked out, then on
 a trace recorded on the chip."""
 
 import glob
+import json
 import os
+import types
 
 import pytest
 
@@ -161,3 +163,64 @@ def test_recorded_four_chip_trace_finds_the_collectives_by_name():
                                                                 rel=1e-3)
     # under GSPMD the flash gate stands aside: no kernel in this step
     assert plane.op_seconds(kernel_costs.FLASH_ATTENTION_OPS) == 0
+
+
+# benchmarks/fixtures/gpt2s-resident-t1024.3steps.xplane.pb.gz: the first
+# three steps of the traced slice of gpt2s-resident-t1024 on a TPU v5e (my
+# chip run, PR 32, seed 2147483621), cut by benchmarks/tools/cut_xplane.py
+# and gzipped (179,267 bytes). The kernels are the splash kernels, forward
+# and one fused backward.
+def test_recorded_splash_trace_holds_two_kernels_a_layer_and_step():
+    trace = xplane.load(os.path.join(
+        FIXTURES, "gpt2s-resident-t1024.3steps.xplane.pb.gz"))
+    plane = trace.first
+    assert plane.step_name.startswith("jit_train_step")
+    assert len(plane.steps) == 3
+    assert plane.step_seconds() == pytest.approx([0.081244] * 3, rel=1e-3)
+    assert plane.busy_s == pytest.approx(plane.window_s, rel=1e-3)
+    found = [n for n in plane.ops.names
+             if kernel_costs.FLASH_ATTENTION_OPS.search(n)]
+    assert len(found) == 2 * 12 * 3
+    assert {xplane.kind_of(n) for n in found} == {
+        "splash_mha_fwd_residuals", "splash_mha_dkv_no_residuals"}
+    by_kind = plane.ops.seconds_by_name(xplane.kind_of)
+    assert by_kind["splash_mha_fwd_residuals"] / 3 == pytest.approx(
+        0.004983, rel=1e-3)
+    assert by_kind["splash_mha_dkv_no_residuals"] / 3 == pytest.approx(
+        0.008982, rel=1e-3)
+    # the names decide the count: seven products in two kernels
+    cost = kernel_costs.attention_causal(plane.ops.names, 8, 12, 1024, 64)
+    assert cost["flops"] == 7 * 6_442_450_944
+    assert plane.op_seconds(xplane.COLLECTIVE) == 0
+
+
+@pytest.mark.parametrize("fixture, cell, ms_per_step, share", [
+    # stock flash kernels (PR 22): 12 x 9 x 12.885e9 / 197e12 = 7.064 ms of
+    # the 35.29 the three kernels took
+    ("gpt2s-resident-t2048.3steps", "gpt2s-resident-t2048", 35.292, 20.015),
+    # splash, fused backward (PR 32): 12 x 7 x 6.442e9 / 197e12 = 2.747 ms
+    # of 13.97
+    ("gpt2s-resident-t1024.3steps", "gpt2s-resident-t1024", 13.965, 19.670),
+])
+def test_the_attention_readers_on_a_trace_of_each_generation(
+        fixture, cell, ms_per_step, share):
+    with open(os.path.join(manifest.BENCH_DIR, "harness", "peaks.json"),
+              encoding="utf-8") as fh:
+        peaks = json.load(fh)["TPU v5 lite"]
+    run = types.SimpleNamespace(
+        device_trace=xplane.load(os.path.join(FIXTURES,
+                                              fixture + ".xplane.pb.gz")),
+        peaks=peaks, cell=manifest.Cell(manifest.load(), cell))
+
+    def read(metric):
+        return manifest._load_module(manifest.metric_path(metric)).read(run)
+
+    assert read("flash_attn_ms_per_step") == pytest.approx(ms_per_step,
+                                                           rel=1e-4)
+    roofline = read("flash_attention_roofline")
+    assert roofline == pytest.approx(share, rel=1e-4) and roofline < 100
+    # a trace that holds no attention kernel leaves both out of the line
+    run.device_trace = xplane.load(os.path.join(
+        FIXTURES, "gpt2l-2x2-resident-t1024.1step.xplane.pb.gz"))
+    assert read("flash_attn_ms_per_step") is None
+    assert read("flash_attention_roofline") is None
