@@ -7,9 +7,14 @@ The router scores every expert for every token in float32; only the
 (token, expert) pairs it chose are computed: the pairs are sorted by expert,
 each expert multiplies its own run of rows (grouped matrix products), and
 the results go back to their tokens weighted by the router. Shapes are
-static under ``jit`` (the sorted buffer always holds tokens x ``top_k``
-rows) and no pair is ever dropped, however uneven the routing: there is no
-capacity factor.
+static under ``jit``: the sorted buffer has room for tokens x ``top_k``
+rows. What runs over it follows the pairs *held here*, which sort first:
+the gathers, the gated product and the weighted sum are loops over row
+blocks whose trip count is a value of the program (``_for_held_blocks``),
+as the grouped kernels' tiles are, so a share that holds a quarter of the
+experts moves about a quarter of the rows. No pair is ever dropped, however
+uneven the routing: there is no capacity factor, and a batch that lands
+wholly on held experts runs every block.
 
 A layer may hold a *share* of the experts (``experts_held=(first, count)``):
 it routes over all ``n_experts``, holds the weights of its own ``count``, and
@@ -22,6 +27,7 @@ mesh axis and sums them with one ``psum``, numerically the uncut layer.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -30,6 +36,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
+from deeplearning4j_tpu.observe import trace as _trace
 
 EXPERT_AXIS = "expert"
 GATES = ("softmax_topk", "sigmoid")
@@ -76,46 +83,234 @@ def _dense_gates(experts, weights, n_experts: int):
                    * weights[..., None], axis=-2)
 
 
-@jax.custom_vjp
-def _take_rows(rows, index, inverse):
-    """``rows[index]`` for a permutation ``index`` whose inverse is
-    ``inverse``: the backward pass is a gather too, not a scatter."""
-    return rows[index]
+#: sorted rows a step of the block loops moves (PERF.md §6, PR 33, has the
+#: sweep on a v5e at 32,768 rows of 2048): the loops run over the blocks that
+#: hold a held pair, so a layer wastes half a block on average
+_ROW_BLOCK = 1024
 
 
-def _take_rows_fwd(rows, index, inverse):
-    return rows[index], inverse
+def _for_held_blocks(n_held, block: int, body, init):
+    """``body(start, valid, carry)`` for every block of ``block`` sorted rows
+    that holds a held pair (the first ``n_held`` rows are those pairs);
+    ``valid`` ``[block, 1]`` says which of the block's rows are. The trip
+    count is a value of the program, not of its shapes: the work follows
+    ``n_held``, and a batch that lands wholly on held experts runs every
+    block."""
+    def step(i, carry):
+        start = i * block
+        valid = (start + jnp.arange(block)) < n_held
+        return body(start, valid[:, None], carry)
+
+    return jax.lax.fori_loop(0, (n_held + block - 1) // block, step, init)
 
 
-def _take_rows_bwd(inverse, g):
-    return g[inverse], None, None
+def _block_of(rows, start, block: int):
+    return jax.lax.dynamic_slice_in_dim(rows, start, block, axis=0)
 
 
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+def _put_block(rows, block_rows, start):
+    return jax.lax.dynamic_update_slice_in_dim(
+        rows, block_rows.astype(rows.dtype), start, axis=0)
 
 
-@jax.custom_vjp
-def _rows_of_pairs(tokens, order, inverse):
-    """The token of each (token, expert) pair, pairs in the order ``order``.
-    Pair ``j * m + t`` is token ``t``'s ``j``-th expert (``m`` tokens), so
-    that ``[k * m, d]`` splits into ``[k, m, d]`` without moving anything.
-    Backward, each token sums the rows of its ``k`` pairs, found through
-    ``inverse``: gathers both ways."""
-    return tokens[order % tokens.shape[0]]
+def _take(rows, index):
+    return rows.at[index].get(mode="promise_in_bounds")
 
 
-def _rows_of_pairs_fwd(tokens, order, inverse):
-    return tokens[order % tokens.shape[0]], (inverse, tokens.shape[0])
+def _unwritten(shape, dtype):
+    """A buffer that nothing has written: a kernel with one output and no
+    body. For rows that the loops fill block by block and the grouped
+    kernels read group by group, a memset of the whole buffer is the only
+    pass that would still run over tokens x ``top_k`` rows."""
+    from jax.experimental import pallas as pl
+
+    return pl.pallas_call(
+        lambda out: None, out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY), name="unwritten")()
 
 
-def _rows_of_pairs_bwd(res, g):
-    inverse, m = res
-    by_pair = g[inverse].reshape(-1, m, g.shape[-1])
-    return (jnp.sum(by_pair.astype(jnp.float32), 0).astype(g.dtype),
-            None, None)
+def _row_major(x):
+    """``x`` held to the layout it would have anyway, rows major. From a
+    scatter that makes a layer's output the TPU compiler lays the whole
+    residual stream out tokens-minor, and every layer that reads the
+    stream pays: 1.9% of the LFM2 cell's tokens a second (PERF.md §6,
+    PR 33)."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    return with_layout_constraint(
+        x, Layout(major_to_minor=tuple(range(x.ndim))))
 
 
-_rows_of_pairs.defvjp(_rows_of_pairs_fwd, _rows_of_pairs_bwd)
+def _room(shape, dtype, loose: bool):
+    """Where a loop over the held blocks writes its rows. The rows of the
+    blocks it does not reach are zero, or with ``loose`` undefined."""
+    return _unwritten(shape, dtype) if loose else jnp.zeros(shape, dtype)
+
+
+def _sum_held(block_at, index, n_held, block: int, shape):
+    """Float32 ``shape``: row ``index[i]`` is the sum of ``block_at(start)[i
+    - start]`` over the held rows ``i`` that name it (a token has up to
+    ``top_k`` of them)."""
+    def body(start, valid, out):
+        rows = jnp.where(valid, block_at(start), 0).astype(jnp.float32)
+        return out.at[_block_of(index, start, block)].add(
+            rows, mode="promise_in_bounds")
+
+    return _for_held_blocks(n_held, block, body,
+                            jnp.zeros(shape, jnp.float32))
+
+
+# The passes below see the sorted buffer through `_for_held_blocks` alone.
+# Each takes `how = (block, loose)`. `loose` is for grouped products
+# that neither read nor write a row that is in no group (the kernels): a
+# buffer then starts unwritten, the backward loops write over the buffers
+# they have just read, and what lies past the held blocks is undefined.
+# Without it every such row is zero, which `jax.lax.ragged_dot` and the
+# gather of an expert's bias need.
+
+def _held_rows(tokens, token_of_row, n_held, how):
+    """The token of each held pair, pairs sorted by expert: ``[rows,
+    width]``, the rows past the held blocks as ``_room`` leaves them."""
+    block, loose = how
+
+    def body(start, valid, out):
+        taken = _take(tokens, _block_of(token_of_row, start, block))
+        return _put_block(out, jnp.where(valid, taken, 0), start)
+
+    return _for_held_blocks(
+        n_held, block, body,
+        _room((token_of_row.shape[0], tokens.shape[-1]), tokens.dtype, loose))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _held_products(tokens, weights, token_of_row, n_held, expert_rows, how):
+    """The held pairs' tokens times each of ``weights`` (a tuple of ``[G,
+    width, hidden]``), group by group: a tuple of ``[rows, hidden]``. The
+    gathered rows are not kept for the backward pass, which gathers them
+    again (a quarter of a millisecond a layer for a buffer of tokens x
+    ``top_k`` rows; PERF.md §6, PR 33). Backward, each product's gradient
+    for the rows is added block by block as a token sums the rows of its
+    held pairs: no sum over the whole buffer."""
+    with jax.named_scope("dispatch"):
+        rows = _held_rows(tokens, token_of_row, n_held, how)
+    with jax.named_scope("experts"):
+        return tuple(_grouped_matmul(rows, w, expert_rows) for w in weights)
+
+
+def _held_products_fwd(tokens, weights, token_of_row, n_held, expert_rows,
+                       how):
+    return (_held_products(tokens, weights, token_of_row, n_held,
+                           expert_rows, how),
+            (tokens, weights, token_of_row, n_held, expert_rows))
+
+
+def _held_products_bwd(how, res, gs):
+    tokens, weights, token_of_row, n_held, expert_rows = res
+    block = how[0]
+    with jax.named_scope("dispatch"):
+        rows = _held_rows(tokens, token_of_row, n_held, how)
+    with jax.named_scope("experts"):
+        pulled = [_grouped_matmul_vjp(rows, w, expert_rows, g)
+                  for w, g in zip(weights, gs)]
+    with jax.named_scope("dispatch"):
+        d_tokens = _sum_held(
+            lambda start: sum(_block_of(d_rows, start, block)
+                              .astype(jnp.float32) for d_rows, _ in pulled),
+            token_of_row, n_held, block, tokens.shape).astype(tokens.dtype)
+    return (d_tokens, tuple(d_w for _, d_w in pulled), None, None, None)
+
+
+_held_products.defvjp(_held_products_fwd, _held_products_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 3))
+def _on_held_rows(fn, arrays, n_held, how):
+    """``fn(*arrays)``, a function of each row alone, on the held blocks of
+    ``arrays`` (each ``[rows, width]``): ``[rows, width]``. What the arrays
+    hold past ``n_held`` is never kept, NaN included, forward or backward."""
+    block, loose = how
+    made = jax.eval_shape(fn, *(
+        jax.ShapeDtypeStruct((block,) + a.shape[1:], a.dtype)
+        for a in arrays))
+
+    def body(start, valid, out):
+        kept = fn(*(_block_of(a, start, block) for a in arrays))
+        return _put_block(out, jnp.where(valid, kept, 0), start)
+
+    return _for_held_blocks(
+        n_held, block, body,
+        _room((arrays[0].shape[0],) + made.shape[1:], made.dtype, loose))
+
+
+def _on_held_rows_fwd(fn, arrays, n_held, how):
+    return _on_held_rows(fn, arrays, n_held, how), (arrays, n_held)
+
+
+def _on_held_rows_bwd(fn, how, res, g):
+    arrays, n_held = res
+    block, loose = how
+
+    def body(start, valid, outs):
+        _, pull = jax.vjp(fn, *(_block_of(a, start, block)
+                                for a in (outs if loose else arrays)))
+        grads = pull(jnp.where(valid, _block_of(g, start, block), 0))
+        return tuple(_put_block(out, jnp.where(valid, grad, 0), start)
+                     for out, grad in zip(outs, grads))
+
+    init = arrays if loose else tuple(jnp.zeros_like(a) for a in arrays)
+    return _for_held_blocks(n_held, block, body, init), None
+
+
+_on_held_rows.defvjp(_on_held_rows_fwd, _on_held_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _sum_to_tokens(out_rows, row_weights, token_of_row, n_held, how, m: int,
+                   dtype):
+    """``[m, width]`` in ``dtype``: each token's held rows, weighted by the
+    router and summed in float32. Only held rows are read, forward and
+    backward, and no ``[top_k, m, width]`` is ever made."""
+    block, loose = how
+
+    def block_at(start):
+        return _block_of(out_rows, start, block).astype(jnp.float32) \
+            * _block_of(row_weights, start, block)[:, None]
+
+    out = _sum_held(block_at, token_of_row, n_held, block,
+                    (m, out_rows.shape[-1])).astype(dtype)
+    return _row_major(out) if loose else out
+
+
+def _sum_to_tokens_fwd(out_rows, row_weights, token_of_row, n_held, how, m,
+                       dtype):
+    return (_sum_to_tokens(out_rows, row_weights, token_of_row, n_held, how,
+                           m, dtype),
+            (out_rows, row_weights, token_of_row, n_held))
+
+
+def _sum_to_tokens_bwd(how, m, dtype, res, g):
+    out_rows, row_weights, token_of_row, n_held = res
+    block, loose = how
+
+    def body(start, valid, outs):
+        d_rows, d_weights = outs
+        taken = _take(g, _block_of(token_of_row, start, block)) \
+            .astype(jnp.float32)
+        computed = _block_of(d_rows if loose else out_rows, start, block)
+        d_row = taken * _block_of(row_weights, start, block)[:, None]
+        d_weight = jnp.sum(taken * computed.astype(jnp.float32), -1)
+        return (_put_block(d_rows, jnp.where(valid, d_row, 0), start),
+                _put_block(d_weights, jnp.where(valid[:, 0], d_weight, 0),
+                           start))
+
+    d_rows, d_weights = _for_held_blocks(
+        n_held, block, body,
+        (out_rows if loose else jnp.zeros_like(out_rows),
+         jnp.zeros_like(row_weights)))
+    return d_rows, d_weights, None, None
+
+
+_sum_to_tokens.defvjp(_sum_to_tokens_fwd, _sum_to_tokens_bwd)
 
 
 #: megablox tile sizes (rows, contraction, columns) from a sweep on a v5e at
@@ -124,18 +319,20 @@ _rows_of_pairs.defvjp(_rows_of_pairs_fwd, _rows_of_pairs_bwd)
 _GMM_TILING = (256, 1024, 1024)
 
 
-def _megablox_tiling(rows, weights):
+def _megablox_tiling(rows, weights, n_rows=None):
     """Tile sizes for the grouped-matmul kernel bundled with jax
     (``jax.experimental.pallas.ops.tpu.megablox``), or None where it cannot
     serve: a backend that is no TPU, a program the compiler partitions
     (Mosaic kernels cannot be), a dtype other than bfloat16 (float32 tiles
-    of this size do not fit VMEM), or a row count its row tile does not
+    of this size do not fit VMEM), or a row count (``n_rows`` where
+    ``rows`` only stands for the buffer to come) its row tile does not
     divide."""
     from deeplearning4j_tpu.nn import helpers as _helpers
 
     tile_rows, tile_in, tile_out = _GMM_TILING
     if (jax.default_backend() != "tpu" or rows.dtype != jnp.bfloat16
-            or weights.dtype != jnp.bfloat16 or rows.shape[0] % tile_rows
+            or weights.dtype != jnp.bfloat16
+            or (rows.shape[0] if n_rows is None else n_rows) % tile_rows
             or _helpers.partitioned_by_compiler(rows)):
         return None
     return (tile_rows, min(tile_in, weights.shape[1]),
@@ -154,6 +351,24 @@ def _grouped_matmul(rows, weights, group_sizes):
     from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
     return megablox.gmm(rows, weights, group_sizes, rows.dtype, tiling)
+
+
+def _grouped_matmul_vjp(rows, weights, group_sizes, g):
+    """``(d_rows, d_weights)`` of :func:`_grouped_matmul` for the cotangent
+    ``g``, without the product itself: what its own backward pass runs (for
+    the kernels ``gmm`` with the weights transposed and ``tgmm``, under
+    those names), for a backward pass that makes ``rows`` again."""
+    tiling = _megablox_tiling(rows, weights)
+    if tiling is None:
+        return jax.vjp(lambda r, w: jax.lax.ragged_dot(r, w, group_sizes),
+                       rows, weights)[1](g)
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    return (megablox.backend.gmm(g, weights, group_sizes, rows.dtype, tiling,
+                                 transpose_rhs=True),
+            megablox.backend.tgmm(rows.swapaxes(0, 1), g, group_sizes,
+                                  weights.dtype, tiling,
+                                  num_actual_groups=weights.shape[0]))
 
 
 def _moe_share(params, x, *, top_k: int, act, gate: str = "softmax_topk",
@@ -175,33 +390,48 @@ def _moe_share(params, x, *, top_k: int, act, gate: str = "softmax_topk",
                                   params.get("expert_bias"), norm_topk,
                                   scaling)
     m, k = experts.shape
+    block = min(_ROW_BLOCK, m * k)
+    n_rows = -(-m * k // block) * block         # whole blocks
+    # the kernels leave alone every row that is in no group, so a buffer
+    # may be undefined there; an expert's bias is gathered for every row
+    loose = gated and _megablox_tiling(tokens, params["W1"],
+                                       n_rows) is not None
+    how = (block, loose)
+    tracer = _trace.get_active_tracer()
+    if tracer is not None:
+        # which form this layer took, counted while its step is traced
+        tracer.count("moe.held_rows_kernel_calls" if loose
+                     else "moe.held_rows_plain_calls")
     with jax.named_scope("dispatch"):
-        # a pair's group: its expert's place among those held, or `count`;
-        # pairs run choice by choice (see `_rows_of_pairs`)
+        # a pair's group: its expert's place among those held, or `count`.
+        # Pair `j * m + t` is token t's j-th expert; sorted by group, the
+        # held pairs come first, and only they are moved from here on
         local = experts.T.reshape(-1) - first
         group = jnp.where((local >= 0) & (local < count), local, count)
-        order = jnp.argsort(group, stable=True)     # pairs sorted by group
-        inverse = jnp.argsort(order)
+        order = jnp.argsort(group, stable=True)
         sizes = jnp.bincount(group, length=count + 1).astype(jnp.int32)
         expert_rows, rows_elsewhere = sizes[:count], sizes[count]
-        computed = (jnp.arange(m * k) < jnp.sum(expert_rows))[:, None]
-        rows = jnp.where(computed,
-                         _rows_of_pairs(tokens, order, inverse), 0)
+        n_held = jnp.sum(expert_rows)
+        pad = lambda a: jnp.pad(a, (0, n_rows - m * k))
+        token_of_row = pad(order % m)
+    products = _held_products(
+        tokens, (params["W1"], params["W3"]) if gated else (params["W"],),
+        token_of_row, n_held, expert_rows, how)
     with jax.named_scope("experts"):
         if gated:
-            hidden = act(_grouped_matmul(rows, params["W1"], expert_rows)) \
-                * _grouped_matmul(rows, params["W3"], expert_rows)
+            hidden = _on_held_rows(lambda h1, h3: act(h1) * h3, products,
+                                   n_held, how)
             out_rows = _grouped_matmul(hidden, params["W2"], expert_rows)
         else:
-            expert_of_row = jnp.minimum(group[order], count - 1)
-            out_rows = act(_grouped_matmul(rows, params["W"], expert_rows)
-                           + params["b"][expert_of_row])
-        out_rows = jnp.where(computed, out_rows, 0)
+            expert_of_row = pad(jnp.minimum(group[order], count - 1))
+            out_rows = _on_held_rows(
+                lambda h, b: act(h + b),
+                products + (params["b"][expert_of_row],), n_held, how)
     with jax.named_scope("combine"):
-        by_pair = _take_rows(out_rows, inverse, order).reshape(k, m, -1)
-        # choice by choice, so that the float32 product is never stored
-        out = sum(by_pair[j].astype(jnp.float32) * weights[:, j, None]
-                  for j in range(k)).astype(x.dtype)
+        row_weights = pad(weights.T.reshape(-1).at[order].get(
+            unique_indices=True, mode="promise_in_bounds"))
+        out = _sum_to_tokens(out_rows, row_weights, token_of_row, n_held,
+                             how, m, x.dtype)
     return (out.reshape(lead + out.shape[-1:]),
             (experts.reshape(lead + (k,)), weights.reshape(lead + (k,)),
              expert_rows, rows_elsewhere))

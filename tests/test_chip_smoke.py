@@ -264,7 +264,10 @@ def test_expert_grouped_products_lower_for_tpu(monkeypatch):
     2048 x 1792, 8,192 tokens x 4): on a TPU its nine grouped products are
     megablox kernels, three forward (`gmm`) and, backward, three for the
     rows (`gmm`) and three for the weights (`tgmm`); float32 and the CPU
-    take `ragged_dot`."""
+    take `ragged_dot`. With the kernels the buffers the loops fill (the
+    gathered rows, forward and again backward, and the gated product) start
+    unwritten: a kernel with no body and no operand each, in place of a
+    memset."""
     from deeplearning4j_tpu.nn.layers import MixtureOfExpertsLayer
     from deeplearning4j_tpu.nn.layers import moe
 
@@ -290,6 +293,9 @@ def test_expert_grouped_products_lower_for_tpu(monkeypatch):
                            params, x)
     # a jitted kernel is one function of the lowered module however often it
     # is called: W1 and W3 share theirs, forward and backward apart
-    assert len(_kernel_calls(text)) == 6
+    calls = _kernel_calls(text)
+    assert [operands for name, operands in calls if name == "unwritten"] \
+        == [[], [], []]
+    assert len(calls) == 6 + 3
     assert len(re.findall(r"call @gmm", text)) == 6
     assert len(re.findall(r"call @tgmm", text)) == 3

@@ -21,6 +21,7 @@ from deeplearning4j_tpu.nn.layers import (
     MixtureOfExpertsLayer,
     RMSNormLayer,
 )
+from deeplearning4j_tpu.nn.layers import moe
 from deeplearning4j_tpu.nn.layers.attention import rotary_embedding
 from deeplearning4j_tpu.nn.layers.moe import (
     EXPERT_AXIS,
@@ -259,10 +260,62 @@ def test_sparse_dispatch_matches_the_all_expert_einsum(rng, top_k, shape):
     assert int(state["rows_elsewhere"]) == 0
 
 
-def test_sparse_dispatch_gradients_match_the_dense_formulation(rng):
-    layer = gated_layer()
+def routed_to(experts, only=None):
+    """A router that sends every token to `experts` (three of the eight), and
+    token `only[0]` to expert `only[1]` in place of the last of them:
+    `(Wg, expert_bias, x[:, 0])`. The bias alone selects, so the weights
+    stay the sigmoid scores."""
+    bias = np.zeros(8, np.float32)
+    bias[list(experts)] = 0.1 * (1 + np.arange(len(experts)))
+    wg = np.zeros((12, 8), np.float32)
+    column = np.zeros(64, np.float32)
+    if only is not None:
+        wg[0, only[1]] = 10.0       # sigmoid(10) beats 0.5 + 0.1
+        column[:] = -1.0
+        column[only[0]] = 1.0
+    return wg, bias, column
+
+
+# (tokens, block, held, forced routing, pairs held) with `_ROW_BLOCK` = block:
+# the loops over the held rows run 0, 1, 1, 2, 2 and 5 times (the last one
+# row into its block), and once over everything with the shipped block
+HELD_ROWS = {
+    "no pair held": (11, 8, (0, 2), routed_to((5, 6, 7)), 0),
+    "one pair held": (11, 8, (0, 1), routed_to((5, 6, 7), only=(3, 0)), 1),
+    "a block to its last row": (8, 8, (5, 1), routed_to((5, 6, 7)), 8),
+    "two blocks to the last row": (8, 8, (5, 2), routed_to((5, 6, 7)), 16),
+    "the average case": (11, 8, (2, 3), None, None),
+    "every pair held": (11, 8, None, None, 33),
+    "every pair held, one block": (11, None, None, None, 33),
+}
+
+
+@pytest.mark.parametrize("form", ["plain", "kernel twin"])
+@pytest.mark.parametrize("case", list(HELD_ROWS))
+def test_sparse_dispatch_gradients_match_the_dense_formulation(
+        rng, monkeypatch, case, form):
+    """The share a layer holds against every held expert on every token,
+    output and all gradients, at routings that end the block loops
+    (`moe._for_held_blocks`) before the first block, inside one, on a
+    block's last row, and after the last; the counts add up each time.
+    Both forms of the layer: the plain one as the CPU takes it, and the
+    kernels' through their plain twin (`kernel_twin`; the kernels
+    themselves compile only on a TPU)."""
+    m, block, held, forced, n_held = HELD_ROWS[case]
+    if block:
+        monkeypatch.setattr(moe, "_ROW_BLOCK", block)
+    if form == "kernel twin":
+        kernel_twin(monkeypatch)
+    layer = gated_layer(held)
+    first, count = held or (0, 8)
     params = layer.init_params(jax.random.PRNGKey(6))
-    x = jnp.asarray(f32(rng.normal(size=(11, 12))))
+    x = f32(rng.normal(size=(m, 12)))
+    if forced is not None:
+        wg, bias, column = forced
+        params["Wg"] = jnp.asarray(wg)
+        params["expert_bias"] = jnp.asarray(bias)
+        x[:, 0] = column[:m]
+    x = jnp.asarray(x)
 
     def dense(params, x):
         picked, weights = _route(params["Wg"], x, 3, "sigmoid",
@@ -272,7 +325,7 @@ def test_sparse_dispatch_gradients_match_the_dense_formulation(rng):
             * jnp.einsum("md,edh->meh", x, params["W3"])
         return jnp.einsum("meo,me->mo",
                           jnp.einsum("meh,eho->meo", hidden, params["W2"]),
-                          gates)
+                          gates[:, first:first + count])
 
     loss = lambda fn: lambda p, xx: jnp.sum(jnp.sin(fn(p, xx)))
     sparse = lambda p, xx: layer.forward(p, xx)[0]
@@ -284,13 +337,71 @@ def test_sparse_dispatch_gradients_match_the_dense_formulation(rng):
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-5)
     assert float(jnp.abs(got[0]["expert_bias"]).max()) == 0.0
+    state = layer.forward(params, x)[1]
+    rows, elsewhere = int(state["expert_rows"].sum()), \
+        int(state["rows_elsewhere"])
+    assert rows + elsewhere == m * 3
+    if n_held is not None:
+        assert rows == n_held
 
 
+def kernel_twin(monkeypatch):
+    """The layer's form for the grouped kernels (`loose`: buffers start
+    unwritten, the backward loops write over what they have read), run
+    through a plain twin of those kernels that is as hostile as they may
+    be: a row that is in no group is never read, whatever it holds, and
+    comes back NaN, forward and in the gradient for the rows; an unwritten
+    buffer is NaN throughout."""
+    def hostile(rows, sizes):
+        past = (jnp.arange(rows.shape[0]) >= jnp.sum(sizes))[:, None]
+        return (lambda a: jnp.where(past, 0, a),            # never read
+                lambda a: jnp.where(past, jnp.nan, a))      # never written
+
+    def grouped_matmul_vjp(rows, weights, sizes, g):
+        unread, poison = hostile(rows, sizes)
+        d_rows, d_weights = jax.vjp(
+            lambda r, w: jax.lax.ragged_dot(r, w, sizes), unread(rows),
+            weights)[1](unread(g))
+        return poison(d_rows), d_weights
+
+    def grouped_matmul(rows, weights, sizes):
+        unread, poison = hostile(rows, sizes)
+
+        @jax.custom_vjp
+        def product(rows, weights):
+            return poison(jax.lax.ragged_dot(unread(rows), weights, sizes))
+
+        def forward(rows, weights):
+            return product(rows, weights), (rows, weights)
+
+        def backward(res, g):
+            return grouped_matmul_vjp(*res, sizes, g)
+
+        product.defvjp(forward, backward)
+        return product(rows, weights)
+
+    monkeypatch.setattr(moe, "_grouped_matmul_vjp", grouped_matmul_vjp)
+    monkeypatch.setattr(moe, "_grouped_matmul", grouped_matmul)
+    monkeypatch.setattr(moe, "_megablox_tiling", lambda *a: (8, 8, 8))
+    monkeypatch.setattr(
+        moe, "_unwritten", lambda shape, dtype: jnp.full(shape, jnp.nan,
+                                                         dtype))
+
+
+@pytest.mark.parametrize("poison", [False, True])
 @pytest.mark.parametrize("held", [None, (4, 4), (0, 2)])
-def test_no_row_is_lost_when_every_token_goes_to_one_expert(rng, held):
+def test_no_row_is_lost_when_every_token_goes_to_one_expert(
+        rng, monkeypatch, held, poison):
     """Forced imbalance: the router sends all 50 tokens to experts 5, 6, 7.
     A layer that holds them computes 150 rows there; one that does not
-    counts 150 rows elsewhere and returns zero."""
+    counts 150 rows elsewhere and returns zero. With `poison`, the layer
+    takes the kernels' form through `kernel_twin`, NaN in every row past the
+    last group and in every unwritten buffer, in blocks of 64 rows so that
+    a block holds both kinds: output and gradients stay finite and the
+    same."""
+    if poison:
+        monkeypatch.setattr(moe, "_ROW_BLOCK", 64)
+        kernel_twin(monkeypatch)
     layer = gated_layer(held)
     params = layer.init_params(jax.random.PRNGKey(7))
     params["Wg"] = jnp.zeros((12, 8))
@@ -313,12 +424,25 @@ def test_no_row_is_lost_when_every_token_goes_to_one_expert(rng, held):
             want += ((a / (1 + np.exp(-a))) * (f32(x) @ w3)) @ w2 / 3
     np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-5)
     assert np.isfinite(np.asarray(out)).all()
+    grads = jax.grad(lambda p, xx: jnp.sum(jnp.sin(layer.forward(p, xx)[0])),
+                     argnums=(0, 1))(params, x)
+    for grad in jax.tree_util.tree_leaves(grads):
+        assert np.isfinite(np.asarray(grad)).all()
+    if held == (0, 2):          # nothing held: nothing reaches a weight
+        assert all(float(jnp.abs(grads[0][n]).max()) == 0.0
+                   for n in ("W1", "W2", "W3"))
+    else:
+        assert float(jnp.abs(grads[0]["W2"][-1]).max()) > 0.0
 
 
-def test_the_four_shares_add_up_to_the_uncut_layer(rng):
+@pytest.mark.parametrize("block", [None, 16])
+def test_the_four_shares_add_up_to_the_uncut_layer(rng, monkeypatch, block):
     """Experts 0-7, 8-15, 16-23 and 24-31 of one seed, each routing over all
     32 and computing its own part: their sum is the whole layer, and so is
-    the plain reference's."""
+    the plain reference's. With the 160 pairs in one block of rows, and in
+    ten, of which a share fills about three."""
+    if block:
+        monkeypatch.setattr(moe, "_ROW_BLOCK", block)
     kw = dict(n_in=16, n_out=16, n_hidden=12, n_experts=32, top_k=4,
               gated=True, activation="silu", gate="sigmoid",
               expert_bias=True, norm_topk=True)
@@ -353,6 +477,32 @@ def test_the_four_shares_add_up_to_the_uncut_layer(rng):
         want = want + w_e * ((jax.nn.silu(x @ params["W1"][e])
                               * (x @ params["W3"][e])) @ params["W2"][e])
     np.testing.assert_allclose(uncut, want, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("form", ["plain", "kernel twin"])
+def test_a_traced_expert_layer_counts_the_form_it_took(rng, monkeypatch, form):
+    """`moe.held_rows_plain_calls` / `moe.held_rows_kernel_calls` on the
+    tracer, once for each time the layer is traced and never for a run of
+    what was traced; nothing is counted with tracing off."""
+    from deeplearning4j_tpu import observe
+
+    if form == "kernel twin":
+        kernel_twin(monkeypatch)
+    name = "moe.held_rows_kernel_calls" if form == "kernel twin" \
+        else "moe.held_rows_plain_calls"
+    layer = gated_layer((0, 4))
+    params = layer.init_params(jax.random.PRNGKey(10))
+    x = jnp.asarray(f32(rng.normal(size=(9, 12))))
+    step = jax.jit(lambda p, xx: layer.forward(p, xx)[0])
+    tracer = observe.enable_tracing()
+    try:
+        step(params, x)
+        step(params, x)
+        assert tracer.counters == {name: 1}
+    finally:
+        observe.disable_tracing()
+    jax.jit(lambda p, xx: layer.forward(p, xx)[0] * 2)(params, x)
+    assert tracer.counters == {name: 1}
 
 
 def test_expert_parallel_forward_of_gated_sigmoid_experts(rng):
