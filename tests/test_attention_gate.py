@@ -173,6 +173,7 @@ def test_tracer_counts_the_path_of_every_layer(as_on_a_chip, tracer):
     step, args = _twelve_layer_step(1024)
     step.trace(*args)
     assert tracer.counters == {"attention.kernel_calls": 12,
+                               "activation.gelu_erfc_calls": 12,
                                "loss.class_id_calls": 1}
 
 
@@ -180,4 +181,5 @@ def test_tracer_counts_the_einsum_path_below_the_gate(as_on_a_chip, tracer):
     step, args = _twelve_layer_step(512)
     step.trace(*args)
     assert tracer.counters == {"attention.einsum_calls": 12,
+                               "activation.gelu_erfc_calls": 12,
                                "loss.class_id_calls": 1}
