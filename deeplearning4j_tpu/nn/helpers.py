@@ -166,7 +166,11 @@ class AttentionHelper:
 
     ``causal`` describes the REQUESTED semantics: a helper must only accept
     a request whose causality matches what its ``attend`` computes, so
-    registering any helper can never change model outputs."""
+    registering any helper can never change model outputs. So does
+    ``window`` (a causal query sees its last ``window`` keys only): a
+    helper that serves windows takes the keyword in both methods, and one
+    that does not name it is never asked about a windowed request
+    (:func:`accepts_window`)."""
 
     def supports(self, layer, q_shape, mask, dropout_active,
                  causal=False) -> bool:  # pragma: no cover - interface
@@ -174,3 +178,14 @@ class AttentionHelper:
 
     def attend(self, q, k, v):  # pragma: no cover - interface
         raise NotImplementedError
+
+
+def accepts_window(helper, window) -> bool:
+    """Whether ``helper`` may be asked about a request with ``window``:
+    always where there is none, else only where its ``supports`` and its
+    ``attend`` both take the keyword."""
+    import inspect
+
+    return window is None or all(
+        "window" in inspect.signature(method).parameters
+        for method in (helper.supports, helper.attend))

@@ -40,16 +40,26 @@ def _auto_flash_helper():
 
 
 def dot_product_attention(q, k, v, mask=None, dropout_rate=0.0, rng=None,
-                          train=False, causal=False):
+                          train=False, causal=False, window=None):
     """q,k,v: [N, H, T, Dh]; mask: [N, T] (1=valid) or [N, 1, Tq, Tk];
-    ``causal=True`` additionally lower-triangular-masks the scores.
+    ``causal=True`` additionally lower-triangular-masks the scores, and
+    with ``window=W`` a query sees only the last ``W`` keys up to its own:
+    ``0 <= q - k < W``. A window that covers the sequence is plain causal
+    attention, and is treated as such from here on.
 
     Consults the "attention" helper seam first: a registered fused kernel
-    (e.g. PallasFlashAttentionHelper) takes supported shapes — causality is
-    part of the request, so a helper only serves requests whose semantics it
-    reproduces; otherwise the einsum path below runs (and XLA fuses it).
+    (e.g. PallasFlashAttentionHelper) takes supported shapes — causality
+    and the window are part of the request, so a helper only serves
+    requests whose semantics it reproduces; otherwise the einsum path below
+    runs (and XLA fuses it).
     """
     from deeplearning4j_tpu.nn import helpers as _helpers
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(f"window={window!r} needs causal=True and at "
+                             f"least one key")
+        if window >= k.shape[-2]:
+            window = None
     helper = _helpers.get_helper("attention")
     dropout_active = bool(train and dropout_rate > 0 and rng is not None)
     if (helper is None and causal and q.shape[-2] >= _AUTO_FLASH_MIN_T
@@ -60,24 +70,33 @@ def dot_product_attention(q, k, v, mask=None, dropout_rate=0.0, rng=None,
         # not depend on knowing the seam exists; opt out via
         # helpers.set_auto_flash_attention(False)
         helper = _auto_flash_helper()
+    # a helper that knows no window is never given one
+    windowed = {} if window is None else {"window": window}
     kernel = (helper is not None
+              and _helpers.accepts_window(helper, window)
               and helper.supports(None, q.shape, mask, dropout_active,
-                                  causal=causal)
+                                  causal=causal, **windowed)
               and q.shape == k.shape == v.shape)
     tracer = _trace.get_active_tracer()
     if tracer is not None:
         # which path this call took, counted while its step is traced
         tracer.count("attention.kernel_calls" if kernel
                      else "attention.einsum_calls")
+        if window is not None:
+            tracer.count("attention.window_kernel_calls" if kernel
+                         else "attention.window_einsum_calls")
     if kernel:
-        return helper.attend(q, k, v)
+        return helper.attend(q, k, v, **windowed)
     scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(q.dtype)
     scores = jnp.einsum("nhqd,nhkd->nhqk", q, k) * scale
     m = None
     if mask is not None:
         m = (mask[:, None, None, :] if mask.ndim == 2 else mask) > 0
     if causal:
-        tri = jnp.tril(jnp.ones((q.shape[-2], k.shape[-2]), bool))[None, None]
+        tri = jnp.tril(jnp.ones((q.shape[-2], k.shape[-2]), bool))
+        if window is not None:
+            tri = jnp.logical_and(tri, jnp.logical_not(jnp.tril(tri, -window)))
+        tri = tri[None, None]
         m = tri if m is None else jnp.logical_and(m, tri)
     if m is not None:
         scores = jnp.where(m, scores, jnp.finfo(scores.dtype).min)
@@ -205,8 +224,9 @@ class CausalSelfAttentionLayer(SelfAttentionLayer, BaseRecurrentLayer):
         qkv = qkv.transpose(3, 0, 2, 1, 4)
         return qkv[0], qkv[1], qkv[2]
 
-    def _project(self, params, out):
-        """``[N,H,T,Dh]`` attention output to the layer's ``[N,T,n_out]``."""
+    def _project(self, params, out, x):
+        """``[N,H,T,Dh]`` attention output to the layer's ``[N,T,n_out]``;
+        ``x`` is the layer's input (which only a gated subclass reads)."""
         n, h, t, dh = out.shape
         y = out.transpose(0, 2, 1, 3).reshape(n, t, h * dh)
         if self.project_input:
@@ -259,7 +279,7 @@ class CausalSelfAttentionLayer(SelfAttentionLayer, BaseRecurrentLayer):
             keep = jax.random.bernoulli(rng, 1.0 - self.attn_dropout, w.shape)
             w = jnp.where(keep, w / (1.0 - self.attn_dropout), 0.0)
         out = jnp.einsum("ngiqk,ngkd->ngiqd", w, vc.astype(q.dtype))
-        return (self._project(params, out.reshape(n, self.n_heads, t, dh)),
+        return (self._project(params, out.reshape(n, self.n_heads, t, dh), x),
                 (kc, vc, valid, pos + t))
 
 
@@ -497,9 +517,14 @@ class GroupedQueryAttentionLayer(CausalSelfAttentionLayer):
     (``Wq`` and a head-major ``Wkv``, columns ``[kv head, (k, v), Dh]``);
     biases only where ``use_bias``; with ``qk_norm`` an RMSNorm over each
     head of q and of k (one weight of ``Dh`` each), before the rotation;
-    with ``rope_theta`` rotary positions on q and k and no learned ones.
+    with ``rope_theta`` rotary positions on q and k and no learned ones
+    (``None``: the layer sees no positions at all); with ``window`` a query
+    sees its last ``window`` keys only (:func:`dot_product_attention`);
+    with ``output_gate`` the heads' output is multiplied, element by
+    element, by ``sigmoid(x Wgate)`` before ``Wo``.
     The stateful path is :class:`CausalSelfAttentionLayer`'s, its KV cache
-    holding ``n_kv_heads`` heads.
+    holding ``n_kv_heads`` heads; it refuses a window, because a cache that
+    forgets keys as they leave it is not written (ROADMAP R-M5).
 
     The full-sequence path repeats K and V to ``n_heads`` and goes through
     :func:`dot_product_attention`, so it takes the same road to a fused
@@ -511,6 +536,8 @@ class GroupedQueryAttentionLayer(CausalSelfAttentionLayer):
     qk_norm: bool = False
     qk_norm_eps: float = 1e-5
     rope_theta: Optional[float] = None
+    window: Optional[int] = None
+    output_gate: bool = False
 
     def _kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
@@ -518,6 +545,8 @@ class GroupedQueryAttentionLayer(CausalSelfAttentionLayer):
     def param_shapes(self):
         dh, h, hkv = self._dh(), self.n_heads, self._kv_heads()
         shapes = {"Wq": (self.n_in, h * dh), "Wkv": (self.n_in, 2 * hkv * dh)}
+        if self.output_gate:
+            shapes["Wgate"] = (self.n_in, h * dh)
         if self.use_bias:
             shapes.update(bq=(h * dh,), bkv=(2 * hkv * dh,))
         if self.qk_norm:
@@ -538,7 +567,12 @@ class GroupedQueryAttentionLayer(CausalSelfAttentionLayer):
         if not self.project_input and self.n_heads * self._dh() != self.n_out:
             raise ValueError("project_input=False requires "
                              "n_heads*head_size == n_out")
+        if self.output_gate and not self.project_input:
+            raise ValueError("output_gate gates what Wo projects: it needs "
+                             "project_input")
         keys = dict(zip(("Wq", "Wkv", "Wo"), jax.random.split(rng, 3)))
+        # a key of its own, so that the other three draw what they drew
+        keys["Wgate"] = jax.random.fold_in(rng, 3)
         params = {}
         for name, shape in self.param_shapes().items():
             if name.startswith("W"):
@@ -569,9 +603,11 @@ class GroupedQueryAttentionLayer(CausalSelfAttentionLayer):
             k = rotary_embedding(k, positions, self.rope_theta)
         return q, k, v
 
-    def _project(self, params, out):
+    def _project(self, params, out, x):
         n, h, t, dh = out.shape
         y = out.transpose(0, 2, 1, 3).reshape(n, t, h * dh)
+        if self.output_gate:
+            y = y * jax.nn.sigmoid(x @ params["Wgate"]).astype(y.dtype)
         if self.project_input:
             y = y @ params["Wo"]
             if self.use_bias:
@@ -585,6 +621,18 @@ class GroupedQueryAttentionLayer(CausalSelfAttentionLayer):
         if groups > 1:
             k, v = jnp.repeat(k, groups, axis=1), jnp.repeat(v, groups, axis=1)
         out = dot_product_attention(q, k, v, mask=mask, causal=True,
+                                    window=self.window,
                                     dropout_rate=self.attn_dropout,
                                     rng=rng, train=train)
-        return self._project(params, out), state or {}
+        return self._project(params, out, x), state or {}
+
+    def forward_seq(self, params, x, carry=None, mask=None, train=False,
+                    rng=None):
+        if carry is not None and self.window is not None:
+            raise NotImplementedError(
+                f"the stateful path (a KV cache of max_cache={self.max_cache}"
+                f" keys) would attend over every cached key: a cache that "
+                f"forgets keys as they leave window={self.window} is not "
+                f"written; run the whole sequence through forward()")
+        return super().forward_seq(params, x, carry=carry, mask=mask,
+                                   train=train, rng=rng)

@@ -47,7 +47,8 @@ EXPERT_BIAS_SCALE = 0.02
 
 
 def _route(wg, x, top_k: int, gate: str = "softmax_topk", bias=None,
-           norm_topk: bool = False, scaling: float = 1.0):
+           norm_topk: bool = False, scaling: float = 1.0,
+           norm_eps: float = 1e-6):
     """Router, in float32 whatever the stream's dtype: ``(experts, weights)``,
     both ``[..., k]``. Shared by the layer and the expert-parallel worker so
     the two paths can never diverge.
@@ -56,7 +57,7 @@ def _route(wg, x, top_k: int, gate: str = "softmax_topk", bias=None,
     - ``sigmoid``: scores ``s = sigmoid(logits)``; the top k of ``s + bias``
       are selected (``bias`` moves the selection only and takes no
       gradient); the weights are ``s`` on the selected, divided by their
-      sum where ``norm_topk``.
+      sum plus ``norm_eps`` where ``norm_topk``.
     """
     logits = jnp.matmul(x.astype(jnp.float32), wg.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)   # [..., E]
@@ -71,7 +72,8 @@ def _route(wg, x, top_k: int, gate: str = "softmax_topk", bias=None,
         _, experts = jax.lax.top_k(biased, k)
         weights = jnp.take_along_axis(scores, experts, axis=-1)
         if norm_topk:
-            weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-6)
+            weights = weights / (jnp.sum(weights, -1, keepdims=True)
+                                 + norm_eps)
     else:
         raise ValueError(f"gate {gate!r} is none of {GATES}")
     return experts, weights * scaling
@@ -373,7 +375,7 @@ def _grouped_matmul_vjp(rows, weights, group_sizes, g):
 
 def _moe_share(params, x, *, top_k: int, act, gate: str = "softmax_topk",
                norm_topk: bool = False, scaling: float = 1.0,
-               gated: bool = False, first=0):
+               norm_eps: float = 1e-6, gated: bool = False, first=0):
     """What the experts held here add to the layer's result.
 
     x: [..., d_in] → [..., d_out]. ``params`` hold the router for all experts
@@ -388,7 +390,7 @@ def _moe_share(params, x, *, top_k: int, act, gate: str = "softmax_topk",
     with jax.named_scope("route"):
         experts, weights = _route(params["Wg"], tokens, top_k, gate,
                                   params.get("expert_bias"), norm_topk,
-                                  scaling)
+                                  scaling, norm_eps)
     m, k = experts.shape
     block = min(_ROW_BLOCK, m * k)
     n_rows = -(-m * k // block) * block         # whole blocks
@@ -455,7 +457,7 @@ class MixtureOfExpertsLayer(Layer):
       ``sigmoid`` (see :func:`_route`), with ``expert_bias`` (a constant
       ``[n_experts]`` added for the selection only, drawn at
       ``EXPERT_BIAS_SCALE``; nothing updates it in training), ``norm_topk``
-      and ``routed_scaling``;
+      (with ``norm_topk_eps`` beside the sum) and ``routed_scaling``;
     - ``gated``: each expert is ``W2(act(W1 x) * W3 x)`` with hidden width
       ``n_hidden`` and no bias, else ``act(W x + b)``;
     - ``experts_held=(first, count)``: the layer holds that share.
@@ -478,6 +480,7 @@ class MixtureOfExpertsLayer(Layer):
     gate: str = "softmax_topk"
     expert_bias: bool = False
     norm_topk: bool = False
+    norm_topk_eps: float = 1e-6
     routed_scaling: float = 1.0
     gated: bool = False
     n_hidden: int = 0
@@ -564,7 +567,7 @@ class MixtureOfExpertsLayer(Layer):
         return _moe_share(
             params, x, top_k=self.top_k, act=self.act_fn(), gate=self.gate,
             norm_topk=self.norm_topk, scaling=self.routed_scaling,
-            gated=self.gated,
+            norm_eps=self.norm_topk_eps, gated=self.gated,
             first=self._held()[0] if first is None else first)
 
     def forward(self, params, x, *, state=None, train=False, rng=None,

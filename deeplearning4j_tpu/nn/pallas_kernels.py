@@ -373,16 +373,21 @@ def _kernel_metadata_on_one_line() -> None:
 
 @functools.lru_cache(maxsize=64)
 def _splash_kernel(heads: int, t: int, row_bytes: int, causal: bool,
-                   interpret: bool):
+                   interpret: bool, window=None):
     """One splash-attention kernel object per shape and masking, kept:
     building it processes the mask on the host, and a step is traced more
     than once. Built outside any trace, so that what it holds (the block
-    schedule, as arrays) can be shared between traces."""
+    schedule, as arrays) can be shared between traces. With ``window`` the
+    mask is the causal band ``0 <= q - k < window``, and the blocks wholly
+    outside it are neither fetched nor computed."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
-        CausalMask, FullMask, MultiHeadMask, make_splash_mha)
+        CausalMask, FullMask, LocalMask, MultiHeadMask, make_splash_mha)
 
     _kernel_metadata_on_one_line()
-    mask = (CausalMask if causal else FullMask)((t, t))
+    if window is not None:
+        mask = LocalMask((t, t), (window - 1, 0), 0)
+    else:
+        mask = (CausalMask if causal else FullMask)((t, t))
     with jax.ensure_compile_time_eval():
         return make_splash_mha(
             MultiHeadMask([mask] * heads),
@@ -415,22 +420,24 @@ class PallasFlashAttentionHelper(AttentionHelper):
         self.interpret = interpret
 
     def supports(self, layer, q_shape, mask, dropout_active,
-                 causal=False) -> bool:
+                 causal=False, window=None) -> bool:
         if jax.default_backend() != "tpu":
             return False
         if causal != self.causal:
             # semantics must match the request exactly: a causal kernel must
-            # not serve a bidirectional layer and vice versa
+            # not serve a bidirectional layer and vice versa (a window is a
+            # band of the causal mask: `dot_product_attention` refuses one
+            # without `causal`)
             return False
         if mask is not None or dropout_active:
             return False
         t, dh = q_shape[-2], q_shape[-1]
         return t % 128 == 0 and dh in (64, 128, 256)
 
-    def attend(self, q, k, v):
+    def attend(self, q, k, v, window=None):
         _, heads, t, dh = q.shape
         kernel = _splash_kernel(heads, t, dh * q.dtype.itemsize, self.causal,
-                                self.interpret)
+                                self.interpret, window)
         # the kernel takes no softmax scale: q carries it, rounded once
         # from float32 (exact for dh = 64 or 256, whose scale is a power of
         # two)

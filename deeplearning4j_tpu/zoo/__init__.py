@@ -15,6 +15,7 @@ from deeplearning4j_tpu.zoo.models import (
     AlexNet,
     Darknet19,
     FaceNetNN4Small2,
+    GatedWindowMoELM,
     GoogLeNet,
     HybridConvMoELM,
     InceptionResNetV1,
@@ -38,8 +39,8 @@ from deeplearning4j_tpu.zoo.models import (
 __all__ = [
     "ModelMetaData", "ModelSelector", "PretrainedType", "ZooModel",
     "register_zoo_model",
-    "AlexNet", "Darknet19", "FaceNetNN4Small2", "GoogLeNet",
-    "HybridConvMoELM",
+    "AlexNet", "Darknet19", "FaceNetNN4Small2", "GatedWindowMoELM",
+    "GoogLeNet", "HybridConvMoELM",
     "InceptionResNetV1", "LeNet", "ResNet50", "SimpleCNN",
     "TextGenerationLSTM", "TinyYOLO", "TransformerEncoder", "TransformerLM",
     "VisionTransformer",

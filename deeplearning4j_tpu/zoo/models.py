@@ -1273,6 +1273,25 @@ class VisionTransformer(ZooModel):
         return g.build()
 
 
+def _gated_dense_ff(g, prefix: str, src: str, d_model: int,
+                    d_ff: int) -> str:
+    """``W2(silu(W1 x) * W3 x)`` without biases as three dense vertices
+    ``<prefix>1``, ``<prefix>3``, ``<prefix>2`` and their product
+    ``<prefix>g``. Returns the output vertex name."""
+    from deeplearning4j_tpu.nn.vertices import ElementWiseVertex
+
+    for part, activation in (("1", "silu"), ("3", "identity")):
+        g.add_layer(prefix + part,
+                    DenseLayer(n_in=d_model, n_out=d_ff, has_bias=False,
+                               activation=activation), src)
+    g.add_vertex(prefix + "g", ElementWiseVertex(op="product"),
+                 prefix + "1", prefix + "3")
+    g.add_layer(prefix + "2", DenseLayer(n_in=d_ff, n_out=d_model,
+                                         has_bias=False,
+                                         activation="identity"), prefix + "g")
+    return prefix + "2"
+
+
 def hybrid_conv_moe_block(g, name: str, src: str, operator: str, *,
                           d_model: int, n_heads: int, n_kv_heads: int,
                           rope_theta: float, conv_kernel: int, norm_eps: float,
@@ -1313,18 +1332,8 @@ def hybrid_conv_moe_block(g, name: str, src: str, operator: str, *,
     g.add_vertex(f"{name}-res1", ElementWiseVertex(op="add"), src, op)
     g.add_layer(f"{name}-norm2", RMSNormLayer(eps=norm_eps), f"{name}-res1")
     if experts is None:
-        # W2(silu(W1 x) * W3 x), no biases
-        for part, activation in (("ff1", "silu"), ("ff3", "identity")):
-            g.add_layer(f"{name}-{part}",
-                        DenseLayer(n_in=d_model, n_out=dense_ff,
-                                   has_bias=False, activation=activation),
-                        f"{name}-norm2")
-        g.add_vertex(f"{name}-ffg", ElementWiseVertex(op="product"),
-                     f"{name}-ff1", f"{name}-ff3")
-        ff = f"{name}-ff2"
-        g.add_layer(ff, DenseLayer(n_in=dense_ff, n_out=d_model,
-                                   has_bias=False, activation="identity"),
-                    f"{name}-ffg")
+        ff = _gated_dense_ff(g, f"{name}-ff", f"{name}-norm2", d_model,
+                             dense_ff)
     else:
         ff = f"{name}-moe"
         g.add_layer(ff, MixtureOfExpertsLayer(n_in=d_model, n_out=d_model,
@@ -1405,6 +1414,155 @@ class HybridConvMoELM(ZooModel):
             src = hybrid_conv_moe_block(
                 g, f"block{i}", src, operator, dense_ff=self.d_ff,
                 experts=None if dense else self.experts, **self.block)
+        g.add_layer("norm_f", RMSNormLayer(eps=self.block["norm_eps"]), src)
+        g.add_layer("out", RnnOutputLayer(n_in=d_model, n_out=self.vocab_size,
+                                          has_bias=False,
+                                          activation="softmax", loss="mcxent"),
+                    "norm_f")
+        g.set_outputs("out")
+        return g.build()
+
+
+def gated_window_moe_block(g, name: str, src: str, layer_type: str, *,
+                           d_model: int, n_heads: int, n_kv_heads: int,
+                           head_size: int, window: int, rope_theta: float,
+                           norm_eps: float, max_len: int, dense_ff: int = 0,
+                           experts: dict = None, shared_ff: int = 0) -> str:
+    """One block of the AFMoE family (arcee-ai Trinity): an RMSNorm before
+    and after each half, ``h + norm(attention(norm(h)))`` then ``h +
+    norm(ff(norm(h)))``. The attention is grouped-query with QK-norm, no
+    biases and a sigmoid gate on its output; a ``sliding_attention`` layer
+    (vertex ``<name>-swa``) sees its last ``window`` keys and rotates q and
+    k, a ``full_attention`` layer (``<name>-att``) sees every earlier key
+    and no positions. The feed-forward is a gated dense one of width
+    ``dense_ff`` or, from ``experts`` (the keyword arguments of
+    :class:`MixtureOfExpertsLayer`), a sparse expert layer ``<name>-moe``
+    plus, with ``shared_ff``, a gated dense one of that width that every
+    token passes through (``<name>-shared1|3|2``). Returns the output
+    vertex name."""
+    from deeplearning4j_tpu.nn.layers import (
+        GroupedQueryAttentionLayer,
+        MixtureOfExpertsLayer,
+        RMSNormLayer,
+    )
+    from deeplearning4j_tpu.nn.vertices import ElementWiseVertex
+
+    if layer_type not in ("sliding_attention", "full_attention"):
+        raise ValueError(f"layer type {layer_type!r} is neither "
+                         f"'sliding_attention' nor 'full_attention'")
+    sliding = layer_type == "sliding_attention"
+    op = f"{name}-swa" if sliding else f"{name}-att"
+    g.add_layer(f"{name}-norm1", RMSNormLayer(eps=norm_eps), src)
+    g.add_layer(op, GroupedQueryAttentionLayer(
+        n_heads=n_heads, n_kv_heads=n_kv_heads, head_size=head_size,
+        use_bias=False, qk_norm=True, qk_norm_eps=norm_eps,
+        rope_theta=rope_theta if sliding else None,
+        window=window if sliding else None, output_gate=True,
+        max_cache=max_len, activation="identity"), f"{name}-norm1")
+    g.add_layer(f"{name}-norm1p", RMSNormLayer(eps=norm_eps), op)
+    g.add_vertex(f"{name}-res1", ElementWiseVertex(op="add"), src,
+                 f"{name}-norm1p")
+    g.add_layer(f"{name}-norm2", RMSNormLayer(eps=norm_eps), f"{name}-res1")
+    if experts is None:
+        ff = _gated_dense_ff(g, f"{name}-ff", f"{name}-norm2", d_model,
+                             dense_ff)
+    else:
+        ff = f"{name}-moe"
+        g.add_layer(ff, MixtureOfExpertsLayer(n_in=d_model, n_out=d_model,
+                                              **experts), f"{name}-norm2")
+        if shared_ff:
+            shared = _gated_dense_ff(g, f"{name}-shared", f"{name}-norm2",
+                                     d_model, shared_ff)
+            ff = f"{name}-ffsum"
+            g.add_vertex(ff, ElementWiseVertex(op="add"), f"{name}-moe",
+                         shared)
+    g.add_layer(f"{name}-norm2p", RMSNormLayer(eps=norm_eps), ff)
+    g.add_vertex(f"{name}-res2", ElementWiseVertex(op="add"),
+                 f"{name}-res1", f"{name}-norm2p")
+    return f"{name}-res2"
+
+
+@register_zoo_model
+class GatedWindowMoELM(ZooModel):
+    """Causal language model of the AFMoE family (arcee-ai Trinity-Mini):
+    token ids [N,T] → embedding times ``sqrt(d_model)`` → blocks
+    (:func:`gated_window_moe_block`) whose attention is a sliding window or
+    full by ``layer_types[l]`` and whose feed-forward is dense for the
+    first ``num_dense_layers`` blocks and, after them, sparse experts
+    (sigmoid routing with a selection-only bias, top-k weights normalised
+    and scaled by ``route_scale``) beside a shared expert → RMSNorm →
+    untied softmax head. Labels as for :class:`TransformerLM`: int32 class
+    ids [N,T] (:func:`lm_labels`).
+
+    ``experts_held=(first, count)`` builds every expert layer as that share
+    of the experts (see :class:`MixtureOfExpertsLayer`); the shared expert
+    is whole in every share. Defaults are the published sizes.
+    ``learning_rate`` is Adam's: a float or a
+    :class:`~deeplearning4j_tpu.nn.updaters.Schedule` (a warm-up, say).
+    """
+
+    def __init__(self, num_labels: int = 0, seed: int = 123,
+                 vocab_size: int = 200192, max_length: int = 8192,
+                 layer_types=("sliding_attention", "sliding_attention",
+                              "sliding_attention", "full_attention") * 8,
+                 num_dense_layers: int = 2, d_model: int = 2048,
+                 n_heads: int = 32, n_kv_heads: int = 4, head_size: int = 128,
+                 d_ff: int = 6144, n_experts: int = 128,
+                 experts_per_token: int = 8, expert_d_ff: int = 1024,
+                 n_shared_experts: int = 1, experts_held=None,
+                 sliding_window: int = 2048, rope_theta: float = 1e4,
+                 route_norm: bool = True, route_scale: float = 2.826,
+                 scale_embedding: bool = True, norm_eps: float = 1e-5,
+                 learning_rate=3e-4):
+        vocab_size = num_labels or vocab_size
+        super().__init__(vocab_size, seed)
+        self.vocab_size = vocab_size
+        self.max_length = max_length
+        self.layer_types = tuple(layer_types)
+        self.num_dense_layers = num_dense_layers
+        self.scale_embedding = scale_embedding
+        self.learning_rate = learning_rate
+        self.block = dict(d_model=d_model, n_heads=n_heads,
+                          n_kv_heads=n_kv_heads, head_size=head_size,
+                          window=sliding_window, rope_theta=rope_theta,
+                          norm_eps=norm_eps, max_len=max_length)
+        self.d_ff = d_ff
+        self.shared_ff = n_shared_experts * expert_d_ff
+        self.experts = dict(
+            n_experts=n_experts, top_k=experts_per_token, n_hidden=expert_d_ff,
+            gated=True, activation="silu", gate="sigmoid", expert_bias=True,
+            norm_topk=route_norm, norm_topk_eps=1e-20,
+            routed_scaling=route_scale, experts_held=experts_held)
+
+    def meta_data(self):
+        return ModelMetaData(((self.max_length,),), 1, "rnn")
+
+    def conf(self):
+        from deeplearning4j_tpu.nn.layers import (
+            EmbeddingSequenceLayer,
+            RMSNormLayer,
+        )
+        from deeplearning4j_tpu.nn.vertices import ScaleVertex
+
+        d_model = self.block["d_model"]
+        g = (NeuralNetConfiguration.builder().seed(self.seed)
+             .weight_init("xavier").updater(Adam(self.learning_rate))
+             .graph_builder()
+             .add_inputs("tokens")
+             .set_input_types(InputType.recurrent(1, self.max_length)))
+        g.add_layer("embed", EmbeddingSequenceLayer(n_in=self.vocab_size,
+                                                    n_out=d_model), "tokens")
+        src = "embed"
+        if self.scale_embedding:
+            src = "embed-scale"
+            g.add_vertex(src, ScaleVertex(scale_factor=d_model ** 0.5),
+                         "embed")
+        for i, layer_type in enumerate(self.layer_types):
+            dense = i < self.num_dense_layers
+            src = gated_window_moe_block(
+                g, f"block{i}", src, layer_type, dense_ff=self.d_ff,
+                experts=None if dense else self.experts,
+                shared_ff=0 if dense else self.shared_ff, **self.block)
         g.add_layer("norm_f", RMSNormLayer(eps=self.block["norm_eps"]), src)
         g.add_layer("out", RnnOutputLayer(n_in=d_model, n_out=self.vocab_size,
                                           has_bias=False,

@@ -116,7 +116,8 @@ class TestZooInstantiation:
             "textgenerationlstm", "tinyyolo", "vgg16", "vgg19", "yolo2"}
         assert reference_13 <= set(names)
         # ... plus the attention-era additions with no reference counterpart
-        assert set(names) - reference_13 == {"hybridconvmoelm",
+        assert set(names) - reference_13 == {"gatedwindowmoelm",
+                                             "hybridconvmoelm",
                                              "transformerencoder",
                                              "transformerlm",
                                              "visiontransformer"}
