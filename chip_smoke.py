@@ -574,8 +574,8 @@ def phase_serve(ctx) -> dict:
 # --------------------------------------------------------------- fourchip
 def _forward_collectives(net, mesh, tokens) -> dict:
     """Collective counts in the compiled, partitioned forward, and how many
-    Mosaic kernels it holds (none while the auto gates stand aside for the
-    partitioner: nn/helpers.py ``partitioned_by_compiler``)."""
+    Mosaic kernels it holds (the attention kernels, one a layer, each under
+    the seam's ``shard_map``: nn/helpers.py ``kernel_shards``)."""
     import re
 
     import jax.numpy as jnp

@@ -15,7 +15,8 @@ pattern — see tests/test_helpers.py).
 
 from __future__ import annotations
 
-from typing import Dict, Optional
+import functools
+from typing import Dict, NamedTuple, Optional
 
 _HELPERS: Dict[str, object] = {}
 _VERSION = 0  # bumped on every registry change; part of every jit cache key
@@ -72,15 +73,72 @@ def partitioned_by_compiler(x) -> bool:
     puts the mesh of any sharded argument into the type of everything
     computed from it). Mosaic refuses such a program — "Mosaic kernels
     cannot be automatically partitioned" — and only a chip with several
-    devices ever shows it, so both auto gates ask here before they pick a
-    Pallas kernel. A helper registered by hand is not second-guessed: JAX's
-    own error says what to do."""
+    devices ever shows it, so the expert layer's and the LSTM's auto gates
+    ask here before they pick a Pallas kernel; attention asks
+    :func:`kernel_shards`, which also says how the kernel may run there."""
+    return bool(_mesh_and_compiler_axes(x)[1])
+
+
+def _mesh_and_compiler_axes(x):
+    """The mesh in ``x``'s traced type, and name and size of each of its
+    axes that has more than one device and that the compiler still
+    manages."""
     import jax
     from jax.sharding import AxisType
 
     mesh = jax.typeof(x).sharding.mesh
-    return any(size > 1 and kind != AxisType.Manual
-               for size, kind in zip(mesh.axis_sizes, mesh.axis_types))
+    return mesh, {name: size for name, size, kind in zip(
+        mesh.axis_names, mesh.axis_sizes, mesh.axis_types)
+        if size > 1 and kind != AxisType.Manual}
+
+
+class KernelShards(NamedTuple):
+    """Where a Pallas kernel over ``[N, H, ...]`` operands runs: on the
+    whole operand (``mesh`` is None: no compiler partitions the program),
+    or once per shard of ``shape`` under a ``shard_map`` that makes the
+    mesh's axes manual round it."""
+
+    mesh: object
+    spec: object
+    shape: tuple
+
+    def per_shard(self, fn, **keywords):
+        """``fn(*operands, **keywords)`` with every operand laid out by
+        ``spec`` and each call on one shard; the result is laid out the
+        same way."""
+        from deeplearning4j_tpu.parallel.mesh import shard_map
+        return shard_map(functools.partial(fn, **keywords), mesh=self.mesh,
+                         in_specs=self.spec, out_specs=self.spec)
+
+
+def kernel_shards(x) -> Optional[KernelShards]:
+    """**The rule for a kernel under a mesh**, stated here once. ``x`` is
+    ``[N, H, ...]``: dimension 0 is split over ``DATA_AXIS`` and dimension
+    1 over ``MODEL_AXIS`` (`parallel/mesh.py`: where ``place_batch`` puts
+    the batch and ``DEFAULT_2D_RULES`` the head-major ``Wqkv`` columns);
+    no other dimension is ever split. That serves a program in which every
+    axis the compiler manages (larger than 1, not already manual) is one
+    of those two, ``data`` divides N and ``model`` divides H. Any other
+    partitioned program (a ``seq``, ``pipe`` or ``expert`` axis larger
+    than 1, 20 heads over a ``model`` of 3, a batch ``data`` does not
+    divide) gets None: Mosaic would refuse the kernel there, so the caller
+    keeps its XLA path. The mesh is read from the traced type, as
+    :func:`partitioned_by_compiler` reads it."""
+    from jax.sharding import PartitionSpec
+
+    from deeplearning4j_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+
+    mesh, axes = _mesh_and_compiler_axes(x)
+    if not axes:
+        return KernelShards(None, None, x.shape)
+    data, model = axes.pop(DATA_AXIS, 1), axes.pop(MODEL_AXIS, 1)
+    if axes or x.shape[0] % data or x.shape[1] % model:
+        return None
+    return KernelShards(
+        mesh,
+        PartitionSpec(DATA_AXIS if data > 1 else None,
+                      MODEL_AXIS if model > 1 else None),
+        (x.shape[0] // data, x.shape[1] // model) + x.shape[2:])
 
 
 # -- flash-attention auto-registration ---------------------------------------
@@ -88,8 +146,8 @@ def partitioned_by_compiler(x) -> bool:
 # at the sequence lengths where the kernel was measured to win
 # (layers/attention.py:_AUTO_FLASH_MIN_T and up; PERF.md §6 has the sweep)
 # automatically uses the causal PallasFlashAttentionHelper, which skips the
-# masked upper triangle the einsum path still computes, unless the program
-# is partitioned by the compiler (see above).
+# masked upper triangle the einsum path still computes; in a program the
+# compiler partitions it runs per shard where `kernel_shards` has a rule.
 # Registering any helper, or set_auto_flash_attention(False), overrides.
 _AUTO_FLASH = True
 
@@ -170,7 +228,10 @@ class AttentionHelper:
     ``window`` (a causal query sees its last ``window`` keys only): a
     helper that serves windows takes the keyword in both methods, and one
     that does not name it is never asked about a windowed request
-    (:func:`accepts_window`)."""
+    (:func:`accepts_window`). In a program the compiler partitions,
+    ``supports`` is asked about one shard's shape and ``attend`` is given
+    one shard at a time (:func:`kernel_shards`), a helper registered by
+    hand like the auto gate's."""
 
     def supports(self, layer, q_shape, mask, dropout_active,
                  causal=False) -> bool:  # pragma: no cover - interface

@@ -48,10 +48,9 @@ def dot_product_attention(q, k, v, mask=None, dropout_rate=0.0, rng=None,
     attention, and is treated as such from here on.
 
     Consults the "attention" helper seam first: a registered fused kernel
-    (e.g. PallasFlashAttentionHelper) takes supported shapes — causality
-    and the window are part of the request, so a helper only serves
-    requests whose semantics it reproduces; otherwise the einsum path below
-    runs (and XLA fuses it).
+    takes the requests it supports, causality and window included, and in a
+    program the compiler partitions one shard of batch and heads at a time
+    (`helpers.kernel_shards` has the rule); else the einsum path below runs.
     """
     from deeplearning4j_tpu.nn import helpers as _helpers
     if window is not None:
@@ -63,28 +62,29 @@ def dot_product_attention(q, k, v, mask=None, dropout_rate=0.0, rng=None,
     helper = _helpers.get_helper("attention")
     dropout_active = bool(train and dropout_rate > 0 and rng is not None)
     if (helper is None and causal and q.shape[-2] >= _AUTO_FLASH_MIN_T
-            and _helpers.auto_flash_attention_enabled()
-            and not _helpers.partitioned_by_compiler(q)):
+            and _helpers.auto_flash_attention_enabled()):
         # no helper registered: the causal kernel serves the lengths at
-        # which it was measured to win (PERF.md §6), so that the gain does
-        # not depend on knowing the seam exists; opt out via
-        # helpers.set_auto_flash_attention(False)
+        # which it was measured to win (PERF.md §6), seam known or not
         helper = _auto_flash_helper()
     # a helper that knows no window is never given one
     windowed = {} if window is None else {"window": window}
-    kernel = (helper is not None
+    shards = None if helper is None else _helpers.kernel_shards(q)
+    kernel = (shards is not None
               and _helpers.accepts_window(helper, window)
-              and helper.supports(None, q.shape, mask, dropout_active,
+              and helper.supports(None, shards.shape, mask, dropout_active,
                                   causal=causal, **windowed)
               and q.shape == k.shape == v.shape)
     tracer = _trace.get_active_tracer()
     if tracer is not None:
         # which path this call took, counted while its step is traced
-        tracer.count("attention.kernel_calls" if kernel
-                     else "attention.einsum_calls")
+        path = "kernel_calls" if kernel else "einsum_calls"
+        tracer.count("attention." + path)
         if window is not None:
-            tracer.count("attention.window_kernel_calls" if kernel
-                         else "attention.window_einsum_calls")
+            tracer.count("attention.window_" + path)
+        if kernel and shards.mesh is not None:
+            tracer.count("attention.sharded_kernel_calls")
+    if kernel and shards.mesh is not None:
+        return shards.per_shard(helper.attend, **windowed)(q, k, v)
     if kernel:
         return helper.attend(q, k, v, **windowed)
     scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(q.dtype)

@@ -43,11 +43,15 @@ class _Spy(PK.PallasFlashAttentionHelper):
         return q
 
 
-def _on_mesh(x):
+def _on_mesh(x, axes=None, spec=("data",)):
+    """`x` on a mesh of host devices (the 2x2 unless `axes` says another),
+    its dimensions split as `spec` says."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
-    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2),
-                ("data", "model"))
-    return jax.device_put(x, NamedSharding(mesh, PartitionSpec("data")))
+    axes = axes or {"data": 2, "model": 2}
+    n = int(np.prod(list(axes.values())))
+    mesh = Mesh(np.asarray(jax.devices()[:n]).reshape(tuple(axes.values())),
+                tuple(axes))
+    return jax.device_put(x, NamedSharding(mesh, PartitionSpec(*spec)))
 
 
 @pytest.mark.parametrize("t,how,kernel", [
@@ -56,11 +60,14 @@ def _on_mesh(x):
     (512, "plain", False),
     (1024, "mask", False),
     (1024, "dropout", False),
-    (1024, "partitioned", False),
+    (1024, "partitioned", True),
+    (512, "partitioned", False),
     (1024, "not causal", False),
 ])
 def test_auto_gate_by_length_and_request(monkeypatch, as_on_a_chip, tracer,
                                          t, how, kernel):
+    """Under the 2x2 the helper is asked about, and given, one shard: half
+    the batch, half the heads, all of T and Dh."""
     calls = []
     monkeypatch.setattr(A, "_auto_flash_helper", lambda: _Spy(calls))
     q = jnp.ones((2, 2, t, 64), jnp.bfloat16)
@@ -73,9 +80,14 @@ def test_auto_gate_by_length_and_request(monkeypatch, as_on_a_chip, tracer,
     if how == "partitioned":
         q = _on_mesh(q)
     jax.jit(lambda q: A.dot_product_attention(q, q, q, **kwargs)).trace(q)
-    assert len(calls) == int(kernel)
-    assert tracer.counters == {
+    sharded = kernel and how == "partitioned"
+    assert calls == ([(1, 1, t, 64) if sharded else (2, 2, t, 64)]
+                     if kernel else [])
+    counters = {
         "attention.kernel_calls" if kernel else "attention.einsum_calls": 1}
+    if sharded:
+        counters["attention.sharded_kernel_calls"] = 1
+    assert tracer.counters == counters
 
 
 def test_gate_is_where_the_sweep_put_it():
@@ -150,6 +162,132 @@ def test_kernel_matches_einsum_at_the_gate(rng, dtype, rtol, atol):
         np.testing.assert_allclose(
             np.asarray(a, np.float32), b, rtol=rtol,
             atol=atol * max(1.0, float(np.abs(b).max())), err_msg=name)
+
+
+def _loss_and_grads(fn, q, k, v, w):
+    return jax.jit(jax.value_and_grad(
+        lambda q, k, v: jnp.sum((fn(q, k, v) * w).astype(jnp.float32)),
+        argnums=(0, 1, 2)))(q, k, v)
+
+
+@pytest.fixture
+def interpreter_kernel():
+    """The causal kernel registered by hand and run by the Pallas
+    interpreter: in a partitioned program a registered helper is placed by
+    the same rule as the auto gate's."""
+    from deeplearning4j_tpu.nn import helpers
+    helpers.set_helper("attention", PK.PallasFlashAttentionHelper(
+        causal=True, interpret=True))
+    try:
+        yield
+    finally:
+        helpers.clear_helper("attention")
+
+
+@pytest.mark.parametrize("window", [None, 128])
+def test_kernel_under_the_mesh_matches_einsum(rng, as_on_a_chip, tracer,
+                                              interpreter_kernel, window):
+    """[4,4,256,64] float32 over data=2 x model=2: each device runs the
+    kernel on its [2,2,256,64]; values and the three gradients against the
+    einsum path on one device."""
+    from deeplearning4j_tpu.nn import helpers
+
+    q, k, v, w = (jnp.asarray(rng.normal(size=(4, 4, 256, 64))
+                              .astype(np.float32)) for _ in range(4))
+
+    def attend(q, k, v):
+        return A.dot_product_attention(q, k, v, causal=True, window=window)
+
+    spec = ("data", "model")
+    placed = [_on_mesh(x, spec=spec) for x in (q, k, v)]
+    out = jax.jit(attend)(*placed)
+    loss_a, grads_a = _loss_and_grads(attend, *placed, _on_mesh(w, spec=spec))
+    windows = {} if window is None else {"attention.window_kernel_calls": 2}
+    assert tracer.counters == {"attention.kernel_calls": 2,
+                               "attention.sharded_kernel_calls": 2, **windows}
+    assert out.sharding.spec == jax.sharding.PartitionSpec(*spec)
+
+    helpers.clear_helper("attention")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(attend(q, k, v)),
+                               rtol=2e-4, atol=2e-5)
+    loss_b, grads_b = _loss_and_grads(attend, q, k, v, w)
+    np.testing.assert_allclose(float(loss_a), float(loss_b), rtol=2e-4)
+    for name, a, b in zip(("dq", "dk", "dv"), grads_a, grads_b):
+        assert a.sharding.spec == jax.sharding.PartitionSpec(*spec), name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-5 * float(np.abs(b).max()),
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("how,axes,shape", [
+    ("heads the model axis does not divide", {"data": 2, "model": 3},
+     (2, 4, 1024, 64)),
+    ("a batch the data axis does not divide", {"data": 2, "model": 2},
+     (3, 2, 1024, 64)),
+    ("a third axis larger than 1", {"data": 2, "model": 2, "seq": 2},
+     (2, 2, 1024, 64)),
+    ("T below the gate", {"data": 2, "model": 2}, (2, 2, 512, 64)),
+])
+def test_meshes_the_rule_does_not_serve_take_the_einsum_path(
+        monkeypatch, rng, as_on_a_chip, tracer, how, axes, shape):
+    """No helper is asked, the counters say einsum, and the partitioned
+    program gives what one device gives, gradients too."""
+    calls = []
+    monkeypatch.setattr(A, "_auto_flash_helper", lambda: _Spy(calls))
+    q, k, v, w = (jnp.asarray(rng.normal(size=shape).astype(np.float32))
+                  for _ in range(4))
+    # a batch of 3 cannot be split in two: replicated, it still carries
+    # the mesh into the traced type
+    spec = () if shape[0] % axes["data"] else ("data",)
+
+    def attend(q, k, v):
+        return A.dot_product_attention(q, k, v, causal=True)
+
+    loss_a, grads_a = _loss_and_grads(
+        attend, *(_on_mesh(x, axes, spec) for x in (q, k, v, w)))
+    assert calls == []
+    assert tracer.counters == {"attention.einsum_calls": 1}
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    loss_b, grads_b = _loss_and_grads(attend, q, k, v, w)
+    np.testing.assert_allclose(float(loss_a), float(loss_b), rtol=1e-4)
+    for name, a, b in zip(("dq", "dk", "dv"), grads_a, grads_b):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3,
+                                   atol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("mode,sharded", [("shared_gradients", True),
+                                          ("averaging", False)])
+def test_parallel_wrapper_reaches_the_kernel(monkeypatch, as_on_a_chip,
+                                             tracer, mode, sharded):
+    """Two workers, a batch of four: either way a worker's kernel sees two
+    sequences. The compiler partitions the shared-gradients step, so the
+    seam makes `data` manual; the averaging step's own `shard_map` already
+    has, and the seam finds nothing left to do."""
+    from deeplearning4j_tpu.datasets.dataset import DataSet
+    from deeplearning4j_tpu.nn.conf import InputType, NeuralNetConfiguration
+    from deeplearning4j_tpu.nn.layers import (CausalSelfAttentionLayer,
+                                              RnnOutputLayer)
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+    from deeplearning4j_tpu.parallel.mesh import make_mesh
+    from deeplearning4j_tpu.parallel.trainer import ParallelWrapper
+
+    calls = []
+    monkeypatch.setattr(A, "_auto_flash_helper", lambda: _Spy(calls))
+    net = MultiLayerNetwork(
+        NeuralNetConfiguration.builder().seed(1).list()
+        .layer(CausalSelfAttentionLayer(n_out=128, n_heads=2,
+                                        max_cache=1024))
+        .layer(RnnOutputLayer(n_out=4))
+        .set_input_type(InputType.recurrent(128, 1024)).build()).init()
+    wrapper = ParallelWrapper(net, make_mesh({"data": 2}, jax.devices()[:2]),
+                              mode=mode, averaging_frequency=1)
+    labels = np.zeros((4, 1024, 4), np.float32)
+    labels[..., 0] = 1
+    wrapper.fit(DataSet(np.zeros((4, 1024, 128), np.float32), labels))
+    assert calls == [(2, 2, 1024, 64)]
+    assert tracer.counters.get("attention.kernel_calls") == 1
+    assert tracer.counters.get("attention.sharded_kernel_calls",
+                               0) == int(sharded)
 
 
 def _twelve_layer_step(t):

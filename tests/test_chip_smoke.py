@@ -130,28 +130,34 @@ def test_partitioned_by_compiler_reads_the_traced_type():
                     "shard_map": False}
 
 
-def test_auto_gates_leave_partitioned_programs_alone(monkeypatch):
+def test_auto_gates_under_a_partitioned_program(monkeypatch):
+    """Attention's gate hands the kernel one shard at a time, under manual
+    axes (`helpers.kernel_shards`); the LSTM's still stands aside."""
+    from deeplearning4j_tpu.nn import helpers
     from deeplearning4j_tpu.nn.layers import LSTMLayer
     from deeplearning4j_tpu.nn.layers import attention as A
     from deeplearning4j_tpu.nn.layers import recurrent as R
 
     class Spy:
-        calls = 0
+        seen = []
 
-        def supports(self, *a, **k):
+        def supports(self, layer, q_shape, *a, **k):
             return True
 
         def attend(self, q, k, v):
-            Spy.calls += 1
+            Spy.seen.append((q.shape, helpers.partitioned_by_compiler(q)))
             return q
 
     monkeypatch.setattr(A, "_auto_flash_helper", Spy)
     q = jnp.ones((4, 2, 2048, 64))
     attend = jax.jit(lambda q: A.dot_product_attention(q, q, q, causal=True))
     attend(q)
-    assert Spy.calls == 1  # one device: the gate opens
-    attend(_on_mesh(q)[0])
-    assert Spy.calls == 1  # sharded over the mesh: the einsum path
+    assert Spy.seen == [((4, 2, 2048, 64), False)]  # one device: whole
+    out = attend(_on_mesh(q)[0])
+    # over data=2 x model=2: a shard of batch and heads, and no axis left
+    # to the compiler round the kernel
+    assert Spy.seen[1:] == [((2, 1, 2048, 64), False)]
+    assert out.shape == q.shape
 
     layer = LSTMLayer(n_in=8, n_out=128)
     region = {}
@@ -299,3 +305,89 @@ def test_expert_grouped_products_lower_for_tpu(monkeypatch):
     assert len(calls) == 6 + 3
     assert len(re.findall(r"call @gmm", text)) == 6
     assert len(re.findall(r"call @tgmm", text)) == 3
+
+
+# ------------------------------------------- the kernels under the mesh
+def _two_layer_step_on_the_2x2(t):
+    """The train step of a two-layer causal LM (Dh=64, four heads) placed
+    on data=2 x model=2 by `DEFAULT_2D_RULES`, and its arguments as `fit()`
+    would pass them; nothing has run."""
+    import numpy as np
+
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    from deeplearning4j_tpu.parallel.mesh import make_mesh
+    from deeplearning4j_tpu.parallel.sharding import (place_batch,
+                                                      shard_model_with_rules)
+    from deeplearning4j_tpu.zoo.models import TransformerLM, lm_labels
+
+    mesh = make_mesh({"data": 2, "model": 2}, jax.devices()[:4])
+    net = ComputationGraph(TransformerLM(
+        vocab_size=32, max_length=t, n_layers=2, d_model=256, n_heads=4,
+        d_ff=256, seed=3).conf()).init()
+    shard_model_with_rules(net, mesh)
+    tokens = np.zeros((4, t), np.int32)
+    it, ep, rng = net._device_tick()
+    return net._get_train_step(), (
+        net.params, net.states, net.updater_states, it, ep,
+        {"tokens": place_batch(jnp.asarray(tokens), mesh)},
+        [place_batch(jnp.asarray(lm_labels(tokens, 32)), mesh)],
+        None, None, rng)
+
+
+def test_train_step_on_the_2x2_lowers_with_two_kernels_a_layer(monkeypatch):
+    """At the gate's T the partitioned step holds a forward and a fused
+    backward kernel per layer, each on a shard of [2, 2, 1024, 64]."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step, args = _two_layer_step_on_the_2x2(1024)
+    text = step.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    # a function that holds a kernel is lowered once and called by each
+    # layer
+    runs = []
+    for body in re.split(r"\n  func\.func ", text)[1:]:
+        func = re.match(r"(?:private |public )?@(\w+)", body).group(1)
+        calls = _kernel_calls(body)
+        runs += [name for name, _ in calls] * len(
+            re.findall(rf"call @{func}\b", text))
+        assert all("tensor<2x2x1024x64xf32>" in operands
+                   for _, operands in calls), calls
+    assert sorted(runs) == ["splash_mha_dkv_no_residuals"] * 2 + [
+        "splash_mha_fwd_residuals"] * 2
+
+
+def test_kernels_under_the_mesh_add_no_collective(monkeypatch):
+    """The `shard_map`'s layout is the one the compiler had chosen for q, k
+    and v (batch over `data`, heads over `model`): compiled for the host's
+    2x2 with the interpreter's kernel in the einsum's place, the step has
+    no more collectives of any kind."""
+    from deeplearning4j_tpu import observe
+    from deeplearning4j_tpu.nn import helpers
+    from deeplearning4j_tpu.nn.pallas_kernels import (
+        PallasFlashAttentionHelper)
+
+    def collectives(tracer):
+        step, args = _two_layer_step_on_the_2x2(128)
+        text = step.lower(*args).compile().as_text()
+        counted = dict(tracer.counters)
+        tracer.counters.clear()
+        return counted, {kind: len(re.findall(rf"\b{kind}(?:-start)?\(", text))
+                         for kind in ("all-reduce", "all-gather",
+                                      "all-to-all", "collective-permute",
+                                      "reduce-scatter")}
+
+    tracer = observe.enable_tracing(jax_hook=False)
+    try:
+        path, einsum = collectives(tracer)
+        assert path["attention.einsum_calls"] == 2
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        helpers.set_helper("attention", PallasFlashAttentionHelper(
+            causal=True, interpret=True))
+        try:
+            path, kernel = collectives(tracer)
+        finally:
+            helpers.clear_helper("attention")
+        assert path["attention.sharded_kernel_calls"] == 2
+    finally:
+        observe.disable_tracing()
+    assert einsum["all-reduce"] > 0
+    assert all(kernel[kind] <= einsum[kind] for kind in einsum), (kernel,
+                                                                  einsum)
