@@ -9,9 +9,9 @@ of ``fit`` and the dispatch of one step with its spans and its device tick.
 
 An engine supplies ``_loss_fn(params, states, inputs, labels, rng, masks,
 label_masks, train, carries)``, ``_layer_items()``, ``_tree_of(pairs)``,
-``_to_batch(ds)``, ``_temporal_length(inputs)`` and ``_fit_tbptt(batch,
-t_total)``; a batch is ``(inputs, labels, masks, label_masks)`` in the
-engine's own shapes.
+``_to_batch(ds)``, ``_temporal_length(inputs)``, ``_fit_tbptt(batch,
+t_total)`` and ``_init_trees(seed)``; a batch is ``(inputs, labels, masks,
+label_masks)`` in the engine's own shapes.
 """
 
 from __future__ import annotations
@@ -284,3 +284,27 @@ class TrainingEngine:
         self.iteration += 1
         self._store_tick(new_it, new_rng)
         return carries
+
+    # ----------------------------------------------------------------- init
+    # Below the step on purpose: a Pallas kernel's compiled form carries the
+    # line numbers of its call path (``_step_body``, ``_dispatch_step``),
+    # so what is added to this file is added at its end.
+    def init(self, seed=None):
+        """Draw parameters, layer states and updater state from ``seed``
+        (the configuration's by default) and reset the counters; returns
+        ``self``. The engine's ``_init_trees`` does the drawing, eagerly,
+        one small program a shape: under tracing a ``model_init`` span
+        holds it, with the programs it fetched nested inside."""
+        tracer = _trace.get_active_tracer()
+        if tracer is None:
+            self._init_trees(seed)
+            return self
+        with tracer.span("model_init", category="setup") as span:
+            self._init_trees(seed)
+            leaves = jax.tree_util.tree_leaves
+            span.set_attribute("parameters", sum(
+                int(leaf.size) for leaf in leaves(self.params)))
+            span.set_attribute("bytes", sum(
+                int(leaf.size) * leaf.dtype.itemsize
+                for leaf in leaves((self.params, self.updater_states))))
+        return self

@@ -64,7 +64,8 @@ class ComputationGraph(TrainingEngine):
         self.transfer_bytes = 0
 
     # ----------------------------------------------------------------- init
-    def init(self, seed: Optional[int] = None) -> "ComputationGraph":
+    def _init_trees(self, seed: Optional[int] = None) -> None:
+        """What ``init()`` (``nn/engine.py``) draws for a DAG, by vertex."""
         g = self.conf.global_conf
         key = jax.random.PRNGKey(g.seed if seed is None else seed)
         self._rng_key = jax.random.fold_in(key, 0x5EED)
@@ -90,7 +91,6 @@ class ComputationGraph(TrainingEngine):
             self.updater_states[vd.name] = smap
         self.iteration = 0
         self.epoch = 0
-        return self
 
     # -------------------------------------------------------------- forward
     def _forward_all(self, params: Params, states: States,

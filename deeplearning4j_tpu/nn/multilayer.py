@@ -74,7 +74,8 @@ class MultiLayerNetwork(TrainingEngine):
         self._updaters: List[Dict[str, Updater]] = []
 
     # ------------------------------------------------------------------ init
-    def init(self, seed: Optional[int] = None) -> "MultiLayerNetwork":
+    def _init_trees(self, seed: Optional[int] = None) -> None:
+        """What ``init()`` (``nn/engine.py``) draws for a chain, by index."""
         g = self.conf.global_conf
         key = jax.random.PRNGKey(g.seed if seed is None else seed)
         self._rng_key = jax.random.fold_in(key, 0x5EED)
@@ -97,7 +98,6 @@ class MultiLayerNetwork(TrainingEngine):
             self.updater_states.append(smap)
         self.iteration = 0
         self.epoch = 0
-        return self
 
     # ------------------------------------------------------------- forward
     def _forward_all(self, params: Params, states: States, x: Array, *,

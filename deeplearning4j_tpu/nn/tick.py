@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
+from deeplearning4j_tpu.observe import trace as _trace
+
 _TLS = threading.local()
 
 
@@ -97,8 +99,15 @@ def device_tick(model, batch=None):
             model._next_rng())
     where, settle = _placement(model.params, batch)
     if settle:
-        model.params, model.states, model.updater_states = jax.device_put(
-            (model.params, model.states, model.updater_states), where)
+        trees = (model.params, model.states, model.updater_states)
+        # under tracing a ``state_commit`` span; a cached tick opens none
+        with _trace.span("state_commit", category="setup") as span:
+            model.params, model.states, model.updater_states = \
+                jax.device_put(trees, where)
+            if span is not None:
+                span.set_attribute("bytes", sum(
+                    int(leaf.nbytes)
+                    for leaf in jax.tree_util.tree_leaves(trees)))
     if where is not None:
         tick = jax.device_put(tick, where)
     model._tick = (mirror, tick)

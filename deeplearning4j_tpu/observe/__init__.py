@@ -10,15 +10,28 @@ Dapper-style request tracing the reference never had):
   path (``fit()``'s own ``host_wait`` / ``step_dispatch`` / ``listeners``
   spans in both engines, ParallelWrapper steps, the ParallelInference
   dispatcher, the ModelServer request path, streaming routes) from no-op to
-  recording;
+  recording, and with them the path from ``init()`` to the first step
+  (category ``setup``): ``model_init`` (either engine's ``init()``, with
+  ``parameters`` and ``bytes``), ``place_params`` (``shard_model`` /
+  ``shard_model_with_rules``: ``leaves``, ``bytes``, ``devices``) and
+  ``state_commit`` (the one ``device_put`` that commits a model's trees
+  before its first step). ``Tracer.count(name)`` keeps a process-wide
+  tally (``tracer.counters``: which path each traced attention, loss,
+  expert layer and GELU took) and puts the same count on the innermost
+  open span (``Span.counts``, exported among the span's ``args``), so the
+  first ``step_dispatch`` says by itself what its program was made of;
 - ``scope``    — the ``jax.named_scope`` labels the train step carries into
   the compiled HLO and the device trace (``Class:name`` per layer, ``loss``,
   ``regularization``, ``optimizer``, ``cast_params``). Metadata only: they
   cost nothing when the step runs, so they are always on, tracing or not;
 - ``jaxhook``  — JAX compile/lowering attribution: ``jax.monitoring``
-  events become ``xla_compile``/``jax_lowering`` spans nested under
-  whatever span triggered them (a train step's under its ``step_dispatch``),
-  so recompiles show up loudly;
+  events become ``jax_trace``/``jax_lowering``/``xla_compile``/``cache_load``
+  spans nested under whatever span triggered them (a train step's under its
+  ``step_dispatch``, ``init()``'s small programs under ``model_init``), so
+  recompiles show up loudly and a first step says how much of it was
+  Python tracing, lowering, and loading from the persistent compile cache;
+  the cache's hit and miss events become the counts
+  ``compile_cache.hits`` / ``compile_cache.misses`` on the span that paid;
 - ``export``   — Chrome trace-event JSON (``chrome://tracing``/Perfetto)
   with flow arrows across threads, plus a terminal text timeline;
 - ``metrics``  — the Prometheus registry core (promoted from

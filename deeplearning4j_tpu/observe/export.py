@@ -60,6 +60,8 @@ def to_chrome_trace(spans: Sequence[Span], *,
             args["error"] = s.error
         for k, v in s.attrs.items():
             args[str(k)] = sanitize_attr(v)
+        if s.counts:
+            args["counts"] = dict(s.counts)  # `Tracer.count` under the span
         events.append({
             "name": s.name, "cat": s.category, "ph": "X",
             "ts": ts, "dur": max((s.end_ns - s.start_ns) / 1e3, 0.0),
@@ -247,6 +249,9 @@ def text_timeline(spans: Sequence[Span], *, limit: Optional[int] = None,
 
         [+     0.000ms    12.40ms] train_step  iteration=1 batch=32
         [+     0.312ms     9.80ms]   xla_compile
+
+    A span's ``counts`` (``Tracer.count`` while it was open) follow its
+    attributes as ``#name=n``.
     """
     done = sorted((s for s in spans if s.end_ns is not None),
                   key=lambda sp: sp.start_ns)
@@ -275,6 +280,8 @@ def text_timeline(spans: Sequence[Span], *, limit: Optional[int] = None,
             line += f"  !{s.error}"
         if attrs and s.attrs:
             line += "  " + " ".join(f"{k}={v}" for k, v in s.attrs.items())
+        if attrs and s.counts:
+            line += "  " + " ".join(f"#{k}={v}" for k, v in s.counts.items())
         if s.links:
             # the Chrome exporter's flow arrows, in text: name the linked
             # source span when it is still in the window, else its id —

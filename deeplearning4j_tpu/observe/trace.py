@@ -5,7 +5,9 @@ stack: a :class:`Span` is one named, timed interval with attributes; a
 :class:`Tracer` creates spans, maintains the current-span context through
 ``contextvars`` (so nesting works across any same-thread call chain,
 including ``http.server`` handler threads), and records completed spans
-into a bounded ring-buffer :class:`TraceRecorder`.
+into a bounded ring-buffer :class:`TraceRecorder`. :meth:`Tracer.count`
+keeps a process-wide tally and puts the same count on the innermost open
+span (``Span.counts``), so that a count is read where the work happened.
 
 Cross-thread handoff is EXPLICIT, matching how the hot paths actually hop
 threads: the enqueueing side captures ``tracer.current_context()`` (or the
@@ -91,8 +93,8 @@ class Span:
     the recorder; open spans accept attributes and links."""
 
     __slots__ = ("name", "category", "trace_id", "span_id", "parent_id",
-                 "start_ns", "end_ns", "attrs", "links", "thread_id",
-                 "thread_name", "error")
+                 "start_ns", "end_ns", "attrs", "counts", "links",
+                 "thread_id", "thread_name", "error")
 
     def __init__(self, name: str, *, trace_id: str, span_id: str,
                  parent_id: Optional[str], start_ns: int,
@@ -106,6 +108,9 @@ class Span:
         self.start_ns = start_ns
         self.end_ns: Optional[int] = None
         self.attrs: Dict[str, Any] = dict(attrs) if attrs else {}
+        # what `Tracer.count` tallied while this span was the innermost
+        # open one of its context: a count at the boundary that caused it
+        self.counts: Dict[str, int] = {}
         self.links: List[SpanContext] = []
         self.thread_id = threading.get_ident()
         self.thread_name = threading.current_thread().name
@@ -178,9 +183,15 @@ class TraceRecorder:
             return len(self._spans)
 
 
-# the current span context, per execution context (thread/task)
-_current_ctx: "contextvars.ContextVar[Optional[Tuple[str, str]]]" = \
+# the current span, per execution context (thread/task): its two ids and
+# the open span itself, for the counts that land on it
+_current_ctx: "contextvars.ContextVar[Optional[Tuple[str, str, Span]]]" = \
     contextvars.ContextVar("dl4j_tpu_trace_ctx", default=None)
+
+# the hook's spans that are compile cost to `thread_compile_seconds`;
+# `jax_trace` and `cache_load` are spans and no more (a cache hit's
+# `xla_compile` already covers its `cache_load`)
+_COMPILE_COST_SPANS = ("xla_compile", "jax_lowering")
 
 
 class Tracer:
@@ -211,7 +222,7 @@ class Tracer:
     # ------------------------------------------------------------- context
     def current_context(self) -> Optional[SpanContext]:
         cur = _current_ctx.get()
-        return None if cur is None else SpanContext(*cur)
+        return None if cur is None else SpanContext(cur[0], cur[1])
 
     def current_traceparent(self) -> Optional[str]:
         ctx = self.current_context()
@@ -242,7 +253,7 @@ class Tracer:
         (the train step's call: a Pallas kernel's compiled form carries its
         call stack, so a second call site is a second program to compile)."""
         sp = self.start_span(name, **kw)
-        return sp, _current_ctx.set((sp.trace_id, sp.span_id))
+        return sp, _current_ctx.set((sp.trace_id, sp.span_id, sp))
 
     def exit_span(self, span: Span, token) -> None:
         _current_ctx.reset(token)
@@ -291,20 +302,34 @@ class Tracer:
         return sp
 
     def count(self, name: str) -> None:
-        """One more for the tally ``counters[name]``."""
+        """One more for the process-wide tally ``counters[name]``, and for
+        ``counts[name]`` of the innermost span open in the calling context,
+        where there is one: "36 under the first ``step_dispatch``" and not
+        only "108 in the process"."""
+        cur = _current_ctx.get()
         with self._compile_lock:
             self.counters[name] = self.counters.get(name, 0) + 1
+            if cur is not None:
+                counts = cur[2].counts
+                counts[name] = counts.get(name, 0) + 1
 
     # -------------------------------------------- compile attribution sink
-    def note_compile_event(self, span_name: str, duration_s: float) -> None:
+    def note_compile_event(self, span_name: str, duration_s: float,
+                           fun_name: Optional[str] = None) -> None:
         """Sink for the JAX monitoring hook (``observe.jaxhook``): records
-        the just-finished lowering/compile as a span under whatever context
-        is current on THIS thread — a recompile inside ``train_step`` or a
-        new batch bucket inside ``batch_execute`` nests exactly where it
-        happened and shows up loudly."""
+        the just-finished trace/lowering/compile/cache load as a span under
+        whatever context is current on THIS thread — a recompile inside
+        ``train_step`` or a new batch bucket inside ``batch_execute`` nests
+        exactly where it happened and shows up loudly. ``fun_name`` is the
+        function jax names with the event (``train_step``, ``_normal``), kept
+        as the span's attribute. Only ``xla_compile`` and ``jax_lowering``
+        count as compile seconds of the thread."""
         now = time.perf_counter_ns()
         self.record(span_name, now - int(duration_s * 1e9), now,
-                    category="compile")
+                    category="compile",
+                    attrs=None if fun_name is None else {"fun_name": fun_name})
+        if span_name not in _COMPILE_COST_SPANS:
+            return
         tid = threading.get_ident()
         with self._compile_lock:
             self._compile_s_by_thread[tid] = \
@@ -430,4 +455,4 @@ def current_span_ids() -> Tuple[Optional[str], Optional[str]]:
     records, pipeline journal lines) work for explicitly-passed tracers
     too, not just the process-wide active one."""
     cur = _current_ctx.get()
-    return (None, None) if cur is None else cur
+    return (None, None) if cur is None else cur[:2]

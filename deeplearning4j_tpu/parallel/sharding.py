@@ -9,6 +9,7 @@ matching collectives (all-gather / reduce-scatter) around the matmuls.
 
 from __future__ import annotations
 
+import functools
 import json
 import re
 
@@ -18,6 +19,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from deeplearning4j_tpu.observe import trace as _trace
 from deeplearning4j_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 
@@ -397,6 +399,27 @@ def lint_partition_rules(rules: Sequence, params) -> List[str]:
     return problems
 
 
+def _place_params_span(place):
+    """``place(net, mesh, ...)`` under a ``place_params`` span while tracing
+    is on: a model is built on one device and moved leaf by leaf, and the
+    span says how long that took for how many leaves, bytes and devices."""
+    @functools.wraps(place)
+    def placing(net, mesh: Mesh, *args, **kwargs):
+        tracer = _trace.get_active_tracer()
+        if tracer is None:
+            return place(net, mesh, *args, **kwargs)
+        with tracer.span("place_params", category="setup",
+                         attrs={"devices": int(mesh.devices.size)}) as span:
+            place(net, mesh, *args, **kwargs)
+            leaves = jax.tree_util.tree_leaves(
+                (net.params, net.states, net.updater_states))
+            span.set_attribute("leaves", len(leaves))
+            span.set_attribute("bytes",
+                               sum(int(leaf.nbytes) for leaf in leaves))
+    return placing
+
+
+@_place_params_span
 def shard_model_with_rules(net, mesh: Mesh, rules: Optional[Sequence] = None
                            ) -> None:
     """Place a model on a DP×MP mesh from a rule list, in-place (the
@@ -460,6 +483,7 @@ def _leaf_sharding_ok(shape, spec: P, mesh: Mesh) -> bool:
     return True
 
 
+@_place_params_span
 def shard_model(net, mesh: Mesh, tp_axis: Optional[str] = None) -> None:
     """Place a model's params / states / updater states on the mesh, in-place.
     Works for both MultiLayerNetwork (list params) and ComputationGraph

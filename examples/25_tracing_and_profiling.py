@@ -7,7 +7,9 @@ stack never had:
 - enable process-wide tracing (``observe.enable_tracing``) with the JAX
   compile hook: every XLA compile becomes an ``xla_compile`` span nested
   under whatever triggered it, so step-0 compilation and later recompiles
-  show up loudly;
+  show up loudly; set-up has spans of its own (``model_init`` round
+  ``init()``, with every small program it fetched inside), and the
+  timeline from ``init()`` to the end of the first step is printed;
 - train data-parallel over the mesh with ``ParallelWrapper`` — per-step
   ``train_step`` spans (device-synced, with loss/batch attrs) — plus a
   ``TraceListener`` that exports ``training_*`` Prometheus series through
@@ -40,7 +42,7 @@ from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.nn.updaters import Adam
 from deeplearning4j_tpu.observe import (TraceListener, default_registry,
                                         disable_tracing, enable_tracing,
-                                        parse_prometheus_text)
+                                        parse_prometheus_text, text_timeline)
 from deeplearning4j_tpu.parallel import ParallelWrapper
 from deeplearning4j_tpu.serving import (ModelRegistry, ModelServer,
                                         ModelServingClient)
@@ -78,6 +80,21 @@ def main():
     print(f"training: {len(step_spans)} train_step spans, "
           f"{len(compile_spans)} xla_compile spans "
           f"(step 0 pays the compile; steady state recompiles would be loud)")
+
+    # -- set-up from inside: what init() and the first step paid for ------
+    spans = tracer.recorder.spans()
+    init_span, = [s for s in spans if s.name == "model_init"]
+    first_step = min(step_spans, key=lambda s: s.start_ns)
+    setup = [s for s in spans if s.start_ns < first_step.end_ns]
+    fetched = [s for s in setup if s.name == "xla_compile"
+               and s.parent_id == init_span.span_id]
+    print(f"set-up: init() drew {init_span.attrs['parameters']} parameters "
+          f"({init_span.attrs['bytes']} bytes with the updater state) in "
+          f"{init_span.duration_ms:.0f} ms and fetched {len(fetched)} small "
+          f"programs on the way; jax traced "
+          f"{sum(s.name == 'jax_trace' for s in setup)} functions before "
+          f"the first step ended. Its timeline, the traces left out:")
+    print(text_timeline([s for s in setup if s.name != "jax_trace"]))
 
     # -- traced serving: traceparent joins client, HTTP and dispatcher -----
     registry = ModelRegistry(metrics=metrics, wait_ms=1.0)
@@ -124,7 +141,8 @@ def main():
           f"schema-valid (load it in chrome://tracing or ui.perfetto.dev)")
 
     names = {s.name for s in tracer.recorder.spans()}
-    for expected in ("parallel_fit", "train_step", "train_iteration",
+    for expected in ("model_init", "parallel_fit", "train_step",
+                     "train_iteration", "jax_trace", "jax_lowering",
                      "xla_compile", "client_predict", "http_request",
                      "inference_request", "queue_wait", "batch_execute",
                      "route.run"):

@@ -174,8 +174,13 @@ def saturated_serving():
           f"/alerts firing={alerts['firing']}")
 
     # recovery: sequential successes only, clock past the short window →
-    # the short-window burn rate drops to 0 and the alert resolves
+    # the short-window burn rate drops to 0 and the alert resolves. A
+    # handler answers INSIDE its admission slot, so the client holds its
+    # 200 a moment before the slot is free; on a busy machine the next
+    # request can arrive in that moment and be shed (max_inflight=1). Wait
+    # for the state the recovery needs, an idle admission, before each one.
     for _ in range(4):
+        assert server.admission.wait_idle(timeout=30)
         assert predict() == 200
     clock.advance(seconds=400)
     resolved = mgr.evaluate_once()
