@@ -151,7 +151,7 @@ class TrainingEngine:
         _helpers.evict_stale_jit_entries(self._jit_cache, current_version)
 
     def _get_train_step(self, with_carries: bool = False):
-        key = ("train", with_carries, _helpers.version())
+        key = ("train", with_carries, self._mesh, _helpers.version())
         if key not in self._jit_cache:
             self._evict_stale(_helpers.version())
 
@@ -174,8 +174,8 @@ class TrainingEngine:
 
             # the program's name in the device trace and the HLO
             train_step.__name__ = "tbptt_step" if with_carries else "train_step"
-            self._jit_cache[key] = jax.jit(train_step,
-                                           donate_argnums=(0, 1, 2, 3, 9))
+            self._jit_cache[key] = _jit_step(train_step, self._mesh,
+                                             donate_argnums=(0, 1, 2, 3, 9))
         return self._jit_cache[key]
 
     def _get_multi_train_step(self):
@@ -183,7 +183,7 @@ class TrainingEngine:
         a single dispatch executes the whole window on device. This is the
         TPU training-loop idiom: per-step host dispatch disappears, and
         XLA pipelines the step boundary (see ``fit_batches_on_device``)."""
-        key = ("train_scan", _helpers.version())
+        key = ("train_scan", self._mesh, _helpers.version())
         if key not in self._jit_cache:
             self._evict_stale(_helpers.version())
 
@@ -205,8 +205,8 @@ class TrainingEngine:
                     (inputs_s, labels_s))
                 return params, states, upd, losses
 
-            self._jit_cache[key] = jax.jit(train_steps_scan,
-                                           donate_argnums=(0, 1, 2))
+            self._jit_cache[key] = _jit_step(train_steps_scan, self._mesh,
+                                             donate_argnums=(0, 1, 2))
         return self._jit_cache[key]
 
     # ------------------------------------------------------------------- fit
@@ -308,3 +308,27 @@ class TrainingEngine:
                 int(leaf.size) * leaf.dtype.itemsize
                 for leaf in leaves((self.params, self.updater_states))))
         return self
+
+
+def _jit_step(step, mesh, **jit_kwargs):
+    """``jax.jit`` of a train step. Under a mesh of several TPUs it is
+    compiled with ``parallel.mesh.step_compiler_options`` (its collectives
+    asynchronous), and each trace of it is counted as
+    ``placement.async_collective_steps`` on the span open then: the first
+    ``step_dispatch``. Elsewhere the call is exactly ``jax.jit(step,
+    **jit_kwargs)``. Defined here, at the end, for the reason ``init``
+    gives: the step's call path keeps its line numbers, and so the imports
+    are local (a line added at the top would move every line below it)."""
+    import functools
+    from deeplearning4j_tpu.parallel.mesh import step_compiler_options
+    options = step_compiler_options(mesh)
+    if options is None:
+        return jax.jit(step, **jit_kwargs)
+
+    @functools.wraps(step)
+    def counted(*args, **kwargs):
+        tracer = _trace.get_active_tracer()
+        if tracer is not None:
+            tracer.count("placement.async_collective_steps")
+        return step(*args, **kwargs)
+    return jax.jit(counted, compiler_options=options, **jit_kwargs)
