@@ -315,20 +315,27 @@ def _jit_step(step, mesh, **jit_kwargs):
     compiled with ``parallel.mesh.step_compiler_options`` (its collectives
     asynchronous), and each trace of it is counted as
     ``placement.async_collective_steps`` on the span open then: the first
-    ``step_dispatch``. Elsewhere the call is exactly ``jax.jit(step,
-    **jit_kwargs)``. Defined here, at the end, for the reason ``init``
-    gives: the step's call path keeps its line numbers, and so the imports
-    are local (a line added at the top would move every line below it)."""
+    ``step_dispatch``; where the options bound the gradient sums over
+    ``data`` (``DATA_SUM_OPTIONS``) it is counted as
+    ``placement.data_sum_overlap_steps`` too. Elsewhere the call is exactly
+    ``jax.jit(step, **jit_kwargs)``. Defined here, at the end, for the
+    reason ``init`` gives: the step's call path keeps its line numbers, and
+    so the imports are local (a line added at the top would move every line
+    below it)."""
     import functools
-    from deeplearning4j_tpu.parallel.mesh import step_compiler_options
-    options = step_compiler_options(mesh)
+    from deeplearning4j_tpu.parallel import mesh as _mesh
+    options = _mesh.step_compiler_options(mesh)
     if options is None:
         return jax.jit(step, **jit_kwargs)
+    counters = ["placement.async_collective_steps"]
+    if _mesh.DATA_SUM_OPTIONS.items() <= options.items():
+        counters.append("placement.data_sum_overlap_steps")
 
     @functools.wraps(step)
     def counted(*args, **kwargs):
         tracer = _trace.get_active_tracer()
         if tracer is not None:
-            tracer.count("placement.async_collective_steps")
+            for name in counters:
+                tracer.count(name)
         return step(*args, **kwargs)
     return jax.jit(counted, compiler_options=options, **jit_kwargs)

@@ -610,16 +610,29 @@ class TestPersistentCompileCache:
     def _explicit_dir_only(self, monkeypatch):
         """These tests place the cache by argument. A cache the environment
         placed would (rightly) refuse that, so the variable goes, and with
-        it whatever cache jax already opened under it."""
+        it whatever cache jax already opened under it. The cache a test
+        opens is closed after it: left open, every later compile in the
+        process would write to it, and a tracer's exact counts would gain
+        ``compile_cache.misses``."""
         import jax
         from jax._src import compilation_cache as jax_cc
 
         from deeplearning4j_tpu.util import compile_cache
+        names = ("jax_compilation_cache_dir",
+                 "jax_persistent_cache_min_compile_time_secs",
+                 "jax_persistent_cache_min_entry_size_bytes")
+        before = {name: getattr(jax.config, name) for name in names}
+        monkeypatch.setattr(compile_cache, "_enabled_dir",
+                            compile_cache._enabled_dir)
         if os.environ.get(compile_cache.ENV_VAR):
             monkeypatch.delenv(compile_cache.ENV_VAR)
-            monkeypatch.setattr(compile_cache, "_enabled_dir", None)
+            compile_cache._enabled_dir = None
             jax.config.update("jax_compilation_cache_dir", None)
             jax_cc.reset_cache()
+        yield
+        for name, value in before.items():
+            jax.config.update(name, value)
+        jax_cc.reset_cache()
 
     def test_registry_populates_cache_dir(self, tmp_path):
         cache = tmp_path / "xla-cache"
