@@ -47,7 +47,17 @@ class OutputLayer(DenseLayer):
         pre = self._preact(params, x)
         fn, wants_logits = loss_mod.resolve(self.loss, self.activation)
         out = pre if wants_logits else self.act_fn()(pre)
-        return fn(labels, out, mask=mask)
+        return fn(_labels_at_width(labels, out, self.n_out), out, mask=mask)
+
+    def forward(self, params, x, **kwargs):
+        """The activations of the ``n_out`` published columns: a head whose
+        vocabulary placement padded to the mesh
+        (``parallel.sharding.shard_model_with_rules``) computes the padded
+        columns too, and their softmax weight is 0."""
+        y, state = super().forward(params, x, **kwargs)
+        if y.shape[-1] > self.n_out:
+            y = y[..., :self.n_out]
+        return y, state
 
 
 @register_layer
@@ -70,7 +80,7 @@ class RnnOutputLayer(OutputLayer):
         pre = self._preact(params, x)  # [N,T,n_out]
         fn, wants_logits = loss_mod.resolve(self.loss, self.activation)
         out = pre if wants_logits else self.act_fn()(pre)
-        return fn(labels, out, mask=mask)
+        return fn(_labels_at_width(labels, out, self.n_out), out, mask=mask)
 
 
 @register_layer
@@ -150,3 +160,14 @@ class CenterLossOutputLayer(OutputLayer):
                    else jnp.take(params["cL"], labels, axis=0))
         center_l = 0.5 * jnp.mean(jnp.sum((x - centers) ** 2, axis=-1))
         return base + self.lambda_ * center_l
+
+
+def _labels_at_width(labels, scores, n_out: int):
+    """One-hot or soft ``labels`` of the published width ``n_out`` with
+    zero columns up to the width of ``scores``, which a head whose
+    vocabulary placement padded to the mesh computes; class ids, and
+    labels of any other width, as they are."""
+    pad = scores.shape[-1] - n_out
+    if pad <= 0 or labels.ndim != scores.ndim or labels.shape[-1] != n_out:
+        return labels
+    return jnp.pad(labels, [(0, 0)] * (labels.ndim - 1) + [(0, pad)])

@@ -241,7 +241,9 @@ Rule = Tuple[str, P]
 #: vocab-COLUMN-sharded — its logits stay sharded through the
 #: log-sum-exp cross-entropy (``losses.mcxent_logits`` routes softmax
 #: losses through ``log_softmax``), so the whole path compiles with ZERO
-#: all-gathers (asserted in tests/test_sharding_rules.py against HLO).
+#: all-gathers (asserted in tests/test_sharding_2d.py against HLO).
+#: A vocabulary that does not divide the axis (GPT-2's 50,257) is padded
+#: at placement rather than replicated: see :func:`shard_model_with_rules`.
 DEFAULT_2D_RULES: Tuple[Rule, ...] = (
     # vocab path: row-sharded embedding take …
     (r"(^|/)embed[^/]*/W$", P(MODEL_AXIS, None)),
@@ -402,38 +404,60 @@ def lint_partition_rules(rules: Sequence, params) -> List[str]:
 def _place_params_span(place):
     """``place(net, mesh, ...)`` under a ``place_params`` span while tracing
     is on: a model is built on one device and moved leaf by leaf, and the
-    span says how long that took for how many leaves, bytes and devices."""
+    span says how long that took for how many leaves, bytes and devices,
+    how many vocabulary leaves were padded to the mesh
+    (``padded_leaves``) and the unpadded bytes that left the net's trees
+    with them (``released_bytes``). ``place`` returns those two counts,
+    or None where it pads nothing; the wrapped call returns None."""
     @functools.wraps(place)
     def placing(net, mesh: Mesh, *args, **kwargs):
         tracer = _trace.get_active_tracer()
         if tracer is None:
-            return place(net, mesh, *args, **kwargs)
+            place(net, mesh, *args, **kwargs)
+            return
         with tracer.span("place_params", category="setup",
                          attrs={"devices": int(mesh.devices.size)}) as span:
-            place(net, mesh, *args, **kwargs)
+            padded, released = place(net, mesh, *args, **kwargs) or (0, 0)
             leaves = jax.tree_util.tree_leaves(
                 (net.params, net.states, net.updater_states))
             span.set_attribute("leaves", len(leaves))
             span.set_attribute("bytes",
                                sum(int(leaf.nbytes) for leaf in leaves))
+            span.set_attribute("padded_leaves", padded)
+            span.set_attribute("released_bytes", released)
     return placing
 
 
 @_place_params_span
-def shard_model_with_rules(net, mesh: Mesh, rules: Optional[Sequence] = None
-                           ) -> None:
+def shard_model_with_rules(net, mesh: Mesh,
+                           rules: Optional[Sequence] = None):
     """Place a model on a DP×MP mesh from a rule list, in-place (the
     config-line counterpart of :func:`shard_model`): params by
     first-match rule, updater-state leaves sharing the param's spec when
     shapes match, layer states replicated. Records the mesh on the net
     (``net._mesh``) so ``fit``/``output`` shard incoming batches over
-    the ``data`` axis end to end.
+    the ``data`` axis end to end. Returns None; its body hands the
+    ``place_params`` span the two counts of the padding below.
 
     ``rules=None`` uses :data:`DEFAULT_2D_RULES`. A matched leaf whose
     dims do not divide the named axes degrades to replicated (same
-    contract as ``shard_model``'s Megatron path)."""
+    contract as ``shard_model``'s Megatron path), with one exception, the
+    vocabulary: an embedding table's rows, and the columns and bias of an
+    output layer whose loss is softmax cross-entropy over logits, are
+    padded to the next multiple of the axis and placed split, with their
+    updater moments (:func:`_pad_vocab_leaves`). A padded row is never
+    looked up, and a padded column's logit is ``PADDED_LOGIT_BIAS``, so
+    the loss, every gradient and every update of the published entries
+    are what they were, and the padded entries stay as placed. Each
+    padded form replaces its source in ``net.params`` /
+    ``net.updater_states`` before the next leaf is placed: device 0,
+    which holds the whole model built by ``init()``, gives up the
+    unpadded vocabulary leaves as it gains their shards. ``output()``,
+    the checkpoints and the zip format give back the published widths
+    (:func:`published_trees`)."""
     specs = match_partition_rules(
         DEFAULT_2D_RULES if rules is None else rules, net.params)
+    counts = _pad_vocab_leaves(net, mesh, specs)
     repl = replicated(mesh)
     placed: Dict[str, Tuple[tuple, P]] = {}
 
@@ -468,6 +492,7 @@ def shard_model_with_rules(net, mesh: Mesh, rules: Optional[Sequence] = None
         lambda v: v.sharding, new_params)
     # steps compiled before placement know nothing about the pins
     net._jit_cache.clear()
+    return counts
 
 
 def _leaf_sharding_ok(shape, spec: P, mesh: Mesh) -> bool:
@@ -481,6 +506,175 @@ def _leaf_sharding_ok(shape, spec: P, mesh: Mesh) -> bool:
             if a not in mesh.shape or dim % mesh.shape[a]:
                 return False
     return True
+
+
+# -- the vocabulary, padded to the mesh ---------------------------------------
+
+#: The bias of a padded head column. Its logit is this, so its softmax
+#: weight is exactly 0, and so are its gradient and its updates: the loss
+#: of the published columns is unchanged. Finite, so that no ``0 * inf``
+#: appears, and representable in bfloat16.
+PADDED_LOGIT_BIAS = -1e30
+
+
+def _model_layers(net) -> Dict[object, object]:
+    """The layer that owns each key of ``net.params``: vertex names for a
+    ComputationGraph, indices for a MultiLayerNetwork."""
+    if isinstance(net.params, dict):
+        return {name: vd.obj for name, vd in net.conf.vertices.items()
+                if vd.is_layer}
+    return dict(enumerate(net.layers))
+
+
+def _softmax_head(layer) -> bool:
+    """An output layer whose loss is softmax cross-entropy over its logits
+    ``x @ W + b``, with nothing that would read or move a padded column
+    other than the loss: no regularised or constrained bias, no weight
+    noise, no parameter beside ``W`` and ``b``."""
+    from deeplearning4j_tpu.nn import losses
+    if not (_is_output_layer(layer) and layer.has_bias
+            and set(layer.param_shapes()) == {"W", "b"}):
+        return False
+    if layer.l1_bias or layer.l2_bias or layer.constraints \
+            or layer.weight_noise is not None:
+        return False
+    fn, wants_logits = losses.resolve(layer.loss, layer.activation)
+    return wants_logits and fn in (losses.mcxent_logits,
+                                   losses.negativeloglikelihood_logits)
+
+
+def _vocab_dim(layer, name: str) -> Optional[Tuple[int, float]]:
+    """``(dim, fill)`` of a parameter whose vocabulary dimension may be
+    padded: an embedding table's rows with zeros, a softmax head's
+    columns with zeros and its bias with ``PADDED_LOGIT_BIAS``; None for
+    every other parameter."""
+    from deeplearning4j_tpu.nn.layers.core import (EmbeddingLayer,
+                                                   EmbeddingSequenceLayer)
+    if isinstance(layer, (EmbeddingLayer, EmbeddingSequenceLayer)):
+        return (0, 0.0) if name == "W" and not layer.constraints else None
+    if _softmax_head(layer):
+        return {"W": (1, 0.0), "b": (0, PADDED_LOGIT_BIAS)}.get(name)
+    return None
+
+
+@functools.partial(jax.jit, static_argnames=("published", "widths",
+                                             "sharding"))
+def _padded(x, fill, *, published, widths, sharding):
+    """``x`` cut to its ``published`` shape (a leaf padded for another
+    mesh) and padded by ``widths`` with ``fill``, made as ``sharding``'s
+    shards: one program a shape, for a parameter and its moments alike."""
+    import jax.numpy as jnp
+    x = x[tuple(slice(0, n) for n in published)]
+    return jax.lax.with_sharding_constraint(
+        jnp.pad(x, widths, constant_values=fill.astype(x.dtype)), sharding)
+
+
+def _with_leaf(tree, path: tuple, value):
+    """``tree`` with the leaf at ``path`` (keys of nested dicts / lists)
+    replaced: the containers on the path are copied, everything else is
+    shared, and the caller's ``tree`` is left as it was."""
+    out = dict(tree) if isinstance(tree, dict) else list(tree)
+    out[path[0]] = value if len(path) == 1 else _with_leaf(
+        tree[path[0]], path[1:], value)
+    return out
+
+
+def _padded_shape(layer, name, v, spec, mesh: Mesh):
+    """``(published shape, padded shape, fill)`` of the vocabulary leaf
+    ``v`` of ``layer`` as placement pads it, or None where it is placed
+    as it is: not a vocabulary leaf, its rule already divides, or it
+    would not divide padded either."""
+    vocab = _vocab_dim(layer, name)
+    if vocab is None or _leaf_sharding_ok(v.shape, spec, mesh):
+        return None
+    dim, fill = vocab
+    axes = tuple(spec)[dim] if dim < len(spec) else None
+    published = tuple(layer.param_shapes()[name])
+    if axes is None or len(published) != v.ndim:
+        return None
+    axes = axes if isinstance(axes, tuple) else (axes,)
+    if not all(a in mesh.shape for a in axes):
+        return None
+    # the smallest multiple of the axes: 50,257 -> 50,258 over two chips
+    step = int(np.prod([mesh.shape[a] for a in axes]))
+    padded = list(published)
+    padded[dim] = -(-published[dim] // step) * step
+    if not _leaf_sharding_ok(padded, spec, mesh):
+        return None
+    return published, tuple(padded), fill
+
+
+def _pad_vocab_leaves(net, mesh: Mesh, specs) -> Tuple[int, int]:
+    """Pad each vocabulary leaf whose rule splits its vocabulary dimension
+    over axes it does not divide (:func:`_padded_shape`), with the updater
+    moments of its shape, and place it split; returns ``(leaves padded,
+    unpadded bytes released)``.
+
+    Leaf by leaf, the padded form takes its source's place in
+    ``net.params`` / ``net.updater_states`` before the next is made, so
+    the net's trees hold one of the two at a time. A padded form cannot
+    share its source's buffer, as a replicated placement does, because
+    the shapes differ. Arrays a caller still holds are left alone: only
+    the net's references go. A leaf with updater state of another shape
+    than its own is not padded, and replicates."""
+    import jax.numpy as jnp
+    repl = replicated(mesh)
+    padded = released = 0
+    for key, layer in _model_layers(net).items():
+        for name in list(net.params[key]):
+            v = net.params[key][name]
+            target = _padded_shape(layer, name, v, specs[key][name], mesh)
+            slots = ({} if net.updater_states is None
+                     else net.updater_states[key][name])
+            moments = [slot for slot, s in slots.items()
+                       if not _is_scalar_leaf(s)]
+            if target is None or any(tuple(slots[m].shape) != v.shape
+                                     for m in moments):
+                continue
+            published, shape, fill = target
+            widths = tuple((0, n - p) for n, p in zip(shape, published))
+            where = NamedSharding(mesh, specs[key][name])
+            fill = max(fill, float(jnp.finfo(v.dtype).min))
+            del v, slots
+            for tree_name, path, value in (
+                    [("params", (key, name), fill)]
+                    + [("updater_states", (key, name, m), 0.0)
+                       for m in moments]):
+                tree = getattr(net, tree_name)
+                src = tree
+                for k in path:
+                    src = src[k]
+                released += int(src.nbytes)
+                src = jax.device_put(src, repl)
+                out = _padded(src, jnp.float32(value), published=published,
+                              widths=widths, sharding=where)
+                del src
+                setattr(net, tree_name, _with_leaf(tree, path, out))
+                del tree, out
+                padded += 1
+    return padded, released
+
+
+def published_trees(net):
+    """``(params, updater_states)`` at the shapes the configuration states:
+    a vocabulary leaf (and its moments) that :func:`shard_model_with_rules`
+    padded comes back cut to its layer's ``param_shapes()``, every other
+    leaf as it is. Checkpoints and the zip format are written from these,
+    so they hold the published model whatever mesh it was trained on."""
+    params, upd = net.params, net.updater_states
+    for key, layer in _model_layers(net).items():
+        for name, v in net.params[key].items():
+            if _vocab_dim(layer, name) is None:
+                continue
+            published = tuple(layer.param_shapes()[name])
+            if v.shape == published:
+                continue
+            cut = tuple(slice(0, n) for n in published)
+            params = _with_leaf(params, (key, name), v[cut])
+            for slot, s in ({} if upd is None else upd[key][name]).items():
+                if s.shape == v.shape:
+                    upd = _with_leaf(upd, (key, name, slot), s[cut])
+    return params, upd
 
 
 @_place_params_span

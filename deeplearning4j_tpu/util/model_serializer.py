@@ -39,11 +39,11 @@ NORMALIZER_NAME = "normalizer.json"
 META_NAME = "meta.json"
 
 
-def _layer_keys(model):
+def _layer_keys(params):
     """(key, params_dict) pairs — list-indexed for MLN, name-keyed for graphs."""
-    if isinstance(model.params, dict):
-        return list(model.params.items())
-    return [(str(i), p) for i, p in enumerate(model.params)]
+    if isinstance(params, dict):
+        return list(params.items())
+    return [(str(i), p) for i, p in enumerate(params)]
 
 
 def _npz_bytes(arrays: dict) -> bytes:
@@ -87,12 +87,14 @@ def write_model(model, path: Union[str, Path], *, save_updater: bool = True,
     path = Path(path)
     path.parent.mkdir(parents=True, exist_ok=True)
 
+    # the published shapes, whatever mesh padded the vocabulary to
+    from deeplearning4j_tpu.parallel.sharding import published_trees
+    model_params, us = published_trees(model)
     params = {f"{k}/{n}": np.asarray(v)
-              for k, pd in _layer_keys(model) for n, v in pd.items()}
+              for k, pd in _layer_keys(model_params) for n, v in pd.items()}
 
     upd = {}
-    if save_updater and model.updater_states is not None:
-        us = model.updater_states
+    if save_updater and us is not None:
         items = us.items() if isinstance(us, dict) else ((str(i), u) for i, u in enumerate(us))
         for k, per_param in items:
             for pn, slots in per_param.items():

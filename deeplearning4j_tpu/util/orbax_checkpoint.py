@@ -110,9 +110,12 @@ def _multiprocess_safe(tree):
 
 
 def _state_pytree(model, with_updater: bool) -> Dict[str, Any]:
-    state: Dict[str, Any] = {"params": model.params, "states": model.states}
-    if with_updater and model.updater_states is not None:
-        state["updater_states"] = model.updater_states
+    # the published shapes, whatever mesh padded the vocabulary to
+    from deeplearning4j_tpu.parallel.sharding import published_trees
+    params, updater_states = published_trees(model)
+    state: Dict[str, Any] = {"params": params, "states": model.states}
+    if with_updater and updater_states is not None:
+        state["updater_states"] = updater_states
     state["counters"] = {"iteration": np.asarray(model.iteration),
                          "epoch": np.asarray(model.epoch)}
     return _multiprocess_safe(state)

@@ -111,6 +111,52 @@ class TestSaveRestore:
                                    np.asarray(g.output_single(x)), rtol=1e-6)
 
 
+    def test_padded_vocabulary_round_trip(self, tmp_path):
+        """A net whose odd vocabulary the mesh padded is saved at the
+        published shapes; restored without a mesh it has them, restored
+        onto a mesh it is padded again, and both compute the same."""
+        import jax
+        from deeplearning4j_tpu.datasets.dataset import DataSet
+        from deeplearning4j_tpu.parallel.mesh import make_mesh
+        from deeplearning4j_tpu.parallel.sharding import (
+            shard_model_with_rules)
+        from deeplearning4j_tpu.zoo.models import TransformerLM, lm_labels
+        vocab = 63
+        lm = TransformerLM(vocab_size=vocab, max_length=8, n_layers=1,
+                           d_model=16, n_heads=4, d_ff=32, seed=3)
+        mesh = make_mesh({"data": 2, "model": 2}, jax.devices()[:4])
+        net = lm.init()
+        shard_model_with_rules(net, mesh)
+        toks = np.random.default_rng(0).integers(0, vocab, (4, 8))
+        ds = DataSet(toks.astype(np.float32),
+                     np.asarray(lm_labels(toks, vocab)))
+        net.fit(ds)
+        assert net.params["out"]["W"].shape == (16, 64)
+        d = str(tmp_path / "lm")
+        save_model(net, d)
+
+        plain = restore_model(d)
+        again = restore_model(d, mesh=mesh)
+        for k, n in (("embed", "W"), ("out", "W"), ("out", "b")):
+            want = lm.init().params[k][n].shape
+            assert plain.params[k][n].shape == want
+            assert all(s.shape == want
+                       for s in plain.updater_states[k][n].values())
+            padded = again.params[k][n].shape
+            assert padded != want and 64 in padded
+            assert all(s.shape == padded
+                       for s in again.updater_states[k][n].values())
+        want = np.asarray(net.output(ds.features))
+        assert want.shape == (4, 8, vocab)
+        for back in (plain, again):
+            np.testing.assert_allclose(np.asarray(back.output(ds.features)),
+                                       want, atol=1e-6)
+        plain.fit(ds)
+        again.fit(ds)
+        assert float(again.score_) == pytest.approx(float(plain.score_),
+                                                    abs=1e-5)
+
+
 class TestManagerRotation:
     def test_keep_last_and_latest_restore(self, tmp_path):
         net, x, y = trained_net(steps=1)

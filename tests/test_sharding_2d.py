@@ -36,22 +36,23 @@ _COLLECTIVE = re.compile(
     r"|all-to-all)\b")
 
 
-def tiny_lm_2d(mesh=None, rules=None, seed=7):
+def tiny_lm_2d(mesh=None, rules=None, seed=7, vocab=VOCAB):
     """1-layer LM whose dims divide every mesh used here (model axis up
-    to 4: vocab 64, d_model 16, heads 4, d_ff 32)."""
-    net = TransformerLM(vocab_size=VOCAB, max_length=T, n_layers=1,
+    to 4: vocab 64, d_model 16, heads 4, d_ff 32); an odd ``vocab`` is
+    the one dimension that does not."""
+    net = TransformerLM(vocab_size=vocab, max_length=T, n_layers=1,
                         d_model=16, n_heads=4, d_ff=32, seed=seed).init()
     if mesh is not None:
         shard_model_with_rules(net, mesh, rules)
     return net
 
 
-def lm_batch(seed=3):
+def lm_batch(seed=3, vocab=VOCAB):
     import jax.numpy as jnp
     rng = np.random.default_rng(seed)
-    toks = rng.integers(0, VOCAB, size=(BATCH, T))
+    toks = rng.integers(0, vocab, size=(BATCH, T))
     return DataSet(toks.astype(np.float32),
-                   np.asarray(lm_labels(jnp.asarray(toks), VOCAB)))
+                   np.asarray(lm_labels(jnp.asarray(toks), vocab)))
 
 
 def collective_counts(hlo):
@@ -266,6 +267,237 @@ class TestEndToEnd2D:
         assert leaf_paths(net.params)["0/W"].sharding.spec == \
             P(None, "model")
         assert np.asarray(net.output(x)).shape == (BATCH, 4)
+
+
+# ------------------------------------- a vocabulary the mesh does not divide
+#: leaves the vocabulary pads: embed/W, out/W, out/b and Adam's m and v of each
+VOCAB_LEAVES = [("embed", "W", 0), ("out", "W", 1), ("out", "b", 0)]
+ODD = 63
+
+
+def vocab_leaves(net):
+    """(name, param, dim, array, moments) of the vocabulary's leaves."""
+    return [(k, n, dim, net.params[k][n], net.updater_states[k][n])
+            for k, n, dim in VOCAB_LEAVES]
+
+
+def device0_bytes(arrays):
+    import jax
+    dev = jax.devices()[0]
+    return sum(shard.data.nbytes for a in arrays
+               for shard in a.addressable_shards if shard.device == dev)
+
+
+class TestPaddedVocab:
+    """GPT-2's 50,257 is odd: the vocabulary leaves are padded to the mesh
+    and placed split, with the same mathematics as the published model.
+    64 is the control, which divides and pads nothing."""
+
+    @pytest.mark.parametrize("vocab", [VOCAB, ODD])
+    def test_vocab_leaves_split_and_padded(self, vocab):
+        net = tiny_lm_2d(make_mesh({"data": 2, "model": 2}), vocab=vocab)
+        for k, n, dim, v, moments in vocab_leaves(net):
+            assert v.shape[dim] == 64, (k, n)
+            assert v.sharding.spec[dim] == "model", (k, n)
+            assert set(moments) == {"m", "v"}
+            for s in moments.values():
+                assert s.shape == v.shape and s.sharding == v.sharding
+
+    @pytest.mark.parametrize("vocab", [VOCAB, ODD])
+    def test_padding_stays_exact_over_two_steps(self, vocab):
+        from deeplearning4j_tpu.parallel.sharding import PADDED_LOGIT_BIAS
+        net = tiny_lm_2d(make_mesh({"data": 2, "model": 2}), vocab=vocab)
+        ds = lm_batch(vocab=vocab)
+        for _ in range(2):
+            net.fit(ds)
+        for k, n, dim, v, moments in vocab_leaves(net):
+            pad = np.take(np.asarray(v), range(vocab, 64), axis=dim)
+            want = PADDED_LOGIT_BIAS if (k, n) == ("out", "b") else 0.0
+            assert np.all(pad == np.float32(want)), (k, n)
+            for s in moments.values():
+                assert not np.take(np.asarray(s), range(vocab, 64),
+                                   axis=dim).any(), (k, n)
+
+    @pytest.mark.parametrize("labels", ["class_ids", "one_hot"])
+    @pytest.mark.parametrize("vocab", [VOCAB, ODD])
+    def test_loss_and_gradients_match_the_unpadded_net(self, vocab, labels):
+        ds = lm_batch(vocab=vocab)
+        y = np.asarray(ds.labels)
+        if labels == "one_hot":
+            y = np.eye(vocab, dtype=np.float32)[y]
+        ref = tiny_lm_2d(seed=11, vocab=vocab)
+        net = tiny_lm_2d(make_mesh({"data": 2, "model": 2}), seed=11,
+                         vocab=vocab)
+        g_ref, loss_ref = ref.compute_gradient_and_score(ds.features, y)
+        g_net, loss_net = net.compute_gradient_and_score(ds.features, y)
+        assert loss_net == pytest.approx(loss_ref, abs=1e-5)
+        ref_g, net_g = leaf_paths(g_ref), leaf_paths(g_net)
+        assert set(ref_g) == set(net_g)
+        for name, want in ref_g.items():
+            got = np.asarray(net_g[name])
+            got = got[tuple(slice(0, n) for n in want.shape)]
+            np.testing.assert_allclose(got, np.asarray(want), atol=2e-6,
+                                       err_msg=name)
+        for k, n, dim, _v, _m in vocab_leaves(net):
+            assert not np.take(np.asarray(g_net[k][n]), range(vocab, 64),
+                               axis=dim).any(), (k, n)
+
+    @pytest.mark.parametrize("vocab", [VOCAB, ODD])
+    def test_vocab_path_compiles_with_zero_all_gathers(self, vocab):
+        """The train step gathers nothing. The forward gathers nothing
+        either, except, where the head is padded, the one output that
+        ``output()`` hands back cut to the published width: its
+        probabilities ``[batch shard, T, 64]``, never a parameter."""
+        mesh = make_mesh({"data": 2, "model": 2})
+        net, ds = tiny_lm_2d(mesh, vocab=vocab), lm_batch(vocab=vocab)
+        step = collective_counts(step_hlo(net, ds, mesh))
+        assert step.get("all-gather", 0) == 0 and step["all-reduce"] > 0
+        forward = forward_hlo(net, ds, mesh)
+        gathers = re.findall(r"= (\S+) all-gather\(", forward)
+        assert gathers == ([] if vocab == VOCAB
+                           else [f"f32[{BATCH // 2},{T},64]{{1,0,2}}"])
+        root = [line for line in forward.splitlines()
+                if "ROOT" in line and "ENTRY" not in line][-1]
+        assert collective_counts(forward)["all-reduce"] > 0
+        if gathers:
+            assert f"f32[{BATCH // 2},{T},{vocab}]" in root
+
+    @pytest.mark.parametrize("vocab", [VOCAB, ODD])
+    def test_output_has_the_published_width(self, vocab):
+        ds = lm_batch(vocab=vocab)
+        ref = tiny_lm_2d(seed=5, vocab=vocab)
+        net = tiny_lm_2d(make_mesh({"data": 2, "model": 2}), seed=5,
+                         vocab=vocab)
+        out = np.asarray(net.output(ds.features))
+        assert out.shape == (BATCH, T, vocab)
+        np.testing.assert_allclose(out, np.asarray(ref.output(ds.features)),
+                                   atol=1e-6)
+        np.testing.assert_allclose(out.sum(-1), 1.0, atol=1e-5)
+
+    @pytest.mark.parametrize("loss", ["mse", "xent"])
+    def test_a_head_of_another_loss_stays_replicated(self, loss):
+        from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration
+        from deeplearning4j_tpu.nn.graph import ComputationGraph
+        from deeplearning4j_tpu.nn.layers import (DenseLayer,
+                                                  EmbeddingLayer,
+                                                  OutputLayer)
+        conf = (NeuralNetConfiguration.builder().seed(3).updater("adam")
+                .graph_builder().add_inputs("ids")
+                .add_layer("embed", EmbeddingLayer(n_in=ODD, n_out=16), "ids")
+                .add_layer("hidden", DenseLayer(n_in=16, n_out=16), "embed")
+                .add_layer("out", OutputLayer(n_in=16, n_out=ODD, loss=loss,
+                                              activation="sigmoid"), "hidden")
+                .set_outputs("out").build())
+        net = ComputationGraph(conf).init()
+        shard_model_with_rules(net, make_mesh({"data": 2, "model": 2}))
+        placed = leaf_paths(net.params)
+        assert placed["embed/W"].shape == (64, 16)      # the embedding pads
+        assert placed["embed/W"].sharding.spec == P("model", None)
+        for n in ("out/W", "out/b"):                    # this head does not
+            assert placed[n].shape[-1] == ODD
+            assert placed[n].sharding.spec == P()
+        x = np.arange(BATCH, dtype=np.float32)[:, None]
+        y = np.zeros((BATCH, ODD), np.float32)
+        net.fit(DataSet(x, y))
+        assert np.isfinite(float(net.score_))
+        assert np.asarray(net.output(x)).shape == (BATCH, ODD)
+
+    #: run in a fresh interpreter: jax.live_arrays() is the whole process's,
+    #: and arrays that other tests' threads make would enter the count
+    LIVE_BYTES = """
+import gc, json, sys
+import jax
+sys.path.insert(0, "tests")
+from test_sharding_2d import (DEFAULT_2D_RULES, ODD, P, device0_bytes,
+                              make_mesh, tiny_lm_2d)
+mesh = make_mesh({"data": 2, "model": 2})
+replicate_vocab = [(r"(^|/)(embed|out)[^/]*/(W|b)$", P())] + list(
+    DEFAULT_2D_RULES)
+out = {}
+for how, rules in (("replicated", replicate_vocab), ("padded", None)):
+    gc.collect()
+    before = device0_bytes(jax.live_arrays())
+    net = tiny_lm_2d(mesh, rules, vocab=ODD)
+    gc.collect()
+    out[how] = [device0_bytes(jax.live_arrays()) - before,
+                device0_bytes(jax.tree_util.tree_leaves(
+                    (net.params, net.states, net.updater_states,
+                     net._rng_key)))]
+    del net
+print(json.dumps(out))
+"""
+
+    def test_device0_holds_no_more_than_the_replicated_placement(self):
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo,
+                   XLA_FLAGS="--xla_force_host_platform_device_count=8")
+        proc = subprocess.run([sys.executable, "-c", self.LIVE_BYTES],
+                              cwd=repo, env=env, capture_output=True,
+                              text=True, timeout=300)
+        assert proc.returncode == 0, proc.stderr[-3000:]
+        held = json.loads(proc.stdout.strip().splitlines()[-1])
+        for how, (live, trees) in held.items():
+            # nothing of the net's outlives placement but its own trees
+            assert live == trees, how
+        # the six [63,16] f32 leaves are 4 KB each on device 0 replicated
+        # and 2 KB each split
+        assert held["padded"][0] <= held["replicated"][0] - 6 * 1900
+
+    def test_each_padded_leaf_replaces_its_source_before_the_next(
+            self, monkeypatch):
+        import weakref
+        from deeplearning4j_tpu.parallel import sharding
+        net = tiny_lm_2d(vocab=ODD)
+        sources = [weakref.ref(leaf) for k, n, _d, v, moments
+                   in vocab_leaves(net) for leaf in (v, *moments.values())]
+        alive_at_call = []
+        padded = sharding._padded
+
+        def watched(*args, **kwargs):
+            alive_at_call.append([ref() is not None for ref in sources])
+            return padded(*args, **kwargs)
+
+        monkeypatch.setattr(sharding, "_padded", watched)
+        shard_model_with_rules(net, make_mesh({"data": 2, "model": 2}))
+        assert len(alive_at_call) == 9
+        for i, alive in enumerate(alive_at_call):
+            assert alive == [False] * i + [True] * (9 - i), i
+
+    @pytest.mark.parametrize("vocab", [VOCAB, ODD])
+    def test_place_params_span_counts_the_padding(self, vocab):
+        from deeplearning4j_tpu.observe import (Tracer, disable_tracing,
+                                                enable_tracing)
+        tracer = enable_tracing(Tracer())
+        try:
+            tiny_lm_2d(make_mesh({"data": 2, "model": 2}), vocab=vocab)
+        finally:
+            disable_tracing()
+        span, = [s for s in tracer.recorder.spans()
+                 if s.name == "place_params"]
+        table, bias = vocab * 16 * 4, vocab * 4
+        want = (9, 3 * (2 * table + bias)) if vocab % 2 else (0, 0)
+        assert (span.attrs["padded_leaves"],
+                span.attrs["released_bytes"]) == want
+
+    @pytest.mark.parametrize("updater", [True, False])
+    def test_zip_checkpoint_holds_the_published_shapes(self, tmp_path,
+                                                       updater):
+        from deeplearning4j_tpu.util.model_serializer import (restore_model,
+                                                              write_model)
+        ds = lm_batch(vocab=ODD)
+        net = tiny_lm_2d(make_mesh({"data": 2, "model": 2}), vocab=ODD)
+        net.fit(ds)
+        path = tmp_path / "lm.zip"
+        write_model(net, path, save_updater=updater)
+        back = restore_model(path, load_updater=updater)
+        for k, n, _dim, v, moments in vocab_leaves(back):
+            want = tiny_lm_2d(vocab=ODD).params[k][n].shape
+            assert v.shape == want
+            if updater:
+                assert all(s.shape == want for s in moments.values())
+        np.testing.assert_allclose(np.asarray(back.output(ds.features)),
+                                   np.asarray(net.output(ds.features)),
+                                   atol=1e-6)
 
 
 # ----------------------------------------------- Keras-imported BERT, 2-D
